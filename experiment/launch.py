@@ -36,7 +36,10 @@ from skycomputing_tpu.ops import build_loss
 from skycomputing_tpu.parallel import PipelineModel
 from skycomputing_tpu.runner import Runner
 from skycomputing_tpu.stimulator import Stimulator
-from skycomputing_tpu.utils import Logger
+from skycomputing_tpu.utils import (
+    Logger,
+    enable_persistent_compilation_cache,
+)
 
 
 def build_optimizer(optim_cfg: dict):
@@ -50,6 +53,10 @@ def run(cfg, logger: Logger) -> int:
     logger.info(
         f"devices: {len(devices)} x {devices[0].platform} "
         f"({devices[0].device_kind})"
+    )
+    # before the profilers' first compile, not first at Runner build
+    logger.info(
+        f"compile cache: {enable_persistent_compilation_cache()}"
     )
 
     # --- cluster membership -------------------------------------------------

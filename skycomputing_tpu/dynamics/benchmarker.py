@@ -48,21 +48,26 @@ def _device_for(worker, devices):
 
 
 def device_available_memory_mb(device, fallback_fraction: float = 0.8) -> float:
-    """Free device memory in MB; psutil host fallback for CPU fake devices."""
-    stats = None
-    try:
-        stats = device.memory_stats()
-    except Exception:
-        stats = None
+    """Free device memory in MB, as the device itself reports it.
+
+    The CPU backend's virtual devices have no memory of their own and
+    report nothing, so they share the host's available RAM.  Any other
+    device that reports nothing is an error: planning a TPU's stages
+    against the host's RAM would look like a working allocation.
+    """
+    stats = device.memory_stats()
     if stats and "bytes_limit" in stats:
         free = stats["bytes_limit"] - stats.get("bytes_in_use", 0)
         return free / 1024.0**2
-    try:
-        import psutil
+    if device.platform != "cpu":
+        raise RuntimeError(
+            f"{device} ({device.device_kind}) reports no memory_stats(); "
+            f"set the worker's mem_limit or fix the runtime — its memory "
+            f"is not the host's"
+        )
+    import psutil
 
-        return psutil.virtual_memory().available * fallback_fraction / 1024.0**2
-    except Exception:  # pragma: no cover - psutil is in the image
-        return 8 * 1024.0
+    return psutil.virtual_memory().available * fallback_fraction / 1024.0**2
 
 
 class DeviceBenchmarker(BaseBenchmarker):

@@ -24,13 +24,6 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def _cost_analysis(compiled) -> dict:
-    """Version-normalized ``compiled.cost_analysis()`` (shared shim)."""
-    from ..utils.profiling import normalize_cost_analysis
-
-    return normalize_cost_analysis(compiled.cost_analysis())
-
-
 def _as_tuple(data) -> Tuple:
     return data if isinstance(data, tuple) else (data,)
 
@@ -123,7 +116,7 @@ class Estimator:
         out_aval = jax.eval_shape(apply_fn, params_aval, *avals)
 
         compiled = jax.jit(apply_fn).lower(params_aval, *avals).compile()
-        flops = float(_cost_analysis(compiled).get("flops", 0.0))
+        flops = float(compiled.cost_analysis().get("flops", 0.0))
 
         mb = 1024.0**2
         # Reference formula (estimator.py:85-152): inputs + 2x outputs (grads)
@@ -176,7 +169,7 @@ class Estimator:
     def measure_flops(fn: Callable, *args) -> float:
         """XLA-reported FLOPs of an arbitrary jittable function."""
         compiled = jax.jit(fn).lower(*args).compile()
-        return float(_cost_analysis(compiled).get("flops", 0.0))
+        return float(compiled.cost_analysis().get("flops", 0.0))
 
     @staticmethod
     def benchmark_decode_step(
@@ -245,7 +238,7 @@ class Estimator:
 
         out_aval = jax.eval_shape(step_fn, params_aval, *args)
         compiled = jax.jit(step_fn).lower(params_aval, *args).compile()
-        flops = float(_cost_analysis(compiled).get("flops", 0.0))
+        flops = float(compiled.cost_analysis().get("flops", 0.0))
 
         # memory counts the DATA outputs only: an attention decode also
         # returns the updated caches, but those alias the preallocated

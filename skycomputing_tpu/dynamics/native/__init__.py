@@ -2,13 +2,19 @@
 
 pybind11 is not in the image, so the C++ core exposes a C ABI and is loaded
 with ctypes.  The shared object is compiled from ``solver.cpp`` with g++ on
-first use (cached next to the source); any failure — no compiler, readonly
-filesystem — degrades silently to the pure-Python solver.
+first use, at a fixed path next to the source.  A library found there is
+used only when the stamp beside it proves it is a build of the CURRENT
+source: the stamp is one sha256 over the compiler flags, the
+``solver.cpp`` it was compiled from and the library itself.  Modification times prove nothing — a
+copied or checked-out tree need not keep them — so they are not consulted.
+A failed build (no compiler, read-only filesystem) is reported once
+through the logger and the pure-Python solver takes over.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -17,35 +23,54 @@ from typing import List, Optional, Tuple
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "solver.cpp")
 _LIB = os.path.join(_HERE, "libskytpu_solver.so")
+_STAMP = _LIB + ".sha256"
+_CXX = ["g++", "-O2", "-shared", "-fPIC", "-std=c++17"]
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
-def _build() -> bool:
-    if os.path.exists(_LIB) and os.path.getmtime(_LIB) >= os.path.getmtime(
-        _SRC
-    ):
-        return True
-    # build to a temp name then os.replace: concurrent first-use processes
-    # must never dlopen a half-written library
+def _stamp_for(lib_path: str) -> str:
+    """One digest over what a build is made of and what it made: the
+    compiler flags, ``solver.cpp`` and the library's own bytes."""
+    digest = hashlib.sha256(" ".join(_CXX).encode())
+    for path in (_SRC, lib_path):
+        with open(path, "rb") as fh:
+            digest.update(fh.read())
+    return digest.hexdigest()
+
+
+def _is_current() -> bool:
+    try:
+        with open(_STAMP) as fh:
+            return fh.read() == _stamp_for(_LIB)
+    except OSError:
+        return False
+
+
+def _build() -> None:
+    """Make ``_LIB`` a verified build of the current ``solver.cpp``."""
+    if _is_current():
+        return
+    # build to a unique temp name then os.replace: concurrent first-use
+    # processes must never dlopen a half-written library
     tmp = f"{_LIB}.{os.getpid()}.tmp"
     try:
         subprocess.run(
-            ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", tmp],
+            _CXX + [_SRC, "-o", tmp],
             check=True,
             capture_output=True,
             timeout=120,
         )
+        stamp = _stamp_for(tmp)
         os.replace(tmp, _LIB)
-        return True
-    except Exception:
-        try:
+        with open(f"{_STAMP}.{os.getpid()}.tmp", "w") as fh:
+            fh.write(stamp)
+        os.replace(fh.name, _STAMP)
+    finally:
+        if os.path.exists(tmp):
             os.unlink(tmp)
-        except OSError:
-            pass
-        return False
 
 
 def load() -> Optional[ctypes.CDLL]:
@@ -55,11 +80,18 @@ def load() -> Optional[ctypes.CDLL]:
         if _tried:
             return _lib
         _tried = True
-        if not _build():
-            return None
         try:
+            _build()
             lib = ctypes.CDLL(_LIB)
-        except OSError:
+        except (OSError, subprocess.SubprocessError) as exc:
+            from ...utils import Logger
+
+            stderr = getattr(exc, "stderr", None) or b""
+            Logger().warning(
+                f"native solver unavailable, allocations use the "
+                f"pure-Python solver: {exc!r} "
+                f"{stderr.decode(errors='replace')[-400:]}".rstrip()
+            )
             return None
         lib.skytpu_solve_minmax.restype = ctypes.c_int
         lib.skytpu_solve_minmax.argtypes = [

@@ -141,11 +141,12 @@ def flash_attention(
 ):
     """Fused attention.  q/k/v: [B, L, H, D]; bias: [B, L] additive mask.
 
-    ``interpret=None`` auto-selects interpret mode off-TPU so the same code
-    path runs (slowly but exactly) on the CPU test mesh.  Default block
-    sizes were tuned on a v5e chip (L=4096: 2.2x over the einsum path at
-    bq=256/bk=512; the 128/128 blocks actually lost to XLA's fused einsum);
-    they clamp to L for shorter sequences.
+    ``interpret=None`` compiles on a TPU backend and interprets (slowly
+    but exactly) everywhere else — the convenience the CPU test mesh runs
+    on; a TPU run therefore never interprets unless asked to.  The default
+    block sizes (bq=256/bk=512) come from an early v5e sweep that no
+    artifact in the tree records: kernel time against the einsum path is
+    not measured.  They clamp to L for shorter sequences.
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5

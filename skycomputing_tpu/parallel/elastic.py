@@ -428,15 +428,15 @@ class ElasticSupervisor:
         # this supervisor's process has a persistent XLA cache active, pin
         # the SAME directory into the trainer so a re-formed world
         # restarts at cache-hit speed instead of re-paying the compile
-        # bill.  setdefault: an operator's explicit choice — including
-        # the "0" opt-out — rides through untouched; when no cache is
-        # active (e.g. the CPU backend's unsafe-serialization default)
-        # nothing is exported and the trainer decides for itself.
+        # bill.  setdefault: an operator's explicit choice rides through
+        # untouched; when no cache is active (e.g. the CPU backend's
+        # unsafe-serialization default) nothing is exported and the
+        # trainer decides for itself.
         from ..utils.compile_cache import compilation_cache_dir
 
         active_cache = compilation_cache_dir()
         if active_cache:
-            env.setdefault("SKYTPU_COMPILE_CACHE", active_cache)
+            env.setdefault("JAX_COMPILATION_CACHE_DIR", active_cache)
         cmd = list(self._trainer_cmd(spec, rank))
         self._logger.info(
             f"[node {self.node_id}] gen {spec['generation']}: rank {rank}/"

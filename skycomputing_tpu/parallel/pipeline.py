@@ -76,10 +76,7 @@ def _donation_enabled() -> bool:
         elif not HOTPATH:
             _DONATE[0] = False
         else:
-            try:
-                _DONATE[0] = jax.default_backend() != "cpu"
-            except Exception:  # pragma: no cover - backend init failure
-                _DONATE[0] = False
+            _DONATE[0] = jax.default_backend() != "cpu"
     return _DONATE[0]
 
 
@@ -115,36 +112,45 @@ _DISPATCH_STATS = {"programs": 0, "puts": 0}
 
 # XLA backend-compile counter, fed by jax.monitoring: every executable the
 # backend actually compiles (a jit cache miss that wasn't served by the
-# persistent compilation cache) emits one duration event.  This is the
-# ground truth for "did this step recompile anything".
+# persistent compilation cache).  This is the ground truth for "did this
+# step recompile anything".  jax wraps the persistent-cache lookup AND the
+# compile in one duration event, so a miss that the cache serves emits the
+# event too; the cache's own hit event, which comes first, cancels it.
 _XLA_COMPILES = [0]
 _COMPILE_LISTENER = [False]
+_SERVED_FROM_CACHE = [False]
 
 
 def _ensure_compile_listener() -> None:
     if _COMPILE_LISTENER[0]:
         return
     _COMPILE_LISTENER[0] = True
-    try:
-        from jax import monitoring
+    from jax import monitoring
 
-        def _on_duration(name: str, _secs: float, **_kw) -> None:
-            if name == "/jax/core/compile/backend_compile_duration":
-                _XLA_COMPILES[0] += 1
-                tracer = get_tracer()
-                if tracer is not None:
-                    # the probe reports AFTER the compile finished: back
-                    # the start off the duration so the span sits where
-                    # the compile actually ran on the timeline
-                    end = tracer.now()
-                    tracer.complete(
-                        "xla_compile", tracer.lane("xla", "compile"),
-                        max(end - _secs * 1e6, 0.0), dur_us=_secs * 1e6,
-                    )
+    def _on_event(name: str, **_kw) -> None:
+        if name == "/jax/compilation_cache/cache_hits":
+            _SERVED_FROM_CACHE[0] = True
 
-        monitoring.register_event_duration_secs_listener(_on_duration)
-    except Exception:  # pragma: no cover - monitoring API moved/absent
-        pass
+    def _on_duration(name: str, _secs: float, **_kw) -> None:
+        if name != "/jax/core/compile/backend_compile_duration":
+            return
+        if _SERVED_FROM_CACHE[0]:
+            _SERVED_FROM_CACHE[0] = False
+            return
+        _XLA_COMPILES[0] += 1
+        tracer = get_tracer()
+        if tracer is not None:
+            # the probe reports AFTER the compile finished: back
+            # the start off the duration so the span sits where
+            # the compile actually ran on the timeline
+            end = tracer.now()
+            tracer.complete(
+                "xla_compile", tracer.lane("xla", "compile"),
+                max(end - _secs * 1e6, 0.0), dur_us=_secs * 1e6,
+            )
+
+    monitoring.register_event_listener(_on_event)
+    monitoring.register_event_duration_secs_listener(_on_duration)
 
 
 def xla_compile_count() -> int:
@@ -1135,10 +1141,10 @@ class PipelineModel:
         Warm-compiles first, then takes the median of ``repeats`` samples,
         each timing ``inner_iters`` chained fwd+bwd executions with ONE
         final block — chaining amortizes per-call dispatch latency (which
-        on a tunneled/remote device can exceed small-stage compute) out of
-        the per-iteration figure.  This is the honest per-stage cost
-        profile the pipelined step time is built from — per-call elapsed
-        times inside a full step are polluted by queueing.
+        can exceed small-stage compute) out of the per-iteration figure.
+        This is the honest per-stage cost profile the pipelined step time
+        is built from — per-call elapsed times inside a full step are
+        polluted by queueing.
 
         ``inner_iters="auto"`` sizes the chain per stage from a single
         post-warm probe execution: ``clamp(round(auto_window_s / t1), 1,

@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from .compat import shard_map as _shard_map
 
 
 def _online_block_update(o, m, l, scores, v_blk):
@@ -116,7 +115,7 @@ def ring_attention(
         # zero-size placeholder keeps one code path; it is never read or
         # permuted (has_bias is trace-time static)
         bias = jnp.zeros((q.shape[0], q.shape[1]), jnp.float32)
-    return _shard_map(
+    return jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(seq_spec, seq_spec, seq_spec, bias_spec),

@@ -36,7 +36,6 @@ import numpy as np
 import optax
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from .compat import shard_map as _shard_map
 
 import flax.linen as nn
 
@@ -689,7 +688,7 @@ class CompiledBertPipeline:
         if rng is not None:
             in_specs.append(P())
             args.append(jax.random.key_data(rng))
-        out = _shard_map(
+        out = jax.shard_map(
             body,
             mesh=self.mesh,
             in_specs=tuple(in_specs),

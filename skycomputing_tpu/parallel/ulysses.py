@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from .compat import shard_map as _shard_map
 
 
 def ulysses_attention(
@@ -96,14 +95,14 @@ def ulysses_attention(
     seq_spec = P(None, axis_name, None, None)
     bias_spec = P(None, axis_name)
     if bias is None:
-        return _shard_map(
+        return jax.shard_map(
             lambda a, b, c: local_fn(a, b, c, None),
             mesh=mesh,
             in_specs=(seq_spec, seq_spec, seq_spec),
             out_specs=seq_spec,
             check_vma=False,
         )(q, k, v)
-    return _shard_map(
+    return jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(seq_spec, seq_spec, seq_spec, bias_spec),

@@ -48,8 +48,8 @@ class Runner(LiveMetricsMixin):
         self.worker_manager = worker_manager
         # persistent XLA compile cache: a relaunched/re-formed trainer (or
         # a repeated run of the same config) reuses serialized executables
-        # instead of recompiling every stage program.  Opt out with
-        # SKYTPU_COMPILE_CACHE=0; silently a no-op when wiring fails.
+        # instead of recompiling every stage program.  Placement and the
+        # off switch: utils/compile_cache.py.
         self.compilation_cache_dir = enable_persistent_compilation_cache()
 
         self._hooks: List[Hook] = []

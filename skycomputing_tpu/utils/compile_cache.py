@@ -1,35 +1,35 @@
 """Persistent XLA compilation cache wiring.
 
 XLA recompilation is the single largest fixed cost of this framework's
-measurement-heavy workflows: a structure-cap-64 bench round once spent
-~50 minutes recompiling stage programs that earlier rounds had already
-built (see ``parallel/pipeline.py``'s program-cache notes).  The
-in-process jit cache cannot help across processes — but JAX's persistent
-compilation cache can: serialized executables keyed by (HLO, backend,
-flags) survive process exit, so a repeated bench/ladder run pays compile
-cost once per *program*, not once per *process*.
+measurement-heavy workflows, and on a fresh accelerator machine nothing
+compiled survives from one process to the next.  The in-process jit
+cache cannot help across processes — but JAX's persistent compilation
+cache can: serialized executables keyed by (HLO, backend, flags, cache
+path) survive process exit, so a repeated run pays compile cost once per
+*program*, not once per *process*.
 
-``enable_persistent_compilation_cache`` is the single entry point; the
-:class:`~..runner.runner.Runner` and ``bench.py`` both call it.  Knobs:
+``enable_persistent_compilation_cache`` is the single entry point;
+``chip_smoke.py``, ``bench.py``, ``experiment/launch.py`` and the
+:class:`~..runner.runner.Runner` all go through it.  Where the cache
+lives is decided from outside the program or not at all:
 
-- ``SKYTPU_COMPILE_CACHE``: ``0``/``off`` disables entirely (the opt-out);
-  any other non-empty value is used as the cache directory.  Unset means
-  the default ``~/.cache/skycomputing_tpu/xla-cache``.
-- ``SKYTPU_COMPILE_CACHE_MIN_S``: minimum backend-compile seconds for an
-  executable to be persisted (default 0.5 — trivial convert/broadcast
-  programs aren't worth the disk round trip; stage programs cost seconds
-  to minutes and always qualify).
+- ``JAX_COMPILATION_CACHE_DIR`` set: JAX itself reads it into
+  ``jax_compilation_cache_dir``; this module sets no directory in code
+  and reports that one.
+- unset: ``<checkout>/.jax_cache`` (git-ignored) — a fixed path, because
+  the path is part of the cache key and a directory that moves (a home
+  directory, a temp name, a pid) never hits.
+- ``SKYTPU_COMPILE_CACHE=0`` is the off switch;
+  ``SKYTPU_COMPILE_CACHE_MIN_S`` is the minimum backend-compile seconds
+  for an executable to be persisted (default 0.5 — trivial
+  convert/broadcast programs aren't worth the disk round trip).
 
-Failures (read-only filesystem, an ancient jax without the config knobs)
-degrade silently to no caching — the cache is an optimization, never a
-correctness dependency.
-
-On the CPU backend the cache is OFF unless a directory is passed (arg or
-env) explicitly: XLA:CPU executable serialization is not hardened in the
+On the CPU backend the cache stays OFF unless ``JAX_COMPILATION_CACHE_DIR``
+asks for it: XLA:CPU executable serialization is not hardened in the
 pinned jaxlib — merely enabling the cache under the test suite aborted
 the process with glibc heap corruption ("corrupted double-linked list"
-inside a donated optimizer update).  TPU/GPU serialization is the
-production-exercised path and stays on by default.
+inside a donated optimizer update).  TPU serialization is the
+production-exercised path and is on by default.
 """
 
 from __future__ import annotations
@@ -38,7 +38,10 @@ import os
 from typing import Optional
 
 DEFAULT_CACHE_DIR = os.path.join(
-    os.path.expanduser("~"), ".cache", "skycomputing_tpu", "xla-cache"
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    ".jax_cache",
 )
 
 _ACTIVE_DIR: Optional[str] = None
@@ -49,54 +52,39 @@ def compilation_cache_dir() -> Optional[str]:
     return _ACTIVE_DIR
 
 
-def enable_persistent_compilation_cache(
-    cache_dir: Optional[str] = None,
-    min_compile_time_s: Optional[float] = None,
-) -> Optional[str]:
-    """Point JAX's persistent compilation cache at a durable directory.
+def enable_persistent_compilation_cache() -> Optional[str]:
+    """Turn JAX's persistent compilation cache on at the one place it
+    may live (see the module docstring).
 
     Idempotent (the first successful call wins; later calls return the
     active directory).  Returns the active cache dir, or None when the
-    opt-out is set or wiring failed.
+    off switch is set or the CPU backend's default-off rule applies.
+    An unwritable directory raises: a cache the caller asked for and
+    did not get is a cold compile bill on every run, not a detail.
     """
     global _ACTIVE_DIR
-    env = os.environ.get("SKYTPU_COMPILE_CACHE")
-    env_flag = env.strip().lower() if env is not None else None
-    if env_flag in ("0", "off", "none", "false", "no", ""):
+    if os.environ.get("SKYTPU_COMPILE_CACHE", "").strip().lower() in (
+        "0", "off", "false", "no",
+    ):
         return None
     if _ACTIVE_DIR is not None:
         return _ACTIVE_DIR
-    # boolean-ish spellings mean "enable with the default dir", not a
-    # directory literally named "true" — only a real path is an explicit
-    # opt-in (which is what unlocks the cache on the CPU backend below)
-    env_is_path = env is not None and env_flag not in ("1", "on", "true",
-                                                       "yes")
-    explicit = cache_dir is not None or env_is_path
-    if cache_dir is None:
-        cache_dir = env if env_is_path else DEFAULT_CACHE_DIR
-    if min_compile_time_s is None:
-        min_compile_time_s = float(
-            os.environ.get("SKYTPU_COMPILE_CACHE_MIN_S", "0.5")
-        )
-    try:
-        import jax
+    import jax
 
-        if jax.default_backend() == "cpu" and not explicit:
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        if jax.default_backend() == "cpu":
             # unsafe by default on this backend — see module docstring
             return None
+        cache_dir = DEFAULT_CACHE_DIR
         os.makedirs(cache_dir, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs",
-            float(min_compile_time_s),
-        )
-        try:
-            # -1: no size floor — the time floor above is the filter
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        except Exception:
-            pass  # knob appeared in later jax; the default is fine
-    except Exception:
-        return None
+    jax.config.update(
+        "jax_persistent_cache_min_compile_time_secs",
+        float(os.environ.get("SKYTPU_COMPILE_CACHE_MIN_S", "0.5")),
+    )
+    # -1: no size floor — the time floor above is the filter
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     _ACTIVE_DIR = cache_dir
     return cache_dir
 

@@ -16,7 +16,7 @@ class Logger:
     single-controller TPU runtime uses by default.
 
     Levels: ``info`` keeps the historical byte format (``[ts] message`` —
-    log-scraping tests and tools/tpu_watch.py parse it); ``warning`` and
+    log-scraping tests parse it); ``warning`` and
     ``error`` insert their level tag after the timestamp.  ``utc=True``
     switches the timestamp to ISO-8601 UTC (``2026-08-04T12:00:00Z``) —
     the format multi-region fleets need, where per-node local clocks make

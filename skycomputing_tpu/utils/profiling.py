@@ -29,19 +29,10 @@ def annotate(name: str):
     return jax.profiler.TraceAnnotation(name)
 
 
-def normalize_cost_analysis(cost) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` across jax versions: some return one
-    dict, some a one-element list of dicts (per computation), some None
-    on backends without a cost model — always hand back a dict."""
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
-
-
 def compiled_cost(fn, *args) -> Dict[str, float]:
     """XLA's cost model for jitted ``fn`` at these args: flops, bytes, time."""
     compiled = jax.jit(fn).lower(*args).compile()
-    cost = normalize_cost_analysis(compiled.cost_analysis())
+    cost = compiled.cost_analysis()
     out = {
         "flops": float(cost.get("flops", 0.0)),
         "bytes_accessed": float(cost.get("bytes accessed", 0.0)),
@@ -55,4 +46,4 @@ def compiled_cost(fn, *args) -> Dict[str, float]:
     return out
 
 
-__all__ = ["trace", "annotate", "compiled_cost", "normalize_cost_analysis"]
+__all__ = ["trace", "annotate", "compiled_cost"]

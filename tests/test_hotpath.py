@@ -15,6 +15,7 @@ from skycomputing_tpu.parallel.pipeline import (
     HOTPATH,
     device_put_elided,
     hotpath_counters,
+    xla_compile_count,
 )
 from tests.test_pipeline import build_pipeline
 
@@ -144,6 +145,25 @@ def test_forced_donation_matches_undonated(devices):
         pl._DONATE[0] = old
 
 
+def test_compile_count_skips_persistent_cache_hits():
+    """jax 0.9 emits the backend-compile duration event around the
+    persistent-cache lookup too, so a program the cache served would read
+    as a compile (the first chip run counted 270 'compiles' on a cold
+    and on a warm cache alike).  The cache's own hit event, emitted just
+    before, must cancel it — and only it."""
+    from jax import monitoring
+
+    compile_event = "/jax/core/compile/backend_compile_duration"
+    base = xla_compile_count()
+    monitoring.record_event_duration_secs(compile_event, 0.25)
+    assert xla_compile_count() == base + 1
+    monitoring.record_event("/jax/compilation_cache/cache_hits")
+    monitoring.record_event_duration_secs(compile_event, 0.01)
+    assert xla_compile_count() == base + 1  # served, not compiled
+    monitoring.record_event_duration_secs(compile_event, 0.25)
+    assert xla_compile_count() == base + 2
+
+
 def test_compilation_cache_opt_out(monkeypatch):
     from skycomputing_tpu.utils import compile_cache
 
@@ -152,26 +172,24 @@ def test_compilation_cache_opt_out(monkeypatch):
 
 
 def test_compilation_cache_defaults_off_on_cpu(monkeypatch):
-    """No explicit directory -> no caching on the CPU backend (XLA:CPU
-    executable serialization is not safe in the pinned jaxlib)."""
+    """No JAX_COMPILATION_CACHE_DIR -> no caching on the CPU backend
+    (XLA:CPU executable serialization is not safe in the pinned
+    jaxlib)."""
     from skycomputing_tpu.utils import compile_cache
 
     monkeypatch.delenv("SKYTPU_COMPILE_CACHE", raising=False)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     assert jax.default_backend() == "cpu"
     assert compile_cache.enable_persistent_compilation_cache() is None
     assert compile_cache.compilation_cache_dir() is None
 
 
-def test_compilation_cache_explicit_path_is_honored(monkeypatch, tmp_path):
-    """An explicit directory is an opt-in on any backend: the helper must
-    resolve it (without enabling jax-level caching in THIS process — the
-    global config is process-wide, and CPU serialization is unsafe to
-    actually exercise here, so only the decision logic is probed)."""
-    from skycomputing_tpu.utils import compile_cache
+def _fake_jax(monkeypatch, backend):
+    """Stand-in ``jax`` recording config updates: the global config is
+    process-wide, and CPU serialization is unsafe to actually exercise
+    here, so only the placement decision is probed."""
+    import sys as _sys
 
-    target = tmp_path / "xla-cache"
-    monkeypatch.setenv("SKYTPU_COMPILE_CACHE", str(target))
-    monkeypatch.setattr(compile_cache, "_ACTIVE_DIR", None)
     recorded = {}
 
     class _FakeConfig:
@@ -184,15 +202,26 @@ def test_compilation_cache_explicit_path_is_honored(monkeypatch, tmp_path):
 
         @staticmethod
         def default_backend():
-            return "cpu"
-
-    import sys as _sys
+            return backend
 
     monkeypatch.setitem(_sys.modules, "jax", _FakeJax)
+    return recorded
+
+
+def test_compilation_cache_env_dir_is_the_only_dir(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR is JAX's own knob: the helper reports
+    it and sets NO directory in code, on any backend."""
+    from skycomputing_tpu.utils import compile_cache
+
+    target = tmp_path / "xla-cache"
+    monkeypatch.delenv("SKYTPU_COMPILE_CACHE", raising=False)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(target))
+    monkeypatch.setattr(compile_cache, "_ACTIVE_DIR", None)
+    recorded = _fake_jax(monkeypatch, "cpu")
     try:
         out = compile_cache.enable_persistent_compilation_cache()
     finally:
         monkeypatch.setattr(compile_cache, "_ACTIVE_DIR", None)
     assert out == str(target)
-    assert recorded["jax_compilation_cache_dir"] == str(target)
-    assert target.is_dir()
+    assert "jax_compilation_cache_dir" not in recorded
+    assert not target.exists()  # JAX creates its own directory

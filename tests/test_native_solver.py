@@ -157,3 +157,38 @@ def test_large_native_respects_memory_and_infeasible():
     with pytest.raises(RuntimeError, match="infeasible"):
         solve_large_native(costs, mem, dt, [0.5] * 24, seed=0, rounds=1,
                            evals0=200, wall_cap_s=5.0)
+
+
+@needs_native
+def test_library_is_verified_against_source_hash_not_trusted(
+        tmp_path, monkeypatch):
+    """A library lying at the fixed path is used only when the stamp
+    beside it matches the current source AND the library bytes — a stray,
+    stale or altered binary is rebuilt from ``solver.cpp`` (the chip tool
+    copies the tree as it stands on disk, ignored files included, and a
+    copy keeps no trustworthy mtimes)."""
+    import shutil
+
+    from skycomputing_tpu.dynamics import native
+
+    lib = tmp_path / "libskytpu_solver.so"
+    src = tmp_path / "solver.cpp"
+    shutil.copy(native._SRC, src)
+    monkeypatch.setattr(native, "_SRC", str(src))
+    monkeypatch.setattr(native, "_LIB", str(lib))
+    monkeypatch.setattr(native, "_STAMP", str(lib) + ".sha256")
+
+    lib.write_bytes(b"not a library")  # stray binary, no stamp
+    assert not native._is_current()
+    native._build()
+    assert native._is_current()
+    assert lib.read_bytes()[:4] == b"\x7fELF"
+
+    built = lib.read_bytes()
+    lib.write_bytes(built + b"\0")  # altered library, stamp untouched
+    assert not native._is_current()
+    lib.write_bytes(built)
+    assert native._is_current()
+    src.write_text(src.read_text() + "\n// edited\n")  # newer source
+    assert not native._is_current()
+    assert [p.name for p in tmp_path.iterdir() if ".tmp" in p.name] == []

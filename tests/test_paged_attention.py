@@ -108,6 +108,11 @@ def test_kernel_int8_dequant_matches_reference():
 # int8 write-time quantization (the scale slab's contract)
 # --------------------------------------------------------------------------
 
+# jitted as the engine runs them (one compile per shape, shared by the
+# tests below) — eager op-by-op dispatch made these the file's slowest
+_update_kv = jax.jit(paged_update_kv)
+_gather_kv = jax.jit(gather_kv_pages)
+
 
 def test_int8_update_bounded_error_and_midpage_valid():
     """Quantize-on-write round-trips within int8 error bounds, a
@@ -130,12 +135,12 @@ def test_int8_update_bounded_error_and_midpage_valid():
     index = np.array([0, 0], np.int32)
     valid = np.array([9, 5], np.int32)
     args = (jnp.asarray(table), jnp.asarray(index), jnp.asarray(valid))
-    kq2, vq2 = paged_update_kv(kq, vq, jnp.asarray(knew),
+    kq2, vq2 = _update_kv(kq, vq, jnp.asarray(knew),
                                jnp.asarray(vnew), *args)
-    kf2, vf2 = paged_update_kv(kf, vf, jnp.asarray(knew),
+    kf2, vf2 = _update_kv(kf, vf, jnp.asarray(knew),
                                jnp.asarray(vnew), *args)
-    gq, _ = gather_kv_pages(kq2, vq2, jnp.asarray(table))
-    gf, _ = gather_kv_pages(kf2, vf2, jnp.asarray(table))
+    gq, _ = _gather_kv(kq2, vq2, jnp.asarray(table))
+    gf, _ = _gather_kv(kf2, vf2, jnp.asarray(table))
     for r in range(R):
         n = int(valid[r])
         ref = np.asarray(gf)[r, :n]
@@ -162,20 +167,20 @@ def test_int8_append_keeps_scale_monotone():
     # big first token, then small appends: amax would SHRINK without
     # the monotone floor and re-quantize the first token coarsely
     big = 8.0 * rng.standard_normal((1, 1, H, D)).astype(np.float32)
-    kq, vq = paged_update_kv(
+    kq, vq = _update_kv(
         kq, vq, jnp.asarray(big), jnp.asarray(big),
         jnp.asarray(table), jnp.asarray([0]), jnp.asarray([1]),
     )
     scale_after_big = np.asarray(kq.scale[4]).copy()
     small = 0.01 * rng.standard_normal((1, 1, H, D)).astype(np.float32)
     for step in range(1, 4):
-        kq, vq = paged_update_kv(
+        kq, vq = _update_kv(
             kq, vq, jnp.asarray(small), jnp.asarray(small),
             jnp.asarray(table), jnp.asarray([step]),
             jnp.asarray([step + 1]),
         )
     assert np.all(np.asarray(kq.scale[4]) >= scale_after_big - 1e-9)
-    gk, _ = gather_kv_pages(kq, vq, jnp.asarray(table))
+    gk, _ = _gather_kv(kq, vq, jnp.asarray(table))
     rel = np.max(np.abs(np.asarray(gk)[0, 0] - big[0, 0])) / np.max(
         np.abs(big)
     )

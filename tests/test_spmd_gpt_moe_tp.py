@@ -1,6 +1,6 @@
 """MoE x in-pipeline tensor parallelism in the compiled GPT engine.
 
-The last admitted composition hole (r03 ``docs/roadmap.md:28``): expert
+The last admitted composition hole (the r03 roadmap): expert
 tensors join the Megatron col/row role tables — w1/b1 column-shard the
 expert intermediate, w2 row-shards it with a psum, router/b2 replicate —
 so a tp-sharded MoE pipeline must reproduce the plain MoE pipeline's
