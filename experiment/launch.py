@@ -36,6 +36,7 @@ from skycomputing_tpu.ops import build_loss
 from skycomputing_tpu.parallel import PipelineModel
 from skycomputing_tpu.runner import Runner
 from skycomputing_tpu.stimulator import Stimulator
+from skycomputing_tpu.telemetry import trace_span
 from skycomputing_tpu.utils import (
     Logger,
     enable_persistent_compilation_cache,
@@ -48,7 +49,16 @@ def build_optimizer(optim_cfg: dict):
     return getattr(optax, name)(**cfg)
 
 
+def _phase(name: str, seconds: dict):
+    """One set-up phase: a ``sky.launch.<name>`` span (in the ring and in
+    a profiler trace when either is on) whose seconds land in
+    ``seconds`` for the launcher's own log line, always."""
+    return trace_span(f"sky.launch.{name}", "launch", "setup",
+                      seconds_into=seconds)
+
+
 def run(cfg, logger: Logger) -> int:
+    phases: dict = {}
     devices = jax.devices()
     logger.info(
         f"devices: {len(devices)} x {devices[0].platform} "
@@ -64,7 +74,8 @@ def run(cfg, logger: Logger) -> int:
     worker_manager.load_worker_pool_from_config(cfg.worker_config)
 
     # --- data ---------------------------------------------------------------
-    data_loader = build_dataloader_from_cfg(cfg.data_config)
+    with _phase("data", phases):
+        data_loader = build_dataloader_from_cfg(cfg.data_config)
 
     def batches():
         for data, labels in data_loader:
@@ -84,48 +95,55 @@ def run(cfg, logger: Logger) -> int:
             return batches()
 
     # --- parameter server (host copy of the full model) ---------------------
-    probe = next(iter(BatchAdapter()))
-    parameter_server = ParameterServer(
-        cfg.model_config, example_inputs=probe[0], rng=jax.random.key(0)
-    )
+    with _phase("parameter_server", phases):
+        probe = next(iter(BatchAdapter()))
+        parameter_server = ParameterServer(
+            cfg.model_config, example_inputs=probe[0], rng=jax.random.key(0)
+        )
     logger.info(f"parameter server: {parameter_server.num_layers} layers")
 
     # --- profiling + allocation ---------------------------------------------
-    bench_cfg = cfg.allocator_config["benchmark_config"]
-    model_bench = ModelBenchmarker(
-        cfg.model_config,
-        build_data_generator(**bench_cfg["model"]["data_generator_cfg"]),
-        param_scale=bench_cfg["model"].get("param_scale", 2),
-    )
-    stimulator = (
-        Stimulator(worker_manager.size)
-        if os.getenv("STIMULATE") is not None
-        else None
-    )
-    device_bench = DeviceBenchmarker(
-        worker_manager,
-        build_data_generator(**bench_cfg["device"]["data_generator_cfg"]),
-        bench_cfg["device"]["model_config"],
-        iterations=bench_cfg["device"].get("iterations", 10),
-        devices=devices,
-        stimulator=stimulator,
-    )
-    allocator = Allocator(
-        cfg.model_config, worker_manager, model_bench, device_bench,
-        logger=logger,
-    )
+    # (the profilers are built here and run inside the allocator: their
+    # ``allocator.profiles`` / ``bench.device`` / ``bench.model`` spans
+    # nest under ``sky.launch.allocate``)
+    with _phase("profile", phases):
+        bench_cfg = cfg.allocator_config["benchmark_config"]
+        model_bench = ModelBenchmarker(
+            cfg.model_config,
+            build_data_generator(**bench_cfg["model"]["data_generator_cfg"]),
+            param_scale=bench_cfg["model"].get("param_scale", 2),
+        )
+        stimulator = (
+            Stimulator(worker_manager.size)
+            if os.getenv("STIMULATE") is not None
+            else None
+        )
+        device_bench = DeviceBenchmarker(
+            worker_manager,
+            build_data_generator(
+                **bench_cfg["device"]["data_generator_cfg"]),
+            bench_cfg["device"]["model_config"],
+            iterations=bench_cfg["device"].get("iterations", 10),
+            devices=devices,
+            stimulator=stimulator,
+        )
+        allocator = Allocator(
+            cfg.model_config, worker_manager, model_bench, device_bench,
+            logger=logger,
+        )
 
     allocate_type = cfg.allocator_config["type"]
     logger.info(f"allocation strategy: {allocate_type}")
     try:
-        if allocate_type == "optimal":
-            allocator.optimal_allocate()
-        elif allocate_type == "dynamic":
-            allocator.dynamic_allocate()
-        elif allocate_type == "even":
-            allocator.even_allocate()
-        else:
-            raise ValueError(f"unknown ALLOCATE_TYPE {allocate_type!r}")
+        with _phase("allocate", phases):
+            if allocate_type == "optimal":
+                allocator.optimal_allocate()
+            elif allocate_type == "dynamic":
+                allocator.dynamic_allocate()
+            elif allocate_type == "even":
+                allocator.even_allocate()
+            else:
+                raise ValueError(f"unknown ALLOCATE_TYPE {allocate_type!r}")
     except Exception as exc:  # allocation failure -> clean exit, no training
         logger.info(f"allocation failed: {exc!r} — skipping training")
         return 1
@@ -137,27 +155,30 @@ def run(cfg, logger: Logger) -> int:
         )
 
     # --- pipeline + runner ---------------------------------------------------
-    model = PipelineModel(
-        worker_manager,
-        parameter_server,
-        build_optimizer(cfg.train_config["optim_cfg"]),
-        build_loss(cfg.train_config["loss_cfg"]),
-        devices=devices,
-        num_microbatches=getattr(cfg, "NUM_MICROBATCHES", 1),
-        schedule=getattr(cfg, "SCHEDULE", "gpipe"),
-    )
+    with _phase("build_pipeline", phases):
+        model = PipelineModel(
+            worker_manager,
+            parameter_server,
+            build_optimizer(cfg.train_config["optim_cfg"]),
+            build_loss(cfg.train_config["loss_cfg"]),
+            devices=devices,
+            num_microbatches=getattr(cfg, "NUM_MICROBATCHES", 1),
+            schedule=getattr(cfg, "SCHEDULE", "gpipe"),
+        )
 
-    runner = Runner(
-        model,
-        parameter_server,
-        worker_manager,
-        max_epochs=cfg.train_config["runner_cfg"]["max_epochs"],
-        max_iters=cfg.train_config["runner_cfg"]["max_iters"],
-        timer_cfg=cfg.train_config.get("timer_config"),
-        logging_cfg=cfg.logging_config,
-    )
-    for hook_cfg in cfg.train_config.get("hook_config", []):
-        runner.register_hook(build_hook(hook_cfg))
+        runner = Runner(
+            model,
+            parameter_server,
+            worker_manager,
+            max_epochs=cfg.train_config["runner_cfg"]["max_epochs"],
+            max_iters=cfg.train_config["runner_cfg"]["max_iters"],
+            timer_cfg=cfg.train_config.get("timer_config"),
+            logging_cfg=cfg.logging_config,
+        )
+        for hook_cfg in cfg.train_config.get("hook_config", []):
+            runner.register_hook(build_hook(hook_cfg))
+    setup = {k.rsplit(".", 1)[1]: round(v, 3) for k, v in phases.items()}
+    logger.info(f"set-up phases (s): {setup}")
 
     runner.train(BatchAdapter())
     summary = runner.phase_timer.summary()

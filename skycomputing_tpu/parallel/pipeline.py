@@ -45,7 +45,7 @@ import optax
 from ..builder import as_tuple, build_layer_stack
 from ..dynamics.parameter_server import ParameterServer
 from ..dynamics.worker_manager import WorkerManager
-from ..telemetry import get_tracer
+from ..telemetry import get_tracer, span_sinks
 
 
 # --- hot-path switches & counters -------------------------------------------
@@ -55,6 +55,11 @@ from ..telemetry import get_tracer
 # can measure the host-dispatch split of both paths in one report; the
 # optimized path is the default and the one CI exercises.
 HOTPATH = os.environ.get("SKYTPU_HOTPATH", "1") != "0"
+
+# the ring lane of the step's host-side spans (``sky.pipe.*``: the issue
+# loops, the barriers); per-stage ``fwd``/``bwd``/``update`` spans keep
+# their own ``stage k`` lanes
+_HOST_LANE = ("host", "dispatch")
 
 # Backward/accumulate donation is an accelerator optimization: on TPU/GPU
 # it cuts peak HBM (dead stage inputs and grad totals are reused in
@@ -796,57 +801,49 @@ class PipelineModel:
         last stage, capping per-stage live inputs at the pipeline depth
         instead of M.
         """
-        compiles0 = xla_compile_count()
-        copies0 = _TRANSFER_STATS["copies"]
-        elided0 = _TRANSFER_STATS["elided"]
-        programs0 = _DISPATCH_STATS["programs"]
-        puts0 = _DISPATCH_STATS["puts"]
-        grad_totals, losses, (t0, t1, t2) = self.compute_gradients(
-            data, labels, rng
-        )
-        self.apply_gradients(grad_totals)
-        t_upd_issued = time.perf_counter()
-        jax.block_until_ready(self.stages[0].params)
-        t3 = time.perf_counter()
+        sp = span_sinks()
+        host = sp.lane(*_HOST_LANE)
+        with sp.span("sky.pipe.step", host):
+            compiles0 = xla_compile_count()
+            copies0 = _TRANSFER_STATS["copies"]
+            elided0 = _TRANSFER_STATS["elided"]
+            programs0 = _DISPATCH_STATS["programs"]
+            puts0 = _DISPATCH_STATS["puts"]
+            grad_totals, losses, (t0, t1, t2) = self.compute_gradients(
+                data, labels, rng
+            )
+            self.apply_gradients(grad_totals)
+            t_upd_issued = time.perf_counter()
+            with sp.span("sky.pipe.wait", host, {"what": "update"}):
+                jax.block_until_ready(self.stages[0].params)
+            t3 = time.perf_counter()
 
-        dispatch_s = self._last_dispatch_s + (t_upd_issued - t2)
-        total_loss = float(sum(jax.device_get(l) for l in losses))
-        self.stats = PipelineStats(
-            forward_s=t1 - t0, backward_s=t2 - t1, step_s=t3 - t2,
-            loss=total_loss, interleaved=self._interleaved,
-            dispatch_s=dispatch_s,
-            compute_wait_s=max((t3 - t0) - dispatch_s, 0.0),
-            transfers=_TRANSFER_STATS["copies"] - copies0,
-            transfers_elided=_TRANSFER_STATS["elided"] - elided0,
-            compiles=xla_compile_count() - compiles0,
-            program_dispatches=_DISPATCH_STATS["programs"] - programs0,
-            put_dispatches=_DISPATCH_STATS["puts"] - puts0,
-        )
-        tracer = get_tracer()
-        if tracer is not None:
-            # one host-dispatch span per step on its own lane, so
-            # trace_report can attribute the step's dispatch share the
-            # same way PipelineStats does (the span's duration IS
-            # dispatch_s, placed ending now)
-            end = tracer.now()
-            tracer.complete(
-                "host_dispatch", tracer.lane("host", "dispatch"),
-                max(end - dispatch_s * 1e6, 0.0), dur_us=dispatch_s * 1e6,
+            dispatch_s = self._last_dispatch_s + (t_upd_issued - t2)
+            with sp.span("sky.pipe.loss_get", host):
+                total_loss = float(sum(jax.device_get(l) for l in losses))
+            self.stats = PipelineStats(
+                forward_s=t1 - t0, backward_s=t2 - t1, step_s=t3 - t2,
+                loss=total_loss, interleaved=self._interleaved,
+                dispatch_s=dispatch_s,
+                compute_wait_s=max((t3 - t0) - dispatch_s, 0.0),
+                transfers=_TRANSFER_STATS["copies"] - copies0,
+                transfers_elided=_TRANSFER_STATS["elided"] - elided0,
+                compiles=xla_compile_count() - compiles0,
+                program_dispatches=_DISPATCH_STATS["programs"] - programs0,
+                put_dispatches=_DISPATCH_STATS["puts"] - puts0,
             )
         return total_loss
 
-    def _trace_lanes(self):
-        """(tracer, per-stage lane list) — (None, None) when disabled.
+    def _span_lanes(self):
+        """(span sinks, the host lane, per-stage lane list).
 
-        Hoisted out of the issue loops: one accessor call and S lane
-        lookups per compute_gradients call, zero per microbatch.
+        Hoisted out of the issue loops: one ``span_sinks()`` and S + 1
+        lane lookups per compute_gradients / apply_gradients call, zero
+        per microbatch; with the ring off the lanes are all ``None``.
         """
-        tracer = get_tracer()
-        if tracer is None:
-            return None, None
-        return tracer, [
-            tracer.lane(stage.lane_name, "dispatch")
-            for stage in self.stages
+        sp = span_sinks()
+        return sp, sp.lane(*_HOST_LANE), [
+            sp.lane(stage.lane_name, "dispatch") for stage in self.stages
         ]
 
     @property
@@ -909,88 +906,89 @@ class PipelineModel:
             rng = jax.random.fold_in(jax.random.key(1), self._grad_call_count)
             self._grad_call_count += 1
         M = self.num_microbatches
-        micro_data = _split_microbatches(as_tuple(data), M)
-        micro_labels = _split_microbatches(labels, M)
         scale = 1.0 / M
-        tracer, lanes = self._trace_lanes()
+        sp, host, lanes = self._span_lanes()
 
-        t0 = time.perf_counter()
-
-        # ---- prefetch: issue every host->device input/label transfer up
-        # front so the copies ride the async queues UNDER the first
-        # microbatches' compute instead of serializing inside the loops
-        if HOTPATH:
-            first_device = self.stages[0].device
-            micro_data = [
-                device_put_elided(md, first_device) for md in micro_data
-            ]
-            micro_labels = [
-                device_put_elided(ml, self._last_device)
-                for ml in micro_labels
-            ]
+        # ---- prefetch: split, then issue every host->device input/label
+        # transfer up front so the copies ride the async queues UNDER the
+        # first microbatches' compute instead of serializing inside the loops
+        with sp.span("sky.pipe.prefetch", host):
+            micro_data = _split_microbatches(as_tuple(data), M)
+            micro_labels = _split_microbatches(labels, M)
+            t0 = time.perf_counter()
+            if HOTPATH:
+                first_device = self.stages[0].device
+                micro_data = [
+                    device_put_elided(md, first_device) for md in micro_data
+                ]
+                micro_labels = [
+                    device_put_elided(ml, self._last_device)
+                    for ml in micro_labels
+                ]
 
         # ---- forward (fill): per microbatch, per stage; keep stage inputs
         stage_inputs: List[List[Tuple]] = [[] for _ in self.stages]
         final_acts_per_mb: List[Tuple] = []
-        rngs = self._step_rngs(rng, M, len(self.stages))
-        for m in range(M):
-            acts = micro_data[m]
-            for k, stage in enumerate(self.stages):
-                acts = device_put_elided(acts, stage.device)
-                stage_inputs[k].append(acts)
-                if tracer is None:
-                    acts = stage.forward_placed(acts, rngs[m][k])
-                else:
-                    span0 = tracer.now()
-                    acts = stage.forward_placed(acts, rngs[m][k])
-                    tracer.complete("fwd", lanes[k], span0, {"mb": m})
-            final_acts_per_mb.append(acts)
+        with sp.span("sky.pipe.rng", host):
+            rngs = self._step_rngs(rng, M, len(self.stages))
+        with sp.span("sky.pipe.fwd_issue", host):
+            for m in range(M):
+                acts = micro_data[m]
+                for k, stage in enumerate(self.stages):
+                    acts = device_put_elided(acts, stage.device)
+                    stage_inputs[k].append(acts)
+                    with sp.span("sky.pipe.fwd", lanes[k],
+                                 {"stage": k, "mb": m}, ring="fwd"):
+                        acts = stage.forward_placed(acts, rngs[m][k])
+                final_acts_per_mb.append(acts)
         dispatch_s = time.perf_counter() - t0
         if block:
-            jax.block_until_ready(final_acts_per_mb[-1])
+            with sp.span("sky.pipe.wait", host, {"what": "fwd"}):
+                jax.block_until_ready(final_acts_per_mb[-1])
         t1 = time.perf_counter()
 
         # ---- loss + backward (drain), accumulating grads per stage
         grad_totals: List[Any] = [None] * len(self.stages)
         losses = []
-        for m in reversed(range(M)):
-            labels_m = device_put_elided(micro_labels[m], self._last_device)
-            final_acts = final_acts_per_mb[m]
-            loss_m, dlogits = self._loss_dispatch(
-                final_acts[0], labels_m, scale
-            )
-            losses.append(loss_m)
-            dy: Optional[Tuple] = (dlogits,) + self._zero_tail(final_acts)
-            for k in reversed(range(len(self.stages))):
-                stage = self.stages[k]
-                if tracer is None:
-                    grad_totals[k], dx = stage.backward_accumulate(
-                        grad_totals[k], stage_inputs[k][m], rngs[m][k], dy
+        with sp.span("sky.pipe.bwd_issue", host):
+            for m in reversed(range(M)):
+                final_acts = final_acts_per_mb[m]
+                with sp.span("sky.pipe.loss", host, {"mb": m}):
+                    labels_m = device_put_elided(
+                        micro_labels[m], self._last_device
                     )
-                else:
-                    span0 = tracer.now()
-                    grad_totals[k], dx = stage.backward_accumulate(
-                        grad_totals[k], stage_inputs[k][m], rngs[m][k], dy
+                    loss_m, dlogits = self._loss_dispatch(
+                        final_acts[0], labels_m, scale
                     )
-                    tracer.complete("bwd", lanes[k], span0, {"mb": m})
-                dy = dx
+                    losses.append(loss_m)
+                    dy: Optional[Tuple] = (
+                        (dlogits,) + self._zero_tail(final_acts)
+                    )
+                for k in reversed(range(len(self.stages))):
+                    stage = self.stages[k]
+                    with sp.span("sky.pipe.bwd", lanes[k],
+                                 {"stage": k, "mb": m}, ring="bwd"):
+                        grad_totals[k], dx = stage.backward_accumulate(
+                            grad_totals[k], stage_inputs[k][m],
+                            rngs[m][k], dy
+                        )
+                    dy = dx
         dispatch_s += time.perf_counter() - t1
         self._last_dispatch_s = dispatch_s
         if block:
-            jax.block_until_ready(grad_totals[0])
+            with sp.span("sky.pipe.wait", host, {"what": "bwd"}):
+                jax.block_until_ready(grad_totals[0])
         t2 = time.perf_counter()
         return grad_totals, losses, (t0, t1, t2)
 
     def apply_gradients(self, grad_totals) -> None:
         """Apply per-stage gradient totals with each stage's optimizer."""
-        tracer, lanes = self._trace_lanes()
-        for k, stage in enumerate(self.stages):
-            if tracer is None:
-                stage.apply_gradients(grad_totals[k])
-            else:
-                span0 = tracer.now()
-                stage.apply_gradients(grad_totals[k])
-                tracer.complete("update", lanes[k], span0)
+        sp, host, lanes = self._span_lanes()
+        with sp.span("sky.pipe.update_issue", host):
+            for k, stage in enumerate(self.stages):
+                with sp.span("sky.pipe.update", lanes[k], {"stage": k},
+                             ring="update"):
+                    stage.apply_gradients(grad_totals[k])
 
     def _compute_gradients_1f1b(self, data, labels, rng, block: bool = True):
         """One-forward-one-backward schedule: issue each microbatch's
@@ -1007,25 +1005,29 @@ class PipelineModel:
             self._grad_call_count += 1
         M = self.num_microbatches
         S = len(self.stages)
-        micro_data = _split_microbatches(as_tuple(data), M)
-        micro_labels = _split_microbatches(labels, M)
         scale = 1.0 / M
-        tracer, lanes = self._trace_lanes()
+        sp, host, lanes = self._span_lanes()
 
-        rngs = self._step_rngs(rng, M, S)
+        with sp.span("sky.pipe.prefetch", host):
+            micro_data = _split_microbatches(as_tuple(data), M)
+            micro_labels = _split_microbatches(labels, M)
+        with sp.span("sky.pipe.rng", host):
+            rngs = self._step_rngs(rng, M, S)
 
         t0 = time.perf_counter()
         # prefetch (see the GPipe path): inputs to stage 0, labels to the
         # last stage, all issued before the first forward
         if HOTPATH:
-            first_device = self.stages[0].device
-            micro_data = [
-                device_put_elided(md, first_device) for md in micro_data
-            ]
-            micro_labels = [
-                device_put_elided(ml, self._last_device)
-                for ml in micro_labels
-            ]
+            with sp.span("sky.pipe.prefetch", host):
+                first_device = self.stages[0].device
+                micro_data = [
+                    device_put_elided(md, first_device)
+                    for md in micro_data
+                ]
+                micro_labels = [
+                    device_put_elided(ml, self._last_device)
+                    for ml in micro_labels
+                ]
         # live state
         stage_inputs: List[Dict[int, Tuple]] = [dict() for _ in range(S)]
         stage_outputs: List[Dict[int, Tuple]] = [dict() for _ in range(S)]
@@ -1055,41 +1057,40 @@ class PipelineModel:
             acts = (
                 micro_data[m] if k == 0 else stage_outputs[k - 1].pop(m)
             )
-            acts = device_put_elided(acts, stage.device)
-            stage_inputs[k][m] = acts
-            if tracer is None:
-                out = stage.forward_placed(acts, rngs[m][k])
-            else:
-                span0 = tracer.now()
-                out = stage.forward_placed(acts, rngs[m][k])
-                tracer.complete("fwd", lanes[k], span0, {"mb": m})
+            with sp.span("sky.pipe.fwd_issue", host):
+                acts = device_put_elided(acts, stage.device)
+                stage_inputs[k][m] = acts
+                with sp.span("sky.pipe.fwd", lanes[k],
+                             {"stage": k, "mb": m}, ring="fwd"):
+                    out = stage.forward_placed(acts, rngs[m][k])
             if k < S - 1:
                 stage_outputs[k][m] = out
             else:
-                labels_m = device_put_elided(
-                    micro_labels[m], self._last_device
-                )
-                loss_m, dlogits = self._loss_dispatch(
-                    out[0], labels_m, scale
-                )
-                losses.append(loss_m)
-                dys[k][m] = (dlogits,) + self._zero_tail(out)
+                # the loss opens the microbatch's backward: it is issued
+                # here, the moment the last stage's forward is, and
+                # belongs to the backward's issue time
+                with sp.span("sky.pipe.bwd_issue", host), \
+                        sp.span("sky.pipe.loss", host, {"mb": m}):
+                    labels_m = device_put_elided(
+                        micro_labels[m], self._last_device
+                    )
+                    loss_m, dlogits = self._loss_dispatch(
+                        out[0], labels_m, scale
+                    )
+                    losses.append(loss_m)
+                    dys[k][m] = (dlogits,) + self._zero_tail(out)
             fwd_next[k] += 1
 
         def do_bwd(k):
             m = bwd_next[k]
             stage = self.stages[k]
             dy = dys[k].pop(m) if k == S - 1 else dys[k + 1].pop(m)
-            if tracer is None:
+            with sp.span("sky.pipe.bwd_issue", host), \
+                    sp.span("sky.pipe.bwd", lanes[k],
+                            {"stage": k, "mb": m}, ring="bwd"):
                 grad_totals[k], dx = stage.backward_accumulate(
                     grad_totals[k], stage_inputs[k].pop(m), rngs[m][k], dy
                 )
-            else:
-                span0 = tracer.now()
-                grad_totals[k], dx = stage.backward_accumulate(
-                    grad_totals[k], stage_inputs[k].pop(m), rngs[m][k], dy
-                )
-                tracer.complete("bwd", lanes[k], span0, {"mb": m})
             if k > 0:
                 dys[k][m] = dx
             bwd_next[k] += 1
@@ -1118,7 +1119,8 @@ class PipelineModel:
 
         self._last_dispatch_s = time.perf_counter() - t0
         if block:
-            jax.block_until_ready(grad_totals[0])
+            with sp.span("sky.pipe.wait", host, {"what": "bwd"}):
+                jax.block_until_ready(grad_totals[0])
         t2 = time.perf_counter()
         # fused fwd/bwd: report (t0, t2, t2) so forward_s carries the whole
         # interleaved time and backward_s reads 0, as the stats contract

@@ -19,7 +19,12 @@ import jax
 from ..dynamics import ParameterServer, WorkerManager
 from ..ops import build_loss
 from ..parallel import PipelineModel
-from ..telemetry import LiveMetricsMixin, MetricsRegistry, trace_span
+from ..telemetry import (
+    LiveMetricsMixin,
+    MetricsRegistry,
+    span_sinks,
+    trace_span,
+)
 from ..utils import (
     DistributedTimer,
     Logger,
@@ -27,6 +32,8 @@ from ..utils import (
     enable_persistent_compilation_cache,
 )
 from .hooks import Hook
+
+_EXHAUSTED = object()  # the loader has no further batch
 
 
 class Runner(LiveMetricsMixin):
@@ -250,52 +257,21 @@ class Runner(LiveMetricsMixin):
             self._inner_iter = 0
             exhausted = True
 
-            for data, labels in data_loader:
-                if self._iter >= self._max_iters or self._stop:
-                    exhausted = False
-                    break
-
-                self._logger.info(
-                    f"epoch: {self._epoch}, iter: {self._iter}"
-                )
-                self.current_batch = (data, labels)
-                self._preflight(data)
-                self._call_hook("before_train_iter")
-
-                self._rng, step_rng = jax.random.split(self._rng)
-                self._timer.add_timestamp()
-                loss = self.model.train_step(data, labels, rng=step_rng)
-                self._timer.add_timestamp()
-
-                stats = self.model.stats
-                self.phase_timer.record("forward", stats.forward_s)
-                self.phase_timer.record("backward", stats.backward_s)
-                self.phase_timer.record("step", stats.step_s)
-                self.phase_timer.record("dispatch", stats.dispatch_s)
-                overhead = (
-                    f" | dispatch: {stats.dispatch_s:.4f} "
-                    f"(copies {stats.transfers}, elided "
-                    f"{stats.transfers_elided}, compiles {stats.compiles})"
-                )
-                if stats.interleaved:
-                    self._logger.info(
-                        f"loss: {loss:.6f} | fwd+bwd (fused, 1f1b): "
-                        f"{stats.forward_s:.4f} | step time: "
-                        f"{stats.step_s:.4f}{overhead}"
-                    )
-                else:
-                    self._logger.info(
-                        f"loss: {loss:.6f} | forward time: "
-                        f"{stats.forward_s:.4f} | backward time: "
-                        f"{stats.backward_s:.4f} | step time: "
-                        f"{stats.step_s:.4f}{overhead}"
-                    )
-
-                self._iter += 1
-                self._inner_iter += 1
-                if self.timeseries is not None:
-                    self.timeseries.sample()
-                self._call_hook("after_train_iter")
+            batches = iter(data_loader)
+            while True:
+                # the sinks are looked up once per iteration: a profiler
+                # that starts mid-iteration is seen from the next one on
+                sp = span_sinks()
+                lane = sp.lane("runner", "loop")
+                with sp.span("sky.runner.iter", lane, {"iter": self._iter}):
+                    with sp.span("sky.runner.data", lane):
+                        batch = next(batches, _EXHAUSTED)
+                    if batch is _EXHAUSTED:
+                        break
+                    if self._iter >= self._max_iters or self._stop:
+                        exhausted = False
+                        break
+                    self._train_iter(sp, lane, *batch)
 
             if not exhausted:
                 # max_iters / stop interrupted the epoch mid-stream: the
@@ -308,6 +284,58 @@ class Runner(LiveMetricsMixin):
             self._call_hook("after_train_epoch")
             if self._iter >= self._max_iters:
                 break
+
+    def _train_iter(self, sp, lane, data, labels) -> None:
+        """One iteration under ``sky.runner.iter``: log line, pre-flight,
+        hooks, the step, the step's stats and log line, hooks."""
+        with sp.span("sky.runner.log", lane):
+            self._logger.info(f"epoch: {self._epoch}, iter: {self._iter}")
+        self.current_batch = (data, labels)
+        self._preflight(data)
+        with sp.span("sky.runner.hooks", lane,
+                     {"point": "before_train_iter"}):
+            self._call_hook("before_train_iter")
+
+        with sp.span("sky.runner.rng", lane):
+            self._rng, step_rng = jax.random.split(self._rng)
+        with sp.span("sky.runner.timer", lane):
+            self._timer.add_timestamp()
+        loss = self.model.train_step(data, labels, rng=step_rng)
+        with sp.span("sky.runner.timer", lane):
+            self._timer.add_timestamp()
+
+        with sp.span("sky.runner.log", lane):
+            stats = self.model.stats
+            self.phase_timer.record("forward", stats.forward_s)
+            self.phase_timer.record("backward", stats.backward_s)
+            self.phase_timer.record("step", stats.step_s)
+            self.phase_timer.record("dispatch", stats.dispatch_s)
+            overhead = (
+                f" | dispatch: {stats.dispatch_s:.4f} "
+                f"(copies {stats.transfers}, elided "
+                f"{stats.transfers_elided}, compiles {stats.compiles})"
+            )
+            if stats.interleaved:
+                self._logger.info(
+                    f"loss: {loss:.6f} | fwd+bwd (fused, 1f1b): "
+                    f"{stats.forward_s:.4f} | step time: "
+                    f"{stats.step_s:.4f}{overhead}"
+                )
+            else:
+                self._logger.info(
+                    f"loss: {loss:.6f} | forward time: "
+                    f"{stats.forward_s:.4f} | backward time: "
+                    f"{stats.backward_s:.4f} | step time: "
+                    f"{stats.step_s:.4f}{overhead}"
+                )
+
+        self._iter += 1
+        self._inner_iter += 1
+        if self.timeseries is not None:
+            self.timeseries.sample()
+        with sp.span("sky.runner.hooks", lane,
+                     {"point": "after_train_iter"}):
+            self._call_hook("after_train_iter")
 
     # --- evaluation ----------------------------------------------------------
     def evaluate(

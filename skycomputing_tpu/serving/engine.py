@@ -78,7 +78,12 @@ from ..parallel.pipeline import (
     device_put_elided,
     xla_compile_count,
 )
-from ..telemetry import LiveMetricsMixin, MetricsRegistry, get_tracer
+from ..telemetry import (
+    LiveMetricsMixin,
+    MetricsRegistry,
+    get_tracer,
+    span_sinks,
+)
 from .batcher import (
     AdmissionQueue,
     FAILED,
@@ -902,6 +907,11 @@ class ServingEngine(LiveMetricsMixin):
         # replica overwrites this with its replica name so a migrated
         # request's waterfall says WHERE each segment ran
         self.trace_name = "engine"
+        # the span sinks of the current step() and the ring lane of its
+        # engine-level spans: looked up once at the top of every step,
+        # used by every phase under it
+        self._sp = span_sinks()
+        self._eng_lane = None
         # live observability (LiveMetricsMixin: enable_timeseries /
         # start_exporter — opt-in, zero-cost until enabled; step()
         # samples the series when one is attached)
@@ -1783,34 +1793,41 @@ class ServingEngine(LiveMetricsMixin):
         here, between decode steps — iteration-level scheduling; the
         chunk budget bounds how much prefill any single decode tick
         can wait behind."""
-        if self._queue.depth > 0 and self.free_slots == 0:
-            self.stats.queue_stalls += 1
-            tracer = get_tracer()
-            if tracer is not None:
-                tracer.instant(
-                    "queue_stall", tracer.lane("serving", "engine"),
-                    {"queued": self._queue.depth},
-                )
-        if self._paged:
-            self._admit_paged()
-            if self._chunk_policy is not None:
-                self._chunk_tick()
-            if self.spec_k > 0 and self._draft is not None:
-                self._spec_tick()
+        sp = self._sp = span_sinks()
+        eng = self._eng_lane = sp.lane("serving", "engine")
+        with sp.span("sky.serve.step", eng,
+                     {"iter": self.stats.iterations}):
+            with sp.span("sky.serve.admit", eng):
+                if self._queue.depth > 0 and self.free_slots == 0:
+                    self.stats.queue_stalls += 1
+                    if sp.tracer is not None:
+                        sp.tracer.instant(
+                            "queue_stall", eng,
+                            {"queued": self._queue.depth},
+                        )
+                if self._paged:
+                    self._admit_paged()
+                else:
+                    self._admit()
+            if self._paged:
+                if self._chunk_policy is not None:
+                    self._chunk_tick()
+                if self.spec_k > 0 and self._draft is not None:
+                    self._spec_tick()
+                else:
+                    self._decode_tick_paged()
             else:
-                self._decode_tick_paged()
-        else:
-            self._admit()
-            self._decode_tick()
-        self.stats.iterations += 1
-        self.stats.queue_depth = self._queue.depth
-        self.stats.batch_occupancy = self.stages[0].pool.occupancy
-        if self._paged:
-            self._sync_paged_stats()
-        if self.timeseries is not None:
-            self.timeseries.sample()
-        if self.autotuner is not None:
-            self.autotuner.on_step(self)
+                self._decode_tick()
+            with sp.span("sky.serve.sync", eng):
+                self.stats.iterations += 1
+                self.stats.queue_depth = self._queue.depth
+                self.stats.batch_occupancy = self.stages[0].pool.occupancy
+                if self._paged:
+                    self._sync_paged_stats()
+                if self.timeseries is not None:
+                    self.timeseries.sample()
+                if self.autotuner is not None:
+                    self.autotuner.on_step(self)
 
     def _sync_paged_stats(self) -> None:
         """Mirror the page pool's counters/gauges into ``ServingStats``
@@ -2407,154 +2424,152 @@ class ServingEngine(LiveMetricsMixin):
     def _prefill_wave(self, wave: List[Request]) -> None:
         bucket = wave[0].bucket
         rows = self.prefill_batch
-        ids, lengths = self.bucketer.pad_batch(
-            [r.effective_prompt for r in wave], bucket, rows, self.pad_id
-        )
-        # sentinel = num_slots: padding rows scatter out of range -> drop
-        slot_ids = np.full((rows,), self.num_slots, np.int32)
-        for i, r in enumerate(wave):
-            slot = self._allocate_slot()
-            assert slot is not None  # next_wave capped by free_slots
-            r.slot = slot
-            slot_ids[i] = slot
+        sp, eng = self._sp, self._eng_lane
+        tracer = sp.tracer
+        # tokens (true, un-padded) ride along so trace analysis can
+        # compute per-bucket padding waste — the skewed-bucket
+        # signature the autotuner acts on; the member request ids (ring
+        # only) make the wave attributable from the engine lane too
+        wave_tokens = int(sum(int(r.effective_prompt.size) for r in wave))
+        wave_args = {"bucket": bucket, "wave": len(wave),
+                     "tokens": wave_tokens}
+        with sp.span("sky.serve.prefill", eng, wave_args):
+            with sp.span("sky.serve.build", eng):
+                ids, lengths = self.bucketer.pad_batch(
+                    [r.effective_prompt for r in wave], bucket, rows,
+                    self.pad_id
+                )
+                # sentinel = num_slots: padding rows scatter out of
+                # range -> drop
+                slot_ids = np.full((rows,), self.num_slots, np.int32)
+                for i, r in enumerate(wave):
+                    slot = self._allocate_slot()
+                    assert slot is not None  # next_wave capped by free_slots
+                    r.slot = slot
+                    slot_ids[i] = slot
 
-        tracer = get_tracer()
-        span0 = tracer.now() if tracer is not None else 0.0
-        t0 = time.perf_counter()
-        compiles0 = xla_compile_count()
-        data: Any = ids
-        for st in self.stages:
-            data = device_put_elided(data, st.device)
-            sids = device_put_elided(slot_ids, st.device)
-            if tracer is None:
-                data, st.pool.slabs = st._prefill_donated(
-                    st.params, data, st.pool.slabs, sids
-                )
-            else:
-                stage0 = tracer.now()
-                data, st.pool.slabs = st._prefill_donated(
-                    st.params, data, st.pool.slabs, sids
-                )
-                tracer.complete(
-                    "prefill", tracer.lane(st.lane_name, "dispatch"),
-                    stage0, {"bucket": bucket},
-                )
-        pos = device_put_elided(lengths - 1, self._last_device)
-        logits = _gather_last(data, pos)  # [rows, V]
-        tokens = _argmax_tokens(logits)
-        jax.block_until_ready(tokens)
-        now = time.perf_counter()
-        self.stats.prefill_s += now - t0
-        wave_tokens = int(lengths[: len(wave)].sum())
-        if tracer is not None:
-            end_us = tracer.now()
-            # tokens (true, un-padded) ride along so trace analysis can
-            # compute per-bucket padding waste — the skewed-bucket
-            # signature the autotuner acts on; the member request ids
-            # make the wave attributable from the engine lane too
-            tracer.complete(
-                "prefill", tracer.lane("serving", "engine"), span0,
-                {"bucket": bucket, "wave": len(wave),
-                 "tokens": wave_tokens,
-                 "requests": [r.request_id for r in wave]},
-                dur_us=end_us - span0,
-            )
-            for r in wave:
-                tracer.instant(
-                    "admit", tracer.lane("serving", "engine"),
-                    {"request": r.request_id, "slot": r.slot},
-                )
-                # request-lane waterfall: the queue_wait segment ends
-                # where the wave began, the prefill segment spans the
-                # wave, and the decode segment opens at the wave's end
-                self._trace_close_queue(r, tracer, end_us=span0)
-                lane = tracer.request_lane(r.request_id, lease=False)
-                if lane is not None:
-                    tracer.complete(
-                        "prefill", lane, span0,
-                        {"request": r.request_id,
-                         "replica": self.trace_name,
-                         "bucket": bucket, "slot": r.slot},
-                        dur_us=end_us - span0,
-                    )
-                r.trace_marks["decode"] = end_us
-        self.stats.prefill_waves += 1
-        self.stats.prefill_tokens += wave_tokens
-        # per-call delta, not a process-global diff: foreign jit work in
-        # the same process must not read as engine recompiles
-        self.stats.compiles += xla_compile_count() - compiles0
+            t0 = time.perf_counter()
+            compiles0 = xla_compile_count()
+            run_args = wave_args if tracer is None else dict(
+                wave_args, requests=[r.request_id for r in wave])
+            with sp.span("sky.serve.run", eng, run_args,
+                         ring="prefill") as run:
+                data: Any = ids
+                for k, st in enumerate(self.stages):
+                    with sp.span("sky.serve.put", eng):
+                        data = device_put_elided(data, st.device)
+                        sids = device_put_elided(slot_ids, st.device)
+                    with sp.span("sky.serve.stage",
+                                 sp.lane(st.lane_name, "dispatch"),
+                                 {"stage": k, "bucket": bucket},
+                                 ring="prefill"):
+                        data, st.pool.slabs = st._prefill_donated(
+                            st.params, data, st.pool.slabs, sids
+                        )
+                pos = device_put_elided(lengths - 1, self._last_device)
+                logits = _gather_last(data, pos)  # [rows, V]
+                tokens = _argmax_tokens(logits)
+                with sp.span("sky.serve.wait", eng):
+                    jax.block_until_ready(tokens)
+            now = time.perf_counter()
+            self.stats.prefill_s += now - t0
+            with sp.span("sky.serve.commit", eng):
+                if tracer is not None:
+                    for r in wave:
+                        tracer.instant(
+                            "admit", eng,
+                            {"request": r.request_id, "slot": r.slot},
+                        )
+                        # request-lane waterfall: the queue_wait segment
+                        # ends where the wave's run began, the prefill
+                        # segment spans it, and the decode segment opens
+                        # at its end
+                        self._trace_close_queue(r, tracer,
+                                                end_us=run.start_us)
+                        lane = tracer.request_lane(r.request_id,
+                                                   lease=False)
+                        if lane is not None:
+                            tracer.complete(
+                                "prefill", lane, run.start_us,
+                                {"request": r.request_id,
+                                 "replica": self.trace_name,
+                                 "bucket": bucket, "slot": r.slot},
+                                dur_us=run.end_us - run.start_us,
+                            )
+                        r.trace_marks["decode"] = run.end_us
+                self.stats.prefill_waves += 1
+                self.stats.prefill_tokens += wave_tokens
+                # per-call delta, not a process-global diff: foreign jit
+                # work in the same process must not read as engine
+                # recompiles
+                self.stats.compiles += xla_compile_count() - compiles0
 
-        tokens_np = np.asarray(tokens)
-        sampled = self._sampled_rows(
-            logits, [(i, r) for i, r in enumerate(wave)]
-        )
-        for i, r in enumerate(wave):
-            tok = self._pick_token(r, tokens_np[i], sampled.get(i))
-            r.tokens.append(tok)
-            r.index = int(lengths[i])
-            r.status = RUNNING
-            self._running[r.request_id] = r
-            if r.first_token_s is None:
-                r.first_token_s = now
-            self.stats.generated_tokens += 1
-            if r.done:
-                self._finish(r, now)
+                tokens_np = np.asarray(tokens)
+                sampled = self._sampled_rows(
+                    logits, [(i, r) for i, r in enumerate(wave)]
+                )
+                for i, r in enumerate(wave):
+                    tok = self._pick_token(r, tokens_np[i], sampled.get(i))
+                    r.tokens.append(tok)
+                    r.index = int(lengths[i])
+                    r.status = RUNNING
+                    self._running[r.request_id] = r
+                    if r.first_token_s is None:
+                        r.first_token_s = now
+                    self.stats.generated_tokens += 1
+                    if r.done:
+                        self._finish(r, now)
 
     def _decode_tick(self) -> None:
         active = list(self._running.values())
         if not active:
             return
-        tokens = np.zeros((self.num_slots,), np.int32)
-        index = np.zeros((self.num_slots,), np.int32)
-        for r in active:
-            tokens[r.slot] = r.tokens[-1]
-            index[r.slot] = r.index
+        sp, eng = self._sp, self._eng_lane
+        tick_args = {"active": len(active)}
+        with sp.span("sky.serve.decode", eng, tick_args):
+            with sp.span("sky.serve.build", eng):
+                tokens = np.zeros((self.num_slots,), np.int32)
+                index = np.zeros((self.num_slots,), np.int32)
+                for r in active:
+                    tokens[r.slot] = r.tokens[-1]
+                    index[r.slot] = r.index
 
-        tracer = get_tracer()
-        span0 = tracer.now() if tracer is not None else 0.0
-        t0 = time.perf_counter()
-        compiles0 = xla_compile_count()
-        data: Any = tokens[:, None]  # [slots, 1]
-        for st in self.stages:
-            data = device_put_elided(data, st.device)
-            idx = device_put_elided(index, st.device)
-            if tracer is None:
-                data, st.pool.slabs = st._decode_donated(
-                    st.params, data, st.pool.slabs, idx
-                )
-            else:
-                stage0 = tracer.now()
-                data, st.pool.slabs = st._decode_donated(
-                    st.params, data, st.pool.slabs, idx
-                )
-                tracer.complete(
-                    "decode", tracer.lane(st.lane_name, "dispatch"), stage0
-                )
-        logits = data[:, 0]  # [slots, V]
-        nxt = _argmax_tokens(logits)
-        jax.block_until_ready(nxt)
-        now = time.perf_counter()
-        self.stats.decode_s += now - t0
-        if tracer is not None:
-            tracer.complete(
-                "decode", tracer.lane("serving", "engine"), span0,
-                {"active": len(active)},
-            )
-        self.stats.decode_tokens += len(active)
-        self.stats.generated_tokens += len(active)
-        self.stats.compiles += xla_compile_count() - compiles0
+            t0 = time.perf_counter()
+            compiles0 = xla_compile_count()
+            with sp.span("sky.serve.run", eng, tick_args, ring="decode"):
+                data: Any = tokens[:, None]  # [slots, 1]
+                for k, st in enumerate(self.stages):
+                    with sp.span("sky.serve.put", eng):
+                        data = device_put_elided(data, st.device)
+                        idx = device_put_elided(index, st.device)
+                    with sp.span("sky.serve.stage",
+                                 sp.lane(st.lane_name, "dispatch"),
+                                 {"stage": k}, ring="decode"):
+                        data, st.pool.slabs = st._decode_donated(
+                            st.params, data, st.pool.slabs, idx
+                        )
+                logits = data[:, 0]  # [slots, V]
+                nxt = _argmax_tokens(logits)
+                with sp.span("sky.serve.wait", eng):
+                    jax.block_until_ready(nxt)
+            now = time.perf_counter()
+            self.stats.decode_s += now - t0
+            with sp.span("sky.serve.commit", eng):
+                self.stats.decode_tokens += len(active)
+                self.stats.generated_tokens += len(active)
+                self.stats.compiles += xla_compile_count() - compiles0
 
-        nxt_np = np.asarray(nxt)
-        sampled = self._sampled_rows(
-            logits, [(r.slot, r) for r in active]
-        )
-        for r in active:
-            tok = self._pick_token(r, nxt_np[r.slot],
-                                   sampled.get(r.slot))
-            r.tokens.append(tok)
-            r.index += 1
-            if r.done:
-                self._finish(r, now)
+                nxt_np = np.asarray(nxt)
+                sampled = self._sampled_rows(
+                    logits, [(r.slot, r) for r in active]
+                )
+                for r in active:
+                    tok = self._pick_token(r, nxt_np[r.slot],
+                                           sampled.get(r.slot))
+                    r.tokens.append(tok)
+                    r.index += 1
+                    if r.done:
+                        self._finish(r, now)
 
     # --- the paged scheduling loop ------------------------------------------
     def _admit_paged(self) -> None:
@@ -2566,13 +2581,17 @@ class ServingEngine(LiveMetricsMixin):
         starved head."""
         if self.static_batching and self._running:
             return  # batch boundary only: the naive baseline policy
+        sp, eng = self._sp, self._eng_lane
         while True:
             queued = self._queue.requests
             if not queued or self._rows.free_slots < 1:
                 return
             head = queued[0]
             if head.request_id in self._swapped:
-                if not self._swap_in(head):
+                with sp.span("sky.serve.swap_in", eng,
+                             {"request": head.request_id}):
+                    swapped_in = self._swap_in(head)
+                if not swapped_in:
                     if head.request_id in self._swapped:
                         # pages genuinely unavailable: the head stalls
                         # the queue until a release frees them
@@ -2590,7 +2609,8 @@ class ServingEngine(LiveMetricsMixin):
                     self._stall_on_pages()
                     return
                 continue
-            wave = self._select_paged_wave()
+            with sp.span("sky.serve.select_wave", eng):
+                wave = self._select_paged_wave()
             if wave is None:
                 self._stall_on_pages()
                 return
@@ -2616,10 +2636,11 @@ class ServingEngine(LiveMetricsMixin):
         # this request's private page (same rule as the one-shot wave);
         # the pool's plan decides what a clone copies (scale rows ride
         # along on an int8 pool)
-        plan = self._pool.cow_plan(grant)
-        if plan:
-            for st in self.stages:
-                st.apply_cow_plan(plan)
+        with self._sp.span("sky.serve.cow", self._eng_lane):
+            plan = self._pool.cow_plan(grant)
+            if plan:
+                for st in self.stages:
+                    st.apply_cow_plan(plan)
         self._queue.remove(request)
         request.prefilled_len = grant.shared_tokens
         request.status = RUNNING
@@ -2706,97 +2727,98 @@ class ServingEngine(LiveMetricsMixin):
         member whose watermark reaches its prompt end commits its
         first token and joins the decode batch."""
         rows = self.prefill_batch
+        sp, eng = self._sp, self._eng_lane
+        tracer = sp.tracer
         chunks = []
         for r in wave:
             eff = r.effective_prompt
             clen = self._next_chunk_len(r)
             chunks.append(eff[r.prefilled_len:r.prefilled_len + clen])
         bucket = self.bucketer.bucket_for(int(chunks[0].size))
-        ids, lengths = self.bucketer.pad_batch(
-            chunks, bucket, rows, self.pad_id
-        )
-        sentinel = self.num_pages
-        tables = np.full(
-            (rows, self.max_pages_per_request), sentinel, np.int32
-        )
-        index = np.zeros((rows,), np.int32)
-        valid = np.zeros((rows,), np.int32)  # pad rows: writes drop
-        for i, r in enumerate(wave):
-            held = self._pool.table(r.request_id)
-            tables[i, : len(held)] = held
-            index[i] = r.prefilled_len
-            valid[i] = r.prefilled_len + int(chunks[i].size)
-
-        width = self._table_width(valid)
-        tables = tables[:, :width]
-        self._count_quant(index, valid, width, len(wave))
-        tracer = get_tracer()
-        span0 = tracer.now() if tracer is not None else 0.0
-        t0 = time.perf_counter()
-        compiles0 = xla_compile_count()
-        data = self._run_paged_stages(
-            ids, tables, index, valid, tracer, "prefill",
-            {"bucket": bucket, "chunk": True},
-        )
-        pos = device_put_elided(lengths - 1, self._last_device)
-        logits = _gather_last(data, pos)  # [rows, V]
-        tokens = _argmax_tokens(logits)
-        jax.block_until_ready(tokens)
-        now = time.perf_counter()
-        self.stats.prefill_s += now - t0
         # per-chunk TRUE token counts: the padding-waste histogram and
         # serving_padding_fraction() must see what this wave actually
         # prefilled, never the members' full prompt lengths
         wave_tokens = int(sum(int(c.size) for c in chunks))
-        if tracer is not None:
-            end_us = tracer.now()
-            tracer.complete(
-                "prefill", tracer.lane("serving", "engine"), span0,
-                {"bucket": bucket, "wave": len(wave),
-                 "tokens": wave_tokens, "chunk": True,
-                 "requests": [r.request_id for r in wave]},
-                dur_us=end_us - span0,
-            )
-        else:
-            end_us = 0.0
-        self.stats.prefill_waves += 1
-        self.stats.prefill_tokens += wave_tokens
-        self.stats.prefill_chunks += len(wave)
-        self.stats.compiles += xla_compile_count() - compiles0
+        wave_args = {"bucket": bucket, "wave": len(wave),
+                     "tokens": wave_tokens, "chunk": True}
+        with sp.span("sky.serve.prefill", eng, wave_args):
+            with sp.span("sky.serve.build", eng):
+                ids, lengths = self.bucketer.pad_batch(
+                    chunks, bucket, rows, self.pad_id
+                )
+                sentinel = self.num_pages
+                tables = np.full(
+                    (rows, self.max_pages_per_request), sentinel, np.int32
+                )
+                index = np.zeros((rows,), np.int32)
+                valid = np.zeros((rows,), np.int32)  # pad rows: writes drop
+                for i, r in enumerate(wave):
+                    held = self._pool.table(r.request_id)
+                    tables[i, : len(held)] = held
+                    index[i] = r.prefilled_len
+                    valid[i] = r.prefilled_len + int(chunks[i].size)
 
-        finals = [
-            (i, r) for i, r in enumerate(wave)
-            if r.prefilled_len + int(chunks[i].size)
-            >= int(r.effective_prompt.size)
-        ]
-        tokens_np = np.asarray(tokens)
-        sampled = self._sampled_rows(logits, finals)
-        for i, r in enumerate(wave):
-            clen = int(chunks[i].size)
-            r.prefilled_len += clen
-            if r.prefilled_len < int(r.effective_prompt.size):
-                continue  # watermark advanced; more chunks to come
-            # final chunk: the last true position's logits seed the
-            # first generated token, exactly like a one-shot wave
-            self._prefilling.pop(r.request_id)
-            self._pool.register_prefix(
-                r.request_id, [int(t) for t in r.prompt]
-            )
-            tok = self._pick_token(r, tokens_np[i], sampled.get(i))
-            r.tokens.append(tok)
-            r.index = r.prefilled_len
-            r.prefilled_len = 0
-            r.status = RUNNING
-            self._running[r.request_id] = r
-            if r.first_token_s is None:
-                r.first_token_s = now
-            self.stats.generated_tokens += 1
-            if tracer is not None:
-                self._trace_close_prefill(r, tracer, end_us=end_us,
-                                          bucket=bucket, slot=r.slot)
-                r.trace_marks["decode"] = end_us
-            if r.done:
-                self._finish(r, now)
+                width = self._table_width(valid)
+                tables = tables[:, :width]
+                self._count_quant(index, valid, width, len(wave))
+            t0 = time.perf_counter()
+            compiles0 = xla_compile_count()
+            run_args = wave_args if tracer is None else dict(
+                wave_args, requests=[r.request_id for r in wave])
+            with sp.span("sky.serve.run", eng, run_args,
+                         ring="prefill") as run:
+                data = self._run_paged_stages(
+                    ids, tables, index, valid, "prefill",
+                    {"bucket": bucket, "chunk": True},
+                )
+                pos = device_put_elided(lengths - 1, self._last_device)
+                logits = _gather_last(data, pos)  # [rows, V]
+                tokens = _argmax_tokens(logits)
+                with sp.span("sky.serve.wait", eng):
+                    jax.block_until_ready(tokens)
+            now = time.perf_counter()
+            self.stats.prefill_s += now - t0
+            with sp.span("sky.serve.commit", eng):
+                self.stats.prefill_waves += 1
+                self.stats.prefill_tokens += wave_tokens
+                self.stats.prefill_chunks += len(wave)
+                self.stats.compiles += xla_compile_count() - compiles0
+
+                finals = [
+                    (i, r) for i, r in enumerate(wave)
+                    if r.prefilled_len + int(chunks[i].size)
+                    >= int(r.effective_prompt.size)
+                ]
+                tokens_np = np.asarray(tokens)
+                sampled = self._sampled_rows(logits, finals)
+                for i, r in enumerate(wave):
+                    clen = int(chunks[i].size)
+                    r.prefilled_len += clen
+                    if r.prefilled_len < int(r.effective_prompt.size):
+                        continue  # watermark advanced; more chunks to come
+                    # final chunk: the last true position's logits seed
+                    # the first generated token, exactly like a one-shot
+                    # wave
+                    self._prefilling.pop(r.request_id)
+                    self._pool.register_prefix(
+                        r.request_id, [int(t) for t in r.prompt]
+                    )
+                    tok = self._pick_token(r, tokens_np[i], sampled.get(i))
+                    r.tokens.append(tok)
+                    r.index = r.prefilled_len
+                    r.prefilled_len = 0
+                    r.status = RUNNING
+                    self._running[r.request_id] = r
+                    if r.first_token_s is None:
+                        r.first_token_s = now
+                    self.stats.generated_tokens += 1
+                    if tracer is not None:
+                        self._trace_close_prefill(
+                            r, tracer, end_us=run.end_us, bucket=bucket,
+                            slot=r.slot)
+                        r.trace_marks["decode"] = run.end_us
+                    if r.done:
+                        self._finish(r, now)
 
     @staticmethod
     def _effective_tokens(request: Request) -> tuple:
@@ -2886,109 +2908,116 @@ class ServingEngine(LiveMetricsMixin):
         row.  A full-prefix hit costs one bucket of tail compute — the
         TTFT-drops-with-prefix-length effect the bench gates."""
         rows = self.prefill_batch
+        sp, eng = self._sp, self._eng_lane
+        tracer = sp.tracer
         tails = [
             r.effective_prompt[g.shared_tokens:] for r, g in wave
         ]
         bucket = self.bucketer.bucket_for(int(tails[0].size))
-        ids, lengths = self.bucketer.pad_batch(
-            tails, bucket, rows, self.pad_id
-        )
-        sentinel = self.num_pages
-        tables = np.full(
-            (rows, self.max_pages_per_request), sentinel, np.int32
-        )
-        index = np.zeros((rows,), np.int32)
-        valid = np.zeros((rows,), np.int32)  # pad rows: every write drops
-        for i, (r, g) in enumerate(wave):
-            row = self._rows.allocate()
-            assert row is not None  # wave capped by free rows
-            r.slot = row
-            tables[i, : len(g.page_table)] = g.page_table
-            index[i] = g.shared_tokens
-            valid[i] = g.shared_tokens + int(tails[i].size)
-        # copy-on-write BEFORE any dispatch touches the slabs: the
-        # donor's partial page becomes the sharer's private page, so
-        # the tail prefill's appends never write a shared page; the
-        # pool's plan decides what a clone copies (scale rows ride
-        # along on an int8 pool)
-        for _, g in wave:
-            plan = self._pool.cow_plan(g)
-            if plan:
-                for st in self.stages:
-                    st.apply_cow_plan(plan)
-
-        width = self._table_width(valid)
-        tables = tables[:, :width]
-        self._count_quant(index, valid, width, len(wave))
-        tracer = get_tracer()
-        span0 = tracer.now() if tracer is not None else 0.0
-        t0 = time.perf_counter()
-        compiles0 = xla_compile_count()
-        data = self._run_paged_stages(
-            ids, tables, index, valid, tracer, "prefill",
-            {"bucket": bucket},
-        )
-        pos = device_put_elided(lengths - 1, self._last_device)
-        logits = _gather_last(data, pos)  # [rows, V]
-        tokens = _argmax_tokens(logits)
-        jax.block_until_ready(tokens)
-        now = time.perf_counter()
-        self.stats.prefill_s += now - t0
         wave_tokens = int(sum(int(t.size) for t in tails))
         shared_tokens = int(sum(g.shared_tokens for _, g in wave))
-        if tracer is not None:
-            end_us = tracer.now()
-            tracer.complete(
-                "prefill", tracer.lane("serving", "engine"), span0,
-                {"bucket": bucket, "wave": len(wave),
-                 "tokens": wave_tokens, "shared": shared_tokens,
-                 "requests": [r.request_id for r, _ in wave]},
-                dur_us=end_us - span0,
-            )
-            for r, g in wave:
-                tracer.instant(
-                    "admit", tracer.lane("serving", "engine"),
-                    {"request": r.request_id, "slot": r.slot,
-                     "pages": len(g.page_table),
-                     "shared": g.shared_tokens},
+        wave_args = {"bucket": bucket, "wave": len(wave),
+                     "tokens": wave_tokens, "shared": shared_tokens}
+        with sp.span("sky.serve.prefill", eng, wave_args):
+            with sp.span("sky.serve.build", eng):
+                ids, lengths = self.bucketer.pad_batch(
+                    tails, bucket, rows, self.pad_id
                 )
-                self._trace_close_queue(r, tracer, end_us=span0)
-                lane = tracer.request_lane(r.request_id, lease=False)
-                if lane is not None:
-                    tracer.complete(
-                        "prefill", lane, span0,
-                        {"request": r.request_id,
-                         "replica": self.trace_name,
-                         "bucket": bucket, "slot": r.slot,
-                         "shared": g.shared_tokens},
-                        dur_us=end_us - span0,
-                    )
-                r.trace_marks["decode"] = end_us
-        self.stats.prefill_waves += 1
-        self.stats.prefill_tokens += wave_tokens
-        self.stats.compiles += xla_compile_count() - compiles0
+                sentinel = self.num_pages
+                tables = np.full(
+                    (rows, self.max_pages_per_request), sentinel, np.int32
+                )
+                index = np.zeros((rows,), np.int32)
+                # pad rows: every write drops
+                valid = np.zeros((rows,), np.int32)
+                for i, (r, g) in enumerate(wave):
+                    row = self._rows.allocate()
+                    assert row is not None  # wave capped by free rows
+                    r.slot = row
+                    tables[i, : len(g.page_table)] = g.page_table
+                    index[i] = g.shared_tokens
+                    valid[i] = g.shared_tokens + int(tails[i].size)
+                width = self._table_width(valid)
+                tables = tables[:, :width]
+                self._count_quant(index, valid, width, len(wave))
+            # copy-on-write BEFORE any dispatch touches the slabs: the
+            # donor's partial page becomes the sharer's private page, so
+            # the tail prefill's appends never write a shared page; the
+            # pool's plan decides what a clone copies (scale rows ride
+            # along on an int8 pool)
+            with sp.span("sky.serve.cow", eng):
+                for _, g in wave:
+                    plan = self._pool.cow_plan(g)
+                    if plan:
+                        for st in self.stages:
+                            st.apply_cow_plan(plan)
 
-        tokens_np = np.asarray(tokens)
-        sampled = self._sampled_rows(
-            logits, [(i, r) for i, (r, _) in enumerate(wave)]
-        )
-        for i, (r, g) in enumerate(wave):
-            # index the radix cache BEFORE the done-check can release
-            # the pages: a request that finishes in its prefill tick
-            # still leaves its prompt warm for the next sharer
-            self._pool.register_prefix(
-                r.request_id, [int(t) for t in r.prompt]
-            )
-            tok = self._pick_token(r, tokens_np[i], sampled.get(i))
-            r.tokens.append(tok)
-            r.index = int(valid[i])
-            r.status = RUNNING
-            self._running[r.request_id] = r
-            if r.first_token_s is None:
-                r.first_token_s = now
-            self.stats.generated_tokens += 1
-            if r.done:
-                self._finish(r, now)
+            t0 = time.perf_counter()
+            compiles0 = xla_compile_count()
+            run_args = wave_args if tracer is None else dict(
+                wave_args, requests=[r.request_id for r, _ in wave])
+            with sp.span("sky.serve.run", eng, run_args,
+                         ring="prefill") as run:
+                data = self._run_paged_stages(
+                    ids, tables, index, valid, "prefill",
+                    {"bucket": bucket},
+                )
+                pos = device_put_elided(lengths - 1, self._last_device)
+                logits = _gather_last(data, pos)  # [rows, V]
+                tokens = _argmax_tokens(logits)
+                with sp.span("sky.serve.wait", eng):
+                    jax.block_until_ready(tokens)
+            now = time.perf_counter()
+            self.stats.prefill_s += now - t0
+            with sp.span("sky.serve.commit", eng):
+                if tracer is not None:
+                    for r, g in wave:
+                        tracer.instant(
+                            "admit", eng,
+                            {"request": r.request_id, "slot": r.slot,
+                             "pages": len(g.page_table),
+                             "shared": g.shared_tokens},
+                        )
+                        self._trace_close_queue(r, tracer,
+                                                end_us=run.start_us)
+                        lane = tracer.request_lane(r.request_id,
+                                                   lease=False)
+                        if lane is not None:
+                            tracer.complete(
+                                "prefill", lane, run.start_us,
+                                {"request": r.request_id,
+                                 "replica": self.trace_name,
+                                 "bucket": bucket, "slot": r.slot,
+                                 "shared": g.shared_tokens},
+                                dur_us=run.end_us - run.start_us,
+                            )
+                        r.trace_marks["decode"] = run.end_us
+                self.stats.prefill_waves += 1
+                self.stats.prefill_tokens += wave_tokens
+                self.stats.compiles += xla_compile_count() - compiles0
+
+                tokens_np = np.asarray(tokens)
+                sampled = self._sampled_rows(
+                    logits, [(i, r) for i, (r, _) in enumerate(wave)]
+                )
+                for i, (r, g) in enumerate(wave):
+                    # index the radix cache BEFORE the done-check can
+                    # release the pages: a request that finishes in its
+                    # prefill tick still leaves its prompt warm for the
+                    # next sharer
+                    self._pool.register_prefix(
+                        r.request_id, [int(t) for t in r.prompt]
+                    )
+                    tok = self._pick_token(r, tokens_np[i], sampled.get(i))
+                    r.tokens.append(tok)
+                    r.index = int(valid[i])
+                    r.status = RUNNING
+                    self._running[r.request_id] = r
+                    if r.first_token_s is None:
+                        r.first_token_s = now
+                    self.stats.generated_tokens += 1
+                    if r.done:
+                        self._finish(r, now)
 
     def _table_width(self, valid) -> int:
         """Page-table columns this step actually needs (the PR 12
@@ -3030,31 +3059,27 @@ class ServingEngine(LiveMetricsMixin):
             self.stats.quantized_pages += int(touched.sum())
         self.stats.dequant_blocks += int(rows) * int(width)
 
-    def _run_paged_stages(self, data, tables, index, valid, tracer,
-                          span_name, span_args=None):
+    def _run_paged_stages(self, data, tables, index, valid, ring,
+                          span_args=None):
         """Thread one paged step through every stage — the ONE
         dispatch idiom shared by tail-prefill waves, chunk waves,
         decode ticks, and the speculative verify forward: per-stage
         device puts, the donated step program with its same-statement
-        slab rebind, and a per-stage dispatch span named
-        ``span_name``.  Returns the last stage's output."""
-        for st in self.stages:
-            data = device_put_elided(data, st.device)
-            tb = device_put_elided(tables, st.device)
-            ix = device_put_elided(index, st.device)
-            vl = device_put_elided(valid, st.device)
-            if tracer is None:
+        slab rebind, and per stage a ``sky.serve.put`` span around the
+        puts and a dispatch span (``sky.serve.stage``; ``ring`` on the
+        stage's lane in the ring).  Returns the last stage's output."""
+        sp, eng = self._sp, self._eng_lane
+        for k, st in enumerate(self.stages):
+            with sp.span("sky.serve.put", eng):
+                data = device_put_elided(data, st.device)
+                tb = device_put_elided(tables, st.device)
+                ix = device_put_elided(index, st.device)
+                vl = device_put_elided(valid, st.device)
+            with sp.span("sky.serve.stage",
+                         sp.lane(st.lane_name, "dispatch"),
+                         dict(span_args or (), stage=k), ring=ring):
                 data, st.slabs = st._step_donated(
                     st.params, data, st.slabs, tb, ix, vl
-                )
-            else:
-                stage0 = tracer.now()
-                data, st.slabs = st._step_donated(
-                    st.params, data, st.slabs, tb, ix, vl
-                )
-                tracer.complete(
-                    span_name, tracer.lane(st.lane_name, "dispatch"),
-                    stage0, span_args,
                 )
         return data
 
@@ -3137,56 +3162,57 @@ class ServingEngine(LiveMetricsMixin):
         active = list(self._running.values())
         if not active:
             return
-        rows = self.max_concurrency
-        sentinel = self.num_pages
-        tokens = np.zeros((rows,), np.int32)
-        index = np.zeros((rows,), np.int32)
-        valid = np.zeros((rows,), np.int32)  # inactive rows never write
-        tables = np.full(
-            (rows, self.max_pages_per_request), sentinel, np.int32
-        )
-        for r in active:
-            tokens[r.slot] = r.tokens[-1]
-            index[r.slot] = r.index
-            valid[r.slot] = r.index + 1
-            held = self._pool.table(r.request_id)
-            tables[r.slot, : len(held)] = held
+        sp, eng = self._sp, self._eng_lane
+        tick_args = {"active": len(active)}
+        with sp.span("sky.serve.decode", eng, tick_args):
+            with sp.span("sky.serve.build", eng):
+                rows = self.max_concurrency
+                sentinel = self.num_pages
+                tokens = np.zeros((rows,), np.int32)
+                index = np.zeros((rows,), np.int32)
+                # inactive rows never write
+                valid = np.zeros((rows,), np.int32)
+                tables = np.full(
+                    (rows, self.max_pages_per_request), sentinel, np.int32
+                )
+                for r in active:
+                    tokens[r.slot] = r.tokens[-1]
+                    index[r.slot] = r.index
+                    valid[r.slot] = r.index + 1
+                    held = self._pool.table(r.request_id)
+                    tables[r.slot, : len(held)] = held
 
-        width = self._table_width(valid)
-        tables = tables[:, :width]
-        self._count_quant(index, valid, width, len(active))
-        tracer = get_tracer()
-        span0 = tracer.now() if tracer is not None else 0.0
-        t0 = time.perf_counter()
-        compiles0 = xla_compile_count()
-        data = self._run_paged_stages(
-            tokens[:, None], tables, index, valid, tracer, "decode"
-        )
-        logits = data[:, 0]  # [rows, V]
-        nxt = _argmax_tokens(logits)
-        jax.block_until_ready(nxt)
-        now = time.perf_counter()
-        self.stats.decode_s += now - t0
-        if tracer is not None:
-            tracer.complete(
-                "decode", tracer.lane("serving", "engine"), span0,
-                {"active": len(active)},
-            )
-        self.stats.decode_tokens += len(active)
-        self.stats.generated_tokens += len(active)
-        self.stats.compiles += xla_compile_count() - compiles0
+                width = self._table_width(valid)
+                tables = tables[:, :width]
+                self._count_quant(index, valid, width, len(active))
+            t0 = time.perf_counter()
+            compiles0 = xla_compile_count()
+            with sp.span("sky.serve.run", eng, tick_args, ring="decode"):
+                data = self._run_paged_stages(
+                    tokens[:, None], tables, index, valid, "decode"
+                )
+                logits = data[:, 0]  # [rows, V]
+                nxt = _argmax_tokens(logits)
+                with sp.span("sky.serve.wait", eng):
+                    jax.block_until_ready(nxt)
+            now = time.perf_counter()
+            self.stats.decode_s += now - t0
+            with sp.span("sky.serve.commit", eng):
+                self.stats.decode_tokens += len(active)
+                self.stats.generated_tokens += len(active)
+                self.stats.compiles += xla_compile_count() - compiles0
 
-        nxt_np = np.asarray(nxt)
-        sampled = self._sampled_rows(
-            logits, [(r.slot, r) for r in active]
-        )
-        for r in active:
-            tok = self._pick_token(r, nxt_np[r.slot],
-                                   sampled.get(r.slot))
-            r.tokens.append(tok)
-            r.index += 1
-            if r.done:
-                self._finish(r, now)
+                nxt_np = np.asarray(nxt)
+                sampled = self._sampled_rows(
+                    logits, [(r.slot, r) for r in active]
+                )
+                for r in active:
+                    tok = self._pick_token(r, nxt_np[r.slot],
+                                           sampled.get(r.slot))
+                    r.tokens.append(tok)
+                    r.index += 1
+                    if r.done:
+                        self._finish(r, now)
 
     def _spec_tick(self) -> None:
         """One speculative decode tick (replaces the plain decode tick
@@ -3218,129 +3244,126 @@ class ServingEngine(LiveMetricsMixin):
             self._decode_tick_paged()
             return
         k = self.spec_k
-        rows = self.max_concurrency
-        sentinel = self.num_pages
-        tokens = np.zeros((rows,), np.int32)
-        index0 = np.zeros((rows,), np.int32)
-        reserve = np.zeros((rows,), np.int32)  # inactive rows: 0 -> drop
-        tables = np.full(
-            (rows, self.max_pages_per_request), sentinel, np.int32
-        )
-        for r in active:
-            tokens[r.slot] = r.tokens[-1]
-            index0[r.slot] = r.index
-            reserve[r.slot] = int(r.prompt.size) + r.max_new_tokens
-            held = self._pool.table(r.request_id)
-            tables[r.slot, : len(held)] = held
+        sp, eng = self._sp, self._eng_lane
+        tick_args = {"active": len(active), "spec_k": k}
+        with sp.span("sky.serve.decode", eng, tick_args):
+            with sp.span("sky.serve.build", eng):
+                rows = self.max_concurrency
+                sentinel = self.num_pages
+                tokens = np.zeros((rows,), np.int32)
+                index0 = np.zeros((rows,), np.int32)
+                # inactive rows: 0 -> drop
+                reserve = np.zeros((rows,), np.int32)
+                tables = np.full(
+                    (rows, self.max_pages_per_request), sentinel, np.int32
+                )
+                for r in active:
+                    tokens[r.slot] = r.tokens[-1]
+                    index0[r.slot] = r.index
+                    reserve[r.slot] = int(r.prompt.size) + r.max_new_tokens
+                    held = self._pool.table(r.request_id)
+                    tables[r.slot, : len(held)] = held
 
-        # verify writes cap at min(index+k+1, reserve); one table width
-        # (covering that bound) serves BOTH the draft loop and the
-        # verify forward, so the two stay on one warmed shape set
-        valid = np.minimum(index0 + k + 1, reserve)
-        width = self._table_width(valid)
-        tables = tables[:, :width]
-        self._count_quant(index0, valid, width, len(active))
-        if self.kv_dtype == "int8":
-            # the draft's k Lq=1 passes also quantize (one tail-page
-            # re-quant per kept step per row) and dequantize (one
-            # gathered width per step) — the verify-only count above
-            # would hide roughly half a spec tick's quantization work
-            slots = [r.slot for r in active]
-            kept = np.clip(reserve[slots] - index0[slots], 0, k)
-            self.stats.quantized_pages += int(kept.sum())
-            self.stats.dequant_blocks += k * len(active) * width
-        tracer = get_tracer()
-        span0 = tracer.now() if tracer is not None else 0.0
-        t0 = time.perf_counter()
-        compiles0 = xla_compile_count()
-        stage0 = self.stages[0]
-        d = self._draft.num_attn
-        # --- draft: k sequential Lq=1 steps against stage 0's slab
-        # prefix (the draft's KV IS the target's first d layers' KV —
-        # prefix-slice sharing, see serving/speculative.py)
-        tb0 = device_put_elided(tables, stage0.device)
-        # the ENTIRE k-step autoregressive draft is one compiled
-        # program (DraftModel.draft_k, k static): one dispatch and one
-        # device->host transfer per tick, not k of each
-        drafted_dev, new_prefix = self._draft.draft_k(
-            device_put_elided(tokens, stage0.device),
-            stage0.slabs[:d], tb0,
-            device_put_elided(index0, stage0.device),
-            device_put_elided(reserve, stage0.device), k,
-        )
-        stage0.slabs = list(new_prefix) + stage0.slabs[d:]
-        drafted = np.asarray(drafted_dev, dtype=np.int32)
-        if tracer is not None:
-            tracer.complete(
-                "draft", tracer.lane("serving", "engine"), span0,
-                {"active": len(active), "spec_k": k},
-            )
-        # --- verify: one Lq=k+1 forward over the whole pipeline
-        verify_span0 = tracer.now() if tracer is not None else 0.0
-        verify_in = np.concatenate([tokens[:, None], drafted], axis=1)
-        logits3 = self._run_paged_stages(
-            verify_in, tables, index0, valid, tracer, "decode"
-        )  # [rows, k+1, V]
-        target = _argmax_tokens(logits3)  # [rows, k+1]
-        jax.block_until_ready(target)
-        now = time.perf_counter()
-        self.stats.decode_s += now - t0
-        if tracer is not None:
-            tracer.complete(
-                "decode", tracer.lane("serving", "engine"), verify_span0,
-                {"active": len(active), "spec_k": k},
-            )
-        self.stats.compiles += xla_compile_count() - compiles0
+                # verify writes cap at min(index+k+1, reserve); one table width
+                # (covering that bound) serves BOTH the draft loop and the
+                # verify forward, so the two stay on one warmed shape set
+                valid = np.minimum(index0 + k + 1, reserve)
+                width = self._table_width(valid)
+                tables = tables[:, :width]
+                self._count_quant(index0, valid, width, len(active))
+                if self.kv_dtype == "int8":
+                    # the draft's k Lq=1 passes also quantize (one tail-page
+                    # re-quant per kept step per row) and dequantize (one
+                    # gathered width per step) — the verify-only count above
+                    # would hide roughly half a spec tick's quantization work
+                    slots = [r.slot for r in active]
+                    kept = np.clip(reserve[slots] - index0[slots], 0, k)
+                    self.stats.quantized_pages += int(kept.sum())
+                    self.stats.dequant_blocks += k * len(active) * width
+            t0 = time.perf_counter()
+            compiles0 = xla_compile_count()
+            with sp.span("sky.serve.run", eng, tick_args, ring="draft"):
+                stage0 = self.stages[0]
+                d = self._draft.num_attn
+                # --- draft: k sequential Lq=1 steps against stage 0's slab
+                # prefix (the draft's KV IS the target's first d layers' KV —
+                # prefix-slice sharing, see serving/speculative.py)
+                tb0 = device_put_elided(tables, stage0.device)
+                # the ENTIRE k-step autoregressive draft is one compiled
+                # program (DraftModel.draft_k, k static): one dispatch and one
+                # device->host transfer per tick, not k of each
+                drafted_dev, new_prefix = self._draft.draft_k(
+                    device_put_elided(tokens, stage0.device),
+                    stage0.slabs[:d], tb0,
+                    device_put_elided(index0, stage0.device),
+                    device_put_elided(reserve, stage0.device), k,
+                )
+                stage0.slabs = list(new_prefix) + stage0.slabs[d:]
+                with sp.span("sky.serve.wait", eng):
+                    drafted = np.asarray(drafted_dev, dtype=np.int32)
+            # --- verify: one Lq=k+1 forward over the whole pipeline
+            with sp.span("sky.serve.run", eng, tick_args, ring="decode"):
+                verify_in = np.concatenate([tokens[:, None], drafted], axis=1)
+                logits3 = self._run_paged_stages(
+                    verify_in, tables, index0, valid, "decode"
+                )  # [rows, k+1, V]
+                target = _argmax_tokens(logits3)  # [rows, k+1]
+                with sp.span("sky.serve.wait", eng):
+                    jax.block_until_ready(target)
+            now = time.perf_counter()
+            self.stats.decode_s += now - t0
+            with sp.span("sky.serve.commit", eng):
+                self.stats.compiles += xla_compile_count() - compiles0
 
-        target_np = np.asarray(target)
-        sampled = self._sampled_rows(
-            logits3[:, 0], [(r.slot, r) for r in active]
-        )
-        committed_total = 0
-        for r in active:
-            row = r.slot
-            if r.temperature > 0.0:
-                # position-0 logits == the plain decode tick's logits;
-                # the drafts for this row are discarded (sampling has
-                # no greedy acceptance rule) and never counted —
-                # accept-rate observability describes greedy traffic
-                tok = self._pick_token(
-                    r, target_np[row, 0], sampled.get(row)
+                target_np = np.asarray(target)
+                sampled = self._sampled_rows(
+                    logits3[:, 0], [(r.slot, r) for r in active]
                 )
-                commit = [tok][: min(1, r.remaining)]
-            else:
-                remaining = r.remaining
-                accepted = greedy_accept_count(
-                    drafted[row], target_np[row, :k]
-                )
-                commit = (
-                    [int(t) for t in drafted[row, :accepted]]
-                    + [int(target_np[row, accepted])]
-                )
-                ncommit = min(len(commit), remaining)
-                commit = commit[:ncommit]
-                # the accept-rate denominator counts only USABLE
-                # proposals: a row whose remaining budget is below k
-                # could never consume the surplus drafts (the fixed
-                # draft shape still computes them), and charging them
-                # would deflate the rate below 1.0 for a PERFECT draft
-                self.stats.draft_tokens += min(k, remaining)
-                self.stats.accepted_draft_tokens += min(
-                    accepted, ncommit
-                )
-                # the verify wrote min(k+1, remaining) positions (its
-                # valid cap); a rollback happened iff the committed
-                # watermark stops short of what was written
-                if ncommit < min(k + 1, remaining):
-                    self.stats.spec_rollbacks += 1
-            for tok in commit:
-                r.tokens.append(tok)
-            r.index += len(commit)
-            committed_total += len(commit)
-            if r.done:
-                self._finish(r, now)
-        self.stats.decode_tokens += committed_total
-        self.stats.generated_tokens += committed_total
+                committed_total = 0
+                for r in active:
+                    row = r.slot
+                    if r.temperature > 0.0:
+                        # position-0 logits == the plain decode tick's logits;
+                        # the drafts for this row are discarded (sampling has
+                        # no greedy acceptance rule) and never counted —
+                        # accept-rate observability describes greedy traffic
+                        tok = self._pick_token(
+                            r, target_np[row, 0], sampled.get(row)
+                        )
+                        commit = [tok][: min(1, r.remaining)]
+                    else:
+                        remaining = r.remaining
+                        accepted = greedy_accept_count(
+                            drafted[row], target_np[row, :k]
+                        )
+                        commit = (
+                            [int(t) for t in drafted[row, :accepted]]
+                            + [int(target_np[row, accepted])]
+                        )
+                        ncommit = min(len(commit), remaining)
+                        commit = commit[:ncommit]
+                        # the accept-rate denominator counts only USABLE
+                        # proposals: a row whose remaining budget is below k
+                        # could never consume the surplus drafts (the fixed
+                        # draft shape still computes them), and charging them
+                        # would deflate the rate below 1.0 for a PERFECT draft
+                        self.stats.draft_tokens += min(k, remaining)
+                        self.stats.accepted_draft_tokens += min(
+                            accepted, ncommit
+                        )
+                        # the verify wrote min(k+1, remaining) positions (its
+                        # valid cap); a rollback happened iff the committed
+                        # watermark stops short of what was written
+                        if ncommit < min(k + 1, remaining):
+                            self.stats.spec_rollbacks += 1
+                    for tok in commit:
+                        r.tokens.append(tok)
+                    r.index += len(commit)
+                    committed_total += len(commit)
+                    if r.done:
+                        self._finish(r, now)
+                self.stats.decode_tokens += committed_total
+                self.stats.generated_tokens += committed_total
 
     @staticmethod
     def _sampled_rows(logits, rows) -> Dict[int, np.ndarray]:

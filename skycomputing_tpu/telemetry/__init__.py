@@ -50,6 +50,7 @@ from .tracer import (
     disable_tracing,
     enable_tracing,
     get_tracer,
+    span_sinks,
     trace_span,
 )
 
@@ -81,5 +82,6 @@ __all__ = [
     "disable_tracing",
     "enable_tracing",
     "get_tracer",
+    "span_sinks",
     "trace_span",
 ]

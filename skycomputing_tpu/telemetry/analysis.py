@@ -35,6 +35,12 @@ from typing import Any, Dict, List, Optional, Tuple
 
 STAGE_RE = re.compile(r"^stage\s+(\d+)")
 
+#: the spans of one training step in which the host ISSUES work (split +
+#: prefetch, rng folds, the three issue loops): their union is the
+#: step's dispatch time; the last is opened once per optimizer step
+ISSUE_SPANS = ("sky.pipe.prefetch", "sky.pipe.rng", "sky.pipe.fwd_issue",
+               "sky.pipe.bwd_issue", "sky.pipe.update_issue")
+
 # baseline keys recognized by the regression gate, with the factor that
 # converts their value to milliseconds
 _STEP_KEYS_MS = {"step_ms": 1.0, "dispatch_ms": None, "step_wall_s": 1e3,
@@ -300,24 +306,26 @@ def analyze(events: List[Dict[str, Any]]) -> Dict[str, Any]:
         report["serving"]["padding_fraction"] = (
             round(padding, 4) if padding is not None else None
         )
-    # host-dispatch share: one "host_dispatch" span per train step (its
-    # duration IS the engine's PipelineStats.dispatch_s), so the trace
-    # carries the same dispatch fraction the engine reports — the figure
-    # the mesh-native drive collapses
-    dispatch = _clip(
-        [
-            (float(ev["ts"]), float(ev["ts"]) + float(ev.get("dur", 0)))
-            for ev in events
-            if ev.get("ph") == "X" and ev.get("name") == "host_dispatch"
-        ],
-        *window,
-    )
+    # host-dispatch share: the union of the step's issue spans (input
+    # split + prefetch, the rng folds, the forward / backward / update
+    # issue loops) — the real intervals PipelineStats.dispatch_s sums —
+    # so the trace carries the same dispatch fraction the engine
+    # reports: the figure the mesh-native drive collapses
+    issue = [
+        (ev["name"], float(ev["ts"]),
+         float(ev["ts"]) + float(ev.get("dur", 0)))
+        for ev in events
+        if ev.get("ph") == "X" and ev.get("name") in ISSUE_SPANS
+    ]
+    dispatch = _clip([(t0, t1) for _, t0, t1 in issue], *window)
     if dispatch:
         dispatch_us = busy_us(dispatch)
         report["dispatch"] = {
             "total_ms": round(dispatch_us / 1e3, 3),
             "share": round(dispatch_us / window_us, 4),
-            "steps": len(dispatch),
+            "steps": len(_clip(
+                [(t0, t1) for name, t0, t1 in issue
+                 if name == ISSUE_SPANS[-1]], *window)),
         }
     compiles = named_durations(events, "xla_compile")
     report["xla_compiles"] = {

@@ -12,11 +12,23 @@ occupancy method, Orca's iteration-level accounting) and exports it in
 
 Design constraints, in order:
 
-- **hard-disabled = zero cost**: tracing defaults OFF; the process-global
-  accessor :func:`get_tracer` returns ``None`` and every instrumentation
-  site is a single ``is None`` test away from the uninstrumented path.
-  The module-level :func:`trace_span` helper returns one shared no-op
-  singleton when disabled — no object allocation, no clock read.
+- **one span primitive, two sinks, one switch each**: every site, hot
+  or cool, opens the same context manager (:func:`trace_span`, or
+  ``span_sinks().span`` where an engine hoists the lookup once per
+  step).  It feeds (i) the ring below, when :func:`enable_tracing` is
+  on, and (ii) a ``jax.profiler.TraceAnnotation`` of the same name,
+  whenever a JAX profiler session is running — whoever started it — so
+  the program's host phases land in the profiler's own trace, on the
+  device operations' clock.  The ``sky.*`` names are the catalogue in
+  ``docs/observability.md``; ``ring=`` keeps the ring's older short
+  name (``fwd``, ``prefill`` ...) where analysis and tuning read it.
+- **hard-disabled = zero cost**: both sinks default OFF;
+  :func:`span_sinks` then returns one shared null object whose spans
+  are one shared no-op singleton — no allocation, no clock read — and
+  :func:`get_tracer` returns ``None`` for the sites that only write
+  instants.
+- **importable without jax**: the import of ``jax.profiler`` is lazy,
+  guarded and resolved once; without jax sink (ii) is simply absent.
 - **low overhead enabled**: events are plain tuples appended to a
   bounded ``deque`` ring buffer (oldest events drop when full, counted
   in :attr:`Tracer.dropped`); dict materialization and lane metadata
@@ -60,29 +72,62 @@ _DEFAULT_CAPACITY = 1 << 16
 
 
 class _Span:
-    """Context manager recording one complete ("X") event on exit."""
+    """THE span: on exit one complete ("X") event in the ring (when a
+    tracer and a lane are given), the whole body inside a
+    ``jax.profiler.TraceAnnotation`` (when one is given), and the
+    body's seconds under ``name`` in ``seconds_into`` (when given —
+    set-up phases a caller logs whether or not a sink is on).
 
-    __slots__ = ("_tracer", "_name", "_lane", "_args", "_t0")
+    ``start_us`` / ``end_us`` are the ring clock's reads, for what a
+    site records after the fact from the same instants (a request's
+    waterfall segments)."""
 
-    def __init__(self, tracer: "Tracer", name: str, lane: Lane,
-                 args: Optional[Dict[str, Any]]):
-        self._tracer = tracer
+    __slots__ = ("_tracer", "_name", "_lane", "_args", "_annotation",
+                 "_seconds_into", "start_us", "end_us")
+
+    def __init__(self, tracer: Optional["Tracer"], name: str,
+                 lane: Optional[Lane],
+                 args: Optional[Dict[str, Any]] = None,
+                 annotation: Any = None,
+                 seconds_into: Optional[Dict[str, float]] = None):
+        self._tracer = tracer if lane is not None else None
         self._name = name
         self._lane = lane
         self._args = args
-        self._t0 = 0.0
+        self._annotation = annotation
+        self._seconds_into = seconds_into
+        self.start_us = 0.0
+        self.end_us = 0.0
 
     def __enter__(self) -> "_Span":
-        self._t0 = self._tracer.now()
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        if self._tracer is not None:
+            self.start_us = self._tracer.now()
+        elif self._seconds_into is not None:
+            self.start_us = time.monotonic() * 1e6
         return self
 
     def __exit__(self, *exc) -> bool:
-        self._tracer.complete(self._name, self._lane, self._t0, self._args)
+        tracer = self._tracer
+        if tracer is not None:
+            self.end_us = tracer.now()
+            tracer.complete(self._name, self._lane, self.start_us,
+                            self._args,
+                            dur_us=self.end_us - self.start_us)
+        elif self._seconds_into is not None:
+            self.end_us = time.monotonic() * 1e6
+        if self._seconds_into is not None:
+            self._seconds_into[self._name] = max(
+                self.end_us - self.start_us, 0.0) / 1e6
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
         return False
 
 
 class _NullSpan:
-    """The disabled-tracing span: one shared instance, allocates nothing."""
+    """The span when neither sink is on: one shared instance, allocates
+    nothing."""
 
     __slots__ = ()
 
@@ -362,9 +407,11 @@ _STATE: List[Optional[Tracer]] = [None]
 def get_tracer() -> Optional[Tracer]:
     """The active tracer, or ``None`` when tracing is disabled.
 
-    This is THE hot-path accessor: instrumentation sites call it once
-    per step/tick, test ``is None``, and skip all tracing work when
-    disabled — the disabled cost is one function call and one compare.
+    For what only the ring holds (instants, counters, async arcs, a
+    request's waterfall recorded after the fact): a site calls it, tests
+    ``is None``, and skips the work when disabled.  Spans go through
+    :func:`span_sinks` / :func:`trace_span`, which also feed the
+    profiler.
     """
     return _STATE[0]
 
@@ -393,19 +440,122 @@ def disable_tracing() -> Optional[Tracer]:
     return tracer
 
 
-def trace_span(name: str, process: str, thread: str = "main",
-               args: Optional[Dict[str, Any]] = None):
-    """Span-or-no-op for cool paths (allocator solves, checkpoint saves).
+# --- the profiler sink -------------------------------------------------------
+# jax.profiler.TraceAnnotation, looked up once and only when a span is
+# first asked for: telemetry/ stays importable (and file-path-loadable)
+# on a runner without jax, where this sink is simply absent.
+_UNRESOLVED = object()
+_ANNOTATION: List[Any] = [_UNRESOLVED]
 
-    When tracing is disabled this returns one shared singleton — zero
-    allocation, zero clock reads — so library code can wrap phases
-    unconditionally.  Hot loops should instead hoist ``get_tracer()``
-    out of the loop and call :meth:`Tracer.complete` directly.
+
+def _resolve_annotation() -> Any:
+    try:
+        from jax.profiler import TraceAnnotation as annotation
+
+        annotation.is_enabled()
+    except (ImportError, AttributeError):  # no jax, or no TraceMe binding
+        annotation = None
+    _ANNOTATION[0] = annotation
+    return annotation
+
+
+def _scalar_args(args: Dict[str, Any]) -> Dict[str, Any]:
+    """What a profiler stat can hold: identifiers and counts.  Lists
+    (a wave's request ids) stay in the ring's args only."""
+    return {k: v for k, v in args.items()
+            if isinstance(v, (int, float, str))}
+
+
+class _Sinks:
+    """The sinks that are on, looked up once per step by an engine and
+    asked for every span of that step."""
+
+    __slots__ = ("tracer", "_annotation")
+
+    def __init__(self, tracer: Optional[Tracer], annotation: Any):
+        self.tracer = tracer
+        self._annotation = annotation
+
+    def lane(self, process: str, thread: str = "main") -> Optional[Lane]:
+        """The ring's lane, or ``None`` when only the profiler is on."""
+        if self.tracer is None:
+            return None
+        return self.tracer.lane(process, thread)
+
+    def span(self, name: str, lane: Optional[Lane] = None,
+             args: Optional[Dict[str, Any]] = None,
+             ring: Optional[str] = None,
+             seconds_into: Optional[Dict[str, float]] = None) -> _Span:
+        """One span called ``name`` in the profiler's trace and ``ring``
+        (default: ``name``) on ``lane`` in the ring."""
+        annotation = self._annotation
+        if annotation is not None:
+            annotation = (annotation(name, **_scalar_args(args)) if args
+                          else annotation(name))
+        return _Span(self.tracer, ring or name, lane, args, annotation,
+                     seconds_into)
+
+
+class _NullSinks:
+    """Neither sink is on: every span is the shared no-op."""
+
+    __slots__ = ()
+    tracer = None
+
+    def lane(self, process: str, thread: str = "main") -> None:
+        return None
+
+    def span(self, name: str, lane: Optional[Lane] = None,
+             args: Optional[Dict[str, Any]] = None,
+             ring: Optional[str] = None,
+             seconds_into: Optional[Dict[str, float]] = None):
+        if seconds_into is not None:
+            return _Span(None, name, None, None, None, seconds_into)
+        return _NULL_SPAN
+
+
+_NULL_SINKS = _NullSinks()
+
+
+def span_sinks():
+    """Which sinks are on right now: the ring (``enable_tracing()``)
+    and/or a running JAX profiler session, whoever started it (a
+    benchmark's ``--trace 1``, ``jax.profiler.start_trace``, a
+    TensorBoard capture).
+
+    THE hot-path accessor: an engine calls it once per ``train_step`` /
+    ``engine.step()`` and opens every span of that step through the
+    result, so the cost with both sinks off is this one call (a ``None``
+    test and one ``is_enabled()``) plus one no-op method call per site,
+    and a profiler that starts mid-step is seen from the next step on.
     """
     tracer = _STATE[0]
-    if tracer is None:
-        return _NULL_SPAN
-    return tracer.span(name, tracer.lane(process, thread), args)
+    annotation = _ANNOTATION[0]
+    if annotation is _UNRESOLVED:
+        annotation = _resolve_annotation()
+    if annotation is not None and not annotation.is_enabled():
+        annotation = None
+    if tracer is None and annotation is None:
+        return _NULL_SINKS
+    return _Sinks(tracer, annotation)
+
+
+def trace_span(name: str, process: str, thread: str = "main",
+               args: Optional[Dict[str, Any]] = None,
+               ring: Optional[str] = None,
+               seconds_into: Optional[Dict[str, float]] = None):
+    """The span for a site that opens one now and then (allocator
+    solves, checkpoint saves, the runner's iteration, set-up phases):
+    ``span_sinks().span(...)`` on the lane ``(process, thread)``.
+
+    With neither sink on this returns one shared singleton — zero
+    allocation, zero clock reads — so library code wraps phases
+    unconditionally.  A loop that opens many spans a step hoists
+    :func:`span_sinks` out of the loop instead.
+    """
+    sinks = span_sinks()
+    return sinks.span(name, sinks.lane(process, thread), args, ring,
+                      seconds_into)
 
 
 __all__ = [
@@ -413,5 +563,6 @@ __all__ = [
     "get_tracer",
     "enable_tracing",
     "disable_tracing",
+    "span_sinks",
     "trace_span",
 ]

@@ -1,32 +1,16 @@
-"""Profiling / tracing helpers.
+"""XLA's per-executable cost model behind a small API.
 
-The reference's tracing story is wall-clock phase logging plus a shared-file
-timer (SURVEY §5).  On TPU the native story is richer: ``jax.profiler``
-traces (viewable in TensorBoard/Perfetto) plus XLA's per-executable cost
-model.  These helpers wrap both behind a small API.
+Spans are not made here: the one span primitive is
+``telemetry.trace_span`` / ``telemetry.span_sinks``, which feeds the
+profiler's trace (``jax.profiler.TraceAnnotation``) whenever a profiler
+session is running.
 """
 
 from __future__ import annotations
 
-import contextlib
-from typing import Dict, Optional
+from typing import Dict
 
 import jax
-
-
-@contextlib.contextmanager
-def trace(log_dir: str):
-    """Capture a jax.profiler trace of the enclosed block into ``log_dir``."""
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-
-
-def annotate(name: str):
-    """Named region that shows up on the profiler timeline."""
-    return jax.profiler.TraceAnnotation(name)
 
 
 def compiled_cost(fn, *args) -> Dict[str, float]:
@@ -46,4 +30,4 @@ def compiled_cost(fn, *args) -> Dict[str, float]:
     return out
 
 
-__all__ = ["trace", "annotate", "compiled_cost"]
+__all__ = ["compiled_cost"]

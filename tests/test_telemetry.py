@@ -133,6 +133,12 @@ def test_disabled_path_is_a_shared_noop():
     assert s1 is s2 is _NULL_SPAN
     with s1:
         pass
+    # the hoisted form an engine uses: one shared null object per lookup,
+    # the same shared span from every site, no lane to look up
+    sinks = telemetry.span_sinks()
+    assert sinks is telemetry.span_sinks() and sinks.tracer is None
+    assert sinks.span("sky.pipe.fwd", None, {"mb": 0}) is _NULL_SPAN
+    assert sinks.lane("host", "dispatch") is None
     # enable -> real spans; disable -> back to the singleton
     tracer = telemetry.enable_tracing()
     assert telemetry.trace_span("c", "p", "t") is not _NULL_SPAN
@@ -317,11 +323,16 @@ def test_tracing_overhead_under_one_percent(devices):
 
     bench = Tracer(capacity=1 << 18)
     lane = bench.lane("bench", "events")
-    n = 20_000
-    t0 = time.perf_counter()
-    for _ in range(n):
-        bench.complete("e", lane, bench.now())
-    cost_s = (time.perf_counter() - t0) / n
+    n = 4_000
+    # the cost of an event is the code's, not the host's load: the
+    # quietest of a few batches (a loaded tier-1 run stretches single
+    # batches several-fold)
+    cost_s = float("inf")
+    for _ in range(5):
+        t0 = time.perf_counter()
+        for _ in range(n):
+            bench.complete("e", lane, bench.now())
+        cost_s = min(cost_s, (time.perf_counter() - t0) / n)
 
     overhead = events_per_step * cost_s / step_s
     assert overhead < 0.01, (
