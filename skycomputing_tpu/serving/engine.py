@@ -200,6 +200,15 @@ class ServingStats:
     # the work the bounded gather and the fused kernel shrink)
     quantized_pages: int = 0
     dequant_blocks: int = 0
+    # paged-attention accounting, one target forward per paged decode
+    # tick: attn_pages_live = pages inside the rows' causal bounds
+    # (sum over the program's rows of ceil((index + Lq) / page_size),
+    # an idle row's one page included) — what the fused kernel fetches
+    # and steps through; attn_pages_table = rows x table width — what
+    # the XLA reference gathers.  Their ratio is the share of the
+    # table the kernel walks
+    attn_pages_live: int = 0
+    attn_pages_table: int = 0
     # speculative-decoding accounting (spec_k > 0): draft_tokens =
     # USABLE draft proposals (capped at each row's remaining token
     # budget — surplus drafts a row could never commit don't deflate
@@ -255,6 +264,7 @@ class ServingStats:
         "prefix_evictions": "counter",
         "prefill_chunks": "counter", "chunk_stalls": "counter",
         "quantized_pages": "counter", "dequant_blocks": "counter",
+        "attn_pages_live": "counter", "attn_pages_table": "counter",
         "draft_tokens": "counter",
         "accepted_draft_tokens": "counter",
         "spec_rollbacks": "counter",
@@ -306,6 +316,8 @@ class ServingStats:
             chunk_stalls=self.chunk_stalls,
             quantized_pages=self.quantized_pages,
             dequant_blocks=self.dequant_blocks,
+            attn_pages_live=self.attn_pages_live,
+            attn_pages_table=self.attn_pages_table,
             draft_tokens=self.draft_tokens,
             accepted_draft_tokens=self.accepted_draft_tokens,
             spec_rollbacks=self.spec_rollbacks,
@@ -3023,8 +3035,9 @@ class ServingEngine(LiveMetricsMixin):
         """Page-table columns this step actually needs (the PR 12
         honest-gather fix): the wave's max live length, ceiled to a
         page, then to the next power-of-two page count with the largest
-        bucket's span as floor — so the XLA reference gathers (and the
-        kernel's grid walks) O(live tokens), not O(max_pages), while
+        bucket's span as floor — so the XLA reference gathers O(live
+        tokens), not O(max_pages) (the kernel walks each row's own live
+        pages whatever the width; ``attn_pages_live``), while
         the distinct compile-shape set stays logarithmic and warmable
         exactly like prefill buckets.  ``gather_pages="full"`` keeps
         the PR 9 behavior: the full table width every step (the
@@ -3058,6 +3071,22 @@ class ServingEngine(LiveMetricsMixin):
             )
             self.stats.quantized_pages += int(touched.sum())
         self.stats.dequant_blocks += int(rows) * int(width)
+
+    def _count_attn_pages(self, query_ends, width: int) -> Dict[str, int]:
+        """Bank one paged decode forward's page walk and return it as
+        span arguments.  ``query_ends``: ``index + Lq`` of each active
+        row; the program's other rows sit at index 0 and cost the one
+        page the kernel always reads."""
+        ends = np.asarray(query_ends, np.int64)
+        idle = self.max_concurrency - ends.size
+        live = np.minimum(-(-ends // self.page_size), width)
+        counts = {
+            "attn_pages_live": int(live.sum()) + idle,
+            "attn_pages_table": self.max_concurrency * int(width),
+        }
+        self.stats.attn_pages_live += counts["attn_pages_live"]
+        self.stats.attn_pages_table += counts["attn_pages_table"]
+        return counts
 
     def _run_paged_stages(self, data, tables, index, valid, ring,
                           span_args=None):
@@ -3163,7 +3192,10 @@ class ServingEngine(LiveMetricsMixin):
         if not active:
             return
         sp, eng = self._sp, self._eng_lane
-        tick_args = {"active": len(active)}
+        ends = [r.index + 1 for r in active]
+        width = self._table_width(ends)
+        tick_args = {"active": len(active),
+                     **self._count_attn_pages(ends, width)}
         with sp.span("sky.serve.decode", eng, tick_args):
             with sp.span("sky.serve.build", eng):
                 rows = self.max_concurrency
@@ -3182,7 +3214,6 @@ class ServingEngine(LiveMetricsMixin):
                     held = self._pool.table(r.request_id)
                     tables[r.slot, : len(held)] = held
 
-                width = self._table_width(valid)
                 tables = tables[:, :width]
                 self._count_quant(index, valid, width, len(active))
             t0 = time.perf_counter()
@@ -3245,7 +3276,21 @@ class ServingEngine(LiveMetricsMixin):
             return
         k = self.spec_k
         sp, eng = self._sp, self._eng_lane
-        tick_args = {"active": len(active), "spec_k": k}
+        # verify writes cap at min(index+k+1, reserve); one table width
+        # (covering that bound) serves BOTH the draft loop and the
+        # verify forward, so the two stay on one warmed shape set
+        width = self._table_width([
+            min(r.index + k + 1, int(r.prompt.size) + r.max_new_tokens)
+            for r in active
+        ])
+        tick_args = {
+            "active": len(active), "spec_k": k,
+            # the verify forward's walk (the draft's k passes run
+            # against stage 0's prefix of layers only)
+            **self._count_attn_pages(
+                [r.index + k + 1 for r in active], width
+            ),
+        }
         with sp.span("sky.serve.decode", eng, tick_args):
             with sp.span("sky.serve.build", eng):
                 rows = self.max_concurrency
@@ -3264,11 +3309,7 @@ class ServingEngine(LiveMetricsMixin):
                     held = self._pool.table(r.request_id)
                     tables[r.slot, : len(held)] = held
 
-                # verify writes cap at min(index+k+1, reserve); one table width
-                # (covering that bound) serves BOTH the draft loop and the
-                # verify forward, so the two stay on one warmed shape set
                 valid = np.minimum(index0 + k + 1, reserve)
-                width = self._table_width(valid)
                 tables = tables[:, :width]
                 self._count_quant(index0, valid, width, len(active))
                 if self.kv_dtype == "int8":

@@ -105,6 +105,107 @@ def test_kernel_int8_dequant_matches_reference():
 
 
 # --------------------------------------------------------------------------
+# the blocking: live pages only, several to a step, heads stacked by shape
+# --------------------------------------------------------------------------
+
+
+def _blocked_case(seed, heads, head_dim, query_len, lengths, width,
+                  quantized):
+    """Rows whose LAST query sits at ``length - 1``, over pages of 4
+    drawn without repeats, the table's tail left at the sentinel."""
+    rng = np.random.default_rng(seed)
+    rows = len(lengths)
+    pages = sum(-(-n // PS) for n in lengths) + 3
+    table = np.full((rows, width), pages, np.int32)
+    free = rng.permutation(pages)
+    for r, n in enumerate(lengths):
+        held, free = free[: -(-n // PS)], free[-(-n // PS):]
+        table[r, : held.size] = held
+    index = np.asarray(lengths, np.int32) - query_len
+    q = rng.standard_normal(
+        (rows, query_len, heads, head_dim)).astype(np.float32)
+    shape = (pages, PS, heads, head_dim)
+    if quantized:
+        k = rng.integers(-127, 128, shape).astype(np.int8)
+        v = rng.integers(-127, 128, shape).astype(np.int8)
+        scales = dict(
+            k_scale=rng.uniform(0.002, 0.01, (pages, heads)).astype(
+                np.float32),
+            v_scale=rng.uniform(0.005, 0.03, (pages, heads)).astype(
+                np.float32),
+        )
+    else:
+        k = rng.standard_normal(shape).astype(np.float32)
+        v = rng.standard_normal(shape).astype(np.float32)
+        scales = {}
+    out = paged_attention(q, k, v, table, index, interpret=True, **scales)
+    ref = paged_attention_reference(q, k, v, table, index, **scales)
+    assert np.all(np.isfinite(np.asarray(out)))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+
+
+# a 24-column table of 4-token pages is walked 16 pages (64 positions)
+# to a step: a row of one page, one ending on the step's last position,
+# one a position past it, one filling the table; and a table far wider
+# than any of its rows, the second step never taken
+BLOCKED_ROWS = {
+    "mixed": [4, 64, 65, 96],
+    "short": [4, 9, 30],
+}
+# H*D of 32 and 192 are whole-row blocks (no lane-aligned head group);
+# 256 is two lane tiles, its heads stacked four or two at a time
+BLOCKED_HEADS = {"row32": (2, 16), "row192": (3, 64), "row256": (4, 64)}
+
+
+def test_blocking_of_the_test_shapes_is_what_the_cases_assume():
+    from skycomputing_tpu.ops.paged_attention import (
+        _heads_per_block,
+        _pages_per_step,
+        _query_rows_per_step,
+    )
+
+    assert _pages_per_step(PS, 32 * 4, 24) == 16
+    assert _pages_per_step(PS, 256, 80) == 64
+    assert _query_rows_per_step(4, 4, 64, 4) == 4
+    assert _query_rows_per_step(300, 4, 64, 4) == 256
+    assert _heads_per_block(4, 64, 1) == 4
+    assert _heads_per_block(4, 64, 256) == 2
+    assert _heads_per_block(3, 64, 1) == 3 == _heads_per_block(3, 64, 256)
+    # the benchmark's cell: 8 bf16 pages of 40 KB a step, the whole row
+    # of heads to a decode tick's matmul, a head pair to a prefill block
+    assert _pages_per_step(16, 1280 * 2, 64) == 8
+    assert _query_rows_per_step(768, 20, 64, 2) == 128
+    assert _heads_per_block(20, 64, 1) == 20
+    assert _heads_per_block(20, 64, 128) == 2
+
+
+@pytest.mark.parametrize("kv", ["fp", "int8"])
+@pytest.mark.parametrize("heads", list(BLOCKED_HEADS))
+@pytest.mark.parametrize("rows", list(BLOCKED_ROWS))
+@pytest.mark.parametrize("query_len", [1, 4])
+def test_kernel_walks_live_pages_of_unequal_rows(query_len, rows, heads,
+                                                 kv):
+    """Rows of unequal length under one table: each row's walk ends at
+    its own last live page (the table's sentinel tail is never read),
+    whether that page closes a step, opens the next or is the table's
+    last column, for decode and verify query lengths."""
+    H_, D_ = BLOCKED_HEADS[heads]
+    _blocked_case(11, H_, D_, query_len, BLOCKED_ROWS[rows], 24,
+                  kv == "int8")
+
+
+@pytest.mark.parametrize("kv", ["fp", "int8"])
+@pytest.mark.parametrize("heads", ["row32", "row256"])
+def test_kernel_tiles_long_query_blocks(heads, kv):
+    """A prefill-length query block is walked 256 rows at a time (the
+    second block partial), each block to its own causal bound, with a
+    cached prefix before the first query of one row."""
+    H_, D_ = BLOCKED_HEADS[heads]
+    _blocked_case(12, H_, D_, 300, [300, 320], 80, kv == "int8")
+
+
+# --------------------------------------------------------------------------
 # int8 write-time quantization (the scale slab's contract)
 # --------------------------------------------------------------------------
 

@@ -28,6 +28,7 @@ from skycomputing_tpu.serving import (
     PagedKVCachePool,
     Request,
     ServingEngine,
+    ServingStats,
     ShapeBucketer,
     SlotKVCachePool,
 )
@@ -735,6 +736,44 @@ def test_paged_attn_impl_pallas_identity_and_recompile_pin(gpt):
         np.testing.assert_array_equal(
             p_out[pr.request_id], reference(fwd, pr)
         )
+
+
+def test_paged_decode_counts_live_and_table_pages(gpt):
+    """``attn_pages_live`` / ``attn_pages_table`` per paged decode tick,
+    against a table worked out by hand: pages of 4, three rows, prompts
+    of 14 and 5 tokens (so first queries at 14 and 5), the 16-bucket's
+    four columns as the table's floor and eight once a row needs a
+    fifth; an idle row costs the one page the kernel always reads."""
+    layer_cfgs, params, _ = gpt
+    engine = ServingEngine(
+        layer_cfgs, params, num_slots=3, max_len=64, buckets=(8, 16),
+        prefill_batch=2, kv_layout="paged", page_size=4,
+        max_concurrency=3,
+    )
+    for length, new in ((14, 6), (5, 3)):
+        engine.submit(Request(
+            prompt=np.arange(1, length + 1, dtype=np.int32),
+            max_new_tokens=new,
+        ))
+    by_hand = [
+        # queries at    pages live       rows x table width
+        (4 + 2 + 1, 3 * 4),  # 14, 5
+        (4 + 2 + 1, 3 * 4),  # 15, 6: the short request's last token
+        (5 + 1 + 1, 3 * 8),  # 16: a fifth page, the next width
+        (5 + 1 + 1, 3 * 8),  # 17
+        (5 + 1 + 1, 3 * 8),  # 18
+    ]
+    seen, before = [], (0, 0)
+    while engine._running or engine._queue.depth:
+        engine.step()
+        now = (engine.stats.attn_pages_live, engine.stats.attn_pages_table)
+        seen.append((now[0] - before[0], now[1] - before[1]))
+        before = now
+    assert seen == by_hand
+    snap = engine.stats.snapshot()
+    assert snap["attn_pages_live"] == 35 and snap["attn_pages_table"] == 96
+    assert ServingStats.FIELD_TYPES["attn_pages_live"] == "counter"
+    assert ServingStats.FIELD_TYPES["attn_pages_table"] == "counter"
 
 
 # re-tiered slow: tier-1 wall-clock budget; the full run keeps it, and

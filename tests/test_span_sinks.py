@@ -369,6 +369,16 @@ def test_serving_leaves_the_catalogue_in_the_profilers_trace(
     if kv_layout == "paged":
         assert all(s["shared"] == 0 for s in by_name["sky.serve.prefill"])
     assert [s["active"] for s in by_name["sky.serve.decode"]] == [2, 2, 2]
+    if kv_layout == "paged":
+        # pages of 8; queries at 14, 15, 16 and 5, 6, 7, ten idle rows of
+        # the program's twelve at a page each; the 16-bucket's 2 columns
+        # until a row needs a third
+        rows = engine.max_concurrency
+        assert rows == 12
+        assert [(s["attn_pages_live"], s["attn_pages_table"])
+                for s in by_name["sky.serve.decode"]] \
+            == [(2 + 1 + 10, rows * 2), (2 + 1 + 10, rows * 2),
+                (3 + 1 + 10, rows * 4)]
     assert all(s["stage"] == 0 for s in by_name["sky.serve.stage"])
     for name in ("sky.serve.put", "sky.serve.stage", "sky.serve.wait"):
         # one stage: one put, one dispatch and one barrier to a run

@@ -68,27 +68,42 @@ def test_flash_attention_forward_compiles_at_bert_large(on_v5e):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("query_len", [1, 4], ids=["decode", "verify"])
+# (rows, query_len, heads, pages): chip_smoke.py's GPT-2 small server
+# (decode, speculative verify) and the benchmark's GPT-2-large cell (a
+# 32-row decode tick, its widest prefill bucket: query blocks tiled)
+PAGED_SHAPES = {
+    "decode": (ROWS, 1, HEADS, PAGES),
+    "verify": (ROWS, 4, HEADS, PAGES),
+    "large-decode": (32, 1, 20, 2048),
+    "large-prefill768": (1, 768, 20, 2048),
+}
+
+
+@pytest.mark.parametrize("shape", list(PAGED_SHAPES))
 @pytest.mark.parametrize("kv", ["fp", "int8"])
-def test_paged_attention_compiles_at_gpt2_widths(on_v5e, kv, query_len):
-    q = on_v5e((ROWS, query_len, HEADS, HEAD_DIM), jnp.bfloat16)
+def test_paged_attention_compiles_at_gpt2_widths(on_v5e, kv, shape):
+    rows, query_len, heads, pages = PAGED_SHAPES[shape]
+    q = on_v5e((rows, query_len, heads, HEAD_DIM), jnp.bfloat16)
     page_dtype = jnp.int8 if kv == "int8" else jnp.bfloat16
-    pages = on_v5e((PAGES, PAGE_SIZE, HEADS, HEAD_DIM), page_dtype)
-    table = on_v5e((ROWS, WIDTH), jnp.int32)
-    index = on_v5e((ROWS,), jnp.int32)
+    pages_ = on_v5e((pages, PAGE_SIZE, heads, HEAD_DIM), page_dtype)
+    table = on_v5e((rows, WIDTH), jnp.int32)
+    index = on_v5e((rows,), jnp.int32)
     if kv == "int8":
-        scale = on_v5e((PAGES, HEADS), jnp.float32)
+        scale = on_v5e((pages, heads), jnp.float32)
         text = _compiled_text(
             lambda q, k, v, t, i, ks, vs: paged_attention(
                 q, k, v, t, i, k_scale=ks, v_scale=vs, interpret=False
             ),
-            q, pages, pages, table, index, scale, scale,
+            q, pages_, pages_, table, index, scale, scale,
         )
     else:
         text = _compiled_text(
             lambda q, k, v, t, i: paged_attention(
                 q, k, v, t, i, interpret=False
             ),
-            q, pages, pages, table, index,
+            q, pages_, pages_, table, index,
         )
     assert "tpu_custom_call" in text
+    # the name a device trace shows, which the benchmark's
+    # ``paged_attn_pct.serve`` finds the kernel by (``paged``)
+    assert "%decode_paged_attention" in text
