@@ -241,7 +241,7 @@ class GptBlock_Attn(nn.Module):
         """One incremental step against PAGED slabs (PagedAttention).
 
         ``hidden``: [R, Lq, H] new positions index..index+Lq-1 per row;
-        ``k_slab``/``v_slab``: [num_pages, page_size, heads, head_dim]
+        ``k_slab``/``v_slab``: [num_pages, page_size, heads * head_dim]
         physical page pools shared by every row — plain arrays, or
         ``serving/kv_cache.QuantizedPages`` (int8 values + scale slab);
         ``page_table``: [R, table_width] logical->physical map
@@ -296,7 +296,9 @@ class GptBlock_Attn(nn.Module):
                 )
             ctx = ctx.astype(dtype)
         elif attn_impl == "xla":
-            k_virt, v_virt = gather_kv_pages(k_slab, v_slab, page_table)
+            k_virt, v_virt = gather_kv_pages(
+                k_slab, v_slab, page_table, cfg.num_attention_heads
+            )
 
             scores = jnp.einsum(
                 "blhd,bmhd->bhlm", q, k_virt.astype(dtype)
@@ -656,7 +658,7 @@ def apply_kv_paged(
     """Thread one PAGED decode step through a module slice — the paged
     twin of :func:`apply_kv_cached`.
 
-    ``slabs`` is one ``[num_pages, page_size, heads, head_dim]`` (k, v)
+    ``slabs`` is one ``[num_pages, page_size, heads * head_dim]`` (k, v)
     pair per attention unit in the slice (plain arrays or
     ``QuantizedPages``); ``page_table``/``index``/``valid_len`` are
     shared across the slice's layers (one logical sequence per row,

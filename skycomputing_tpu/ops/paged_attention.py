@@ -5,7 +5,7 @@ al., SOSP '23): refcounted pages, per-request page tables, copy-on-write
 prefix sharing.  Its device math, though, still materialized each row's
 full virtual KV view in HBM every layer of every decode tick
 (``serving/kv_cache.gather_kv_pages``): a ``[R, table_width * page_size,
-heads, head_dim]`` gather whose cost scales with the TABLE width, not the
+heads * head_dim]`` gather whose cost scales with the TABLE width, not the
 tokens actually live.  This module is the kernel half: the page walk
 moves INSIDE a Pallas kernel, so the gathered view never exists —
 
@@ -18,9 +18,14 @@ moves INSIDE a Pallas kernel, so the gathered view never exists —
   flight while this one is computed (the next row's first chunk under
   this row's last).  A chunk holds whole ``H * D``
   rows — a whole-axis last dimension is a legal block at any width —
-  and ``pages_per_step`` is reckoned from the page's bytes against a
-  VMEM budget (8 bf16 pages of 40 KB at GPT-2-large), so bytes set
-  the pace and not grid steps;
+  which is the shape the pool is STORED in (``serving/kv_cache.
+  init_paged_caches``: ``[num_pages, page_size, H * D]``), so the
+  slabs reach the call untouched.  A ``[.., H, D]`` pool reshaped
+  here would NOT be free: on a TPU the last two axes are tiled, the
+  two shapes tile the same bytes differently, and XLA copies every
+  slab, whatever is live.  ``pages_per_step`` is reckoned from the
+  page's bytes against a VMEM budget (8 bf16 pages of 40 KB at
+  GPT-2-large), so bytes set the pace and not grid steps;
 - live pages only: a block's walk ends at ``ceil((index + its last
   query + 1) / page_size)`` pages.  A page past that is neither
   fetched nor stepped, so a row costs what it holds and not the
@@ -368,7 +373,8 @@ def paged_attention(
     ``q``: [R, Lq, H, D] query block (``Lq = 1`` decode, ``Lq = k + 1``
     speculative verify, a whole prompt bucket in prefill);
     ``k_pages``/``v_pages``: [num_pages, page_size,
-    H, D] physical page pools — fp, or int8 with ``k_scale``/``v_scale``
+    H * D] physical page pools, heads merged into the last axis as the
+    cache manager stores them — fp, or int8 with ``k_scale``/``v_scale``
     [num_pages, H] per-page-per-head dequant scales; ``page_table``:
     [R, table_width] int32 logical->physical, sentinel-padded
     (``>= num_pages`` entries clamp and are causally masked);
@@ -424,19 +430,15 @@ def _paged_attention(q, k_pages, v_pages, page_table, index, k_scale,
     def q_map(r, j, table_ref, idx_ref):
         return (r, j, 0)
 
-    # heads flatten into the lane axis (free reshapes: H and D are the
-    # trailing, contiguous dims); a whole-axis last dimension is a legal
-    # block at any width
+    # the pools go in as they are stored; only q (an activation) has
+    # its heads merged into the lane axis here.  A whole-axis last
+    # dimension is a legal block at any width
     in_specs = [
         pl.BlockSpec((1, Tq, HD), q_map),
         pl.BlockSpec(memory_space=pl.ANY),
         pl.BlockSpec(memory_space=pl.ANY),
     ]
-    operands = [
-        q.reshape(R, Lq, HD),
-        k_pages.reshape(num_pages, page_size, HD),
-        v_pages.reshape(num_pages, page_size, HD),
-    ]
+    operands = [q.reshape(R, Lq, HD), k_pages, v_pages]
     if quantized:
         scales = [
             _scales_by_position(s, table, pps, page_size)
@@ -509,8 +511,8 @@ def paged_attention_reference(
     flat_pos = jnp.clip(pos.reshape(R, -1), 0, num_pages * page_size - 1)
 
     def gather(slab, scale):
-        flat = slab.reshape((num_pages * page_size,) + slab.shape[2:])
-        out = flat[flat_pos].astype(jnp.float32)
+        flat = slab.reshape(num_pages * page_size, H * D)
+        out = flat[flat_pos].astype(jnp.float32).reshape(R, -1, H, D)
         if scale is not None:
             page_of = flat_pos // page_size
             out = out * scale[page_of][:, :, :, None]

@@ -7,7 +7,7 @@ this package is the serving side the ROADMAP's north star demands:
 - :mod:`.kv_cache` — the KV-cache device math for both layouts: slot
   slabs (fixed ``[slots, max_len, heads, head_dim]``, also backing
   ``models/gpt.py``'s single-request decoder) and paged pools
-  (``[num_pages, page_size, heads, head_dim]`` gather/scatter through
+  (``[num_pages, page_size, heads * head_dim]`` gather/scatter through
   page tables; :class:`QuantizedPages` stores them int8 with
   per-page-per-head scale slabs, quantized at write time), donation-
   friendly in-place updates throughout — the fused decode kernel that

@@ -19,7 +19,7 @@ implemented here:
   max_len, heads, head_dim]`` slabs (``serving/kv_cache.py``) — one
   whole row per request.  ``kv_layout="paged"`` (PagedAttention,
   SOSP '23 + SGLang-style radix prefix caching): per-stage
-  ``[num_pages, page_size, heads, head_dim]`` page pools addressed
+  ``[num_pages, page_size, heads * head_dim]`` page pools addressed
   through per-request page tables (host bookkeeping — free-list
   allocator, refcounts, copy-on-write prefix sharing, radix index,
   swap-preemption — in ``serving/paging.py``), so admission charges a
@@ -458,7 +458,7 @@ _scatter_rows = jax.jit(
 
 class _PagedServingStage:
     """One pipeline stage under the PAGED layout: module slice + device
-    + per-attention-layer page slabs ``[num_pages, page_size, heads,
+    + per-attention-layer page slabs ``[num_pages, page_size, heads *
     head_dim]`` + the one fused step program (prefill and decode are
     the same function at different input shapes — see
     ``models/gpt.apply_kv_paged``)."""

@@ -109,10 +109,12 @@ def decode_positions(index, query_len: int):
 class QuantizedPages(NamedTuple):
     """An int8 page slab with its per-page-per-head dequant scales.
 
-    ``values``: [num_pages, page_size, heads, head_dim] int8;
+    ``values``: [num_pages, page_size, heads * head_dim] int8 (heads
+    and head_dim merged on the last axis, as the kernel reads a page);
     ``scale``: [num_pages, heads] float32 — the parallel *scale slab*.
     One symmetric amax scale covers a (page, head) tile: dequantized
-    value = ``values * scale``.  A NamedTuple so it rides jit/pytree
+    value = ``values * scale`` with the scale repeated over its head's
+    ``head_dim`` lanes.  A NamedTuple so it rides jit/pytree
     plumbing (donation, device_put, scatter/gather helpers) exactly
     like a plain slab array; every paged-math entry point here
     dispatches on this type, so ``kv_dtype="int8"`` changes no caller
@@ -164,7 +166,7 @@ def _paged_update_kv_int8(
     the same argument as the fp path, at page granularity.
     """
     num_pages, page_size = k_slab.values.shape[0], k_slab.values.shape[1]
-    R, Lq = k_new.shape[0], k_new.shape[1]
+    R, Lq, H, D = k_new.shape
     max_pages = page_table.shape[1]
     index = jnp.reshape(index, (-1,))
     valid = jnp.reshape(valid_len, (-1,))
@@ -185,7 +187,9 @@ def _paged_update_kv_int8(
             )[:, 0]
             real = in_span & (phys >= 0) & (phys < num_pages)
             src = jnp.clip(phys, 0, num_pages - 1)
-            old_q = vals[src]                 # [R, ps, H, D]
+            # gathered pages (R of them, never the pool) take their
+            # [ps, H, D] shape for the per-(page, head) scale
+            old_q = vals[src].reshape(R, page_size, H, D)
             old_s = scales[src]               # [R, H]
             old_f = old_q.astype(jnp.float32) * old_s[:, None, :, None]
             gpos = lp[:, None] * page_size + jnp.arange(
@@ -214,7 +218,9 @@ def _paged_update_kv_int8(
             hint = jnp.where(has_old, old_s, 0.0)
             q, s = quantize_pages(merged, scale_hint=hint)
             dest = jnp.where(real, phys, num_pages)
-            vals = vals.at[dest].set(q, mode="drop")
+            vals = vals.at[dest].set(
+                q.reshape(R, page_size, H * D), mode="drop"
+            )
             scales = scales.at[dest].set(s, mode="drop")
         return QuantizedPages(vals, scales)
 
@@ -226,7 +232,7 @@ def paged_update_kv(
 ):
     """Scatter ``k_new``/``v_new`` into paged slabs through page tables.
 
-    ``k_slab``/``v_slab``: [num_pages, page_size, heads, head_dim]
+    ``k_slab``/``v_slab``: [num_pages, page_size, heads * head_dim]
     physical page pools; ``k_new``/``v_new``: [R, Lq, heads, head_dim];
     ``page_table``: [R, max_pages] int32, logical page -> physical page,
     padded with an out-of-range sentinel (>= num_pages);
@@ -241,6 +247,14 @@ def paged_update_kv(
     partial shared page is copied-on-write into a private page before
     the owner's first append — so scatter destinations are disjoint
     across rows by construction and scatter order cannot matter.
+
+    The scatter goes through the slab's ``[num_pages * page_size, heads
+    * head_dim]`` view.  Merging the two LEADING axes leaves the tiled
+    minor pair alone, so on a TPU the view is a bitcast wherever
+    ``page_size`` is a whole number of sublane tiles (16 rows of
+    bfloat16, 8 of float32), and a donated slab is written in place.
+    Only ``k_new``/``v_new`` (activations) are reshaped; a slab never
+    is, which is why the pool is stored with its heads merged.
 
     ``k_slab``/``v_slab`` may be :class:`QuantizedPages` (the
     ``kv_dtype="int8"`` pool): writes then quantize at write time with
@@ -269,24 +283,26 @@ def paged_update_kv(
     flat = jnp.where(keep, flat, oob).reshape(-1)
 
     def scatter(slab, new):
-        flat_slab = slab.reshape((num_pages * page_size,) + slab.shape[2:])
+        flat_slab = slab.reshape(num_pages * page_size, slab.shape[2])
         flat_slab = flat_slab.at[flat].set(
-            new.astype(slab.dtype).reshape((R * Lq,) + new.shape[2:]),
-            mode="drop",
+            new.astype(slab.dtype).reshape(R * Lq, -1), mode="drop"
         )
         return flat_slab.reshape(slab.shape)
 
     return scatter(k_slab, k_new), scatter(v_slab, v_new)
 
 
-def gather_kv_pages(k_slab, v_slab, page_table):
+def gather_kv_pages(k_slab, v_slab, page_table, num_heads: int):
     """Per-row virtual cache views through page tables.
 
     Returns ``(k, v)`` of shape [R, max_pages * page_size, heads,
     head_dim]: row r's logically-contiguous sequence, assembled by
-    gathering its pages.  Sentinel table entries clamp into the slab and
-    read garbage — those virtual positions are at or beyond the row's
-    current length by the pool's covering invariant, so
+    gathering its pages.  The slabs store heads and head_dim merged;
+    the GATHERED view (``R x max_pages`` pages, never the pool) is
+    what takes the ``[.., num_heads, head_dim]`` shape.  Sentinel
+    table entries clamp into the slab and read garbage — those virtual
+    positions are at or beyond the row's current length by the pool's
+    covering invariant, so
     :func:`decode_visibility` masks them exactly like the slot layout
     masks a freed row's stale tail.
 
@@ -306,18 +322,18 @@ def gather_kv_pages(k_slab, v_slab, page_table):
     )
     pos = jnp.clip(pos.reshape(R, -1), 0, num_pages * page_size - 1)
 
+    def rows(values):
+        flat = values.reshape(num_pages * page_size, values.shape[2])
+        return flat[pos].reshape(R, pos.shape[1], num_heads, -1)
+
     def gather(slab):
         if isinstance(slab, QuantizedPages):
-            flat = slab.values.reshape(
-                (num_pages * page_size,) + slab.values.shape[2:]
-            )
             page_of = pos // page_size
             return (
-                flat[pos].astype(jnp.float32)
+                rows(slab.values).astype(jnp.float32)
                 * slab.scale[page_of][:, :, :, None]
             )
-        flat = slab.reshape((num_pages * page_size,) + slab.shape[2:])
-        return flat[pos]
+        return rows(slab)
 
     return gather(k_slab), gather(v_slab)
 
@@ -454,10 +470,14 @@ def init_paged_caches(
     device=None,
     kv_dtype: Optional[str] = None,
 ) -> List[Tuple[jax.Array, jax.Array]]:
-    """Zeroed paged (k, v) slab pairs ``[num_pages, page_size, heads,
-    head_dim]``, one per attention layer.  Same total bytes as a slot
-    slab whenever ``num_pages * page_size == slots * max_len`` — the
-    equal-memory pivot the paged-vs-slot bench holds fixed.
+    """Zeroed paged (k, v) slab pairs ``[num_pages, page_size, heads *
+    head_dim]``, one per attention layer: the shape the paged-attention
+    kernel copies pages in and the scatter writes rows in, so no
+    program ever relays a slab out (on a TPU ``[.., heads, head_dim]``
+    and ``[.., heads * head_dim]`` tile the same bytes differently, and
+    a reshape between them copies the whole slab).  Same total bytes as
+    a slot slab whenever ``num_pages * page_size == slots * max_len`` —
+    the equal-memory pivot the paged-vs-slot bench holds fixed.
 
     ``kv_dtype="int8"`` allocates :class:`QuantizedPages` pairs instead:
     int8 value slabs plus float32 ``[num_pages, heads]`` scale slabs
@@ -466,7 +486,7 @@ def init_paged_caches(
     """
     caches = []
     for spec in specs:
-        shape = (num_pages, page_size, spec.num_heads, spec.head_dim)
+        shape = (num_pages, page_size, spec.num_heads * spec.head_dim)
         if kv_dtype == "int8":
             def one():
                 return QuantizedPages(

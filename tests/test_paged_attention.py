@@ -37,8 +37,8 @@ P, PS, H, D = 10, 4, 2, 16
 def _case(rng, R, Lq, tables, index, quantized=False):
     q = rng.standard_normal((R, Lq, H, D)).astype(np.float32)
     if quantized:
-        k = rng.integers(-127, 128, (P, PS, H, D)).astype(np.int8)
-        v = rng.integers(-127, 128, (P, PS, H, D)).astype(np.int8)
+        k = rng.integers(-127, 128, (P, PS, H * D)).astype(np.int8)
+        v = rng.integers(-127, 128, (P, PS, H * D)).astype(np.int8)
         ks = rng.uniform(0.005, 0.03, (P, H)).astype(np.float32)
         vs = rng.uniform(0.005, 0.03, (P, H)).astype(np.float32)
         out = paged_attention(q, k, v, tables, index, k_scale=ks,
@@ -46,8 +46,8 @@ def _case(rng, R, Lq, tables, index, quantized=False):
         ref = paged_attention_reference(q, k, v, tables, index,
                                         k_scale=ks, v_scale=vs)
     else:
-        k = rng.standard_normal((P, PS, H, D)).astype(np.float32)
-        v = rng.standard_normal((P, PS, H, D)).astype(np.float32)
+        k = rng.standard_normal((P, PS, H * D)).astype(np.float32)
+        v = rng.standard_normal((P, PS, H * D)).astype(np.float32)
         out = paged_attention(q, k, v, tables, index, interpret=True)
         ref = paged_attention_reference(q, k, v, tables, index)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -124,7 +124,7 @@ def _blocked_case(seed, heads, head_dim, query_len, lengths, width,
     index = np.asarray(lengths, np.int32) - query_len
     q = rng.standard_normal(
         (rows, query_len, heads, head_dim)).astype(np.float32)
-    shape = (pages, PS, heads, head_dim)
+    shape = (pages, PS, heads * head_dim)
     if quantized:
         k = rng.integers(-127, 128, shape).astype(np.int8)
         v = rng.integers(-127, 128, shape).astype(np.int8)
@@ -212,7 +212,7 @@ def test_kernel_tiles_long_query_blocks(heads, kv):
 # jitted as the engine runs them (one compile per shape, shared by the
 # tests below) — eager op-by-op dispatch made these the file's slowest
 _update_kv = jax.jit(paged_update_kv)
-_gather_kv = jax.jit(gather_kv_pages)
+_gather_kv = jax.jit(lambda k, v, table: gather_kv_pages(k, v, table, H))
 
 
 def test_int8_update_bounded_error_and_midpage_valid():
@@ -309,7 +309,7 @@ def test_quantize_pages_fresh_page_ignores_stale_scale():
 def test_quantized_pages_ride_jit_and_pytrees():
     """QuantizedPages is a pytree: it crosses jit boundaries (the
     engine's donated stage programs) with type and dtypes intact."""
-    qp = QuantizedPages(jnp.zeros((P, PS, H, D), jnp.int8),
+    qp = QuantizedPages(jnp.zeros((P, PS, H * D), jnp.int8),
                         jnp.ones((P, H), jnp.float32))
 
     @jax.jit
@@ -320,3 +320,86 @@ def test_quantized_pages_ride_jit_and_pytrees():
     assert isinstance(out, QuantizedPages)
     assert out.values.dtype == jnp.int8
     assert float(out.scale[0, 0]) == 2.0
+
+
+# --------------------------------------------------------------------------
+# the pool's stored shape: [num_pages, page_size, H * D] holds, byte for
+# byte, what a [num_pages, page_size, H, D] pool held
+# --------------------------------------------------------------------------
+
+
+def _write_4d(k_new, table, index, valid, quantized):
+    """What a ``[P, PS, H, D]`` pool holds after one write into zeroed
+    pages: a plain loop over rows and positions, and for int8 over the
+    pages a row touched (garbage past ``valid`` zeroed, one
+    ``quantize_pages`` scale per page and head).  Returns (values,
+    scale or None)."""
+    values = np.zeros((P, PS, H, D), np.float32)
+    touched = set()
+    for r in range(k_new.shape[0]):
+        for j in range(k_new.shape[1]):
+            pos = int(index[r]) + j
+            if pos >= int(valid[r]) or pos // PS >= table.shape[1]:
+                continue  # the pad tail is dropped
+            page = int(table[r, pos // PS])
+            if page >= P:
+                continue
+            values[page, pos % PS] = k_new[r, j]
+            touched.add(page)
+    if not quantized:
+        return values, None
+    q = np.zeros((P, PS, H, D), np.int8)
+    scale = np.zeros((P, H), np.float32)
+    pages = sorted(touched)
+    # ``quantize_pages`` speaks [.., PS, H, D] pages, as it always did
+    q[pages], scale[pages] = jax.jit(quantize_pages)(values[pages])
+    return q, scale
+
+
+@pytest.mark.parametrize("kv", ["fp", "int8"])
+def test_merged_heads_pool_equals_the_4d_pool_bit_for_bit(kv):
+    """``paged_update_kv`` then ``gather_kv_pages`` on the stored shape
+    give the bytes a ``[P, PS, H, D]`` pool gave: a write that crosses
+    a page boundary mid-page, a second row whose pad tail (positions at
+    or past ``valid_len``) is dropped, unwritten pages left as they
+    were."""
+    quantized = kv == "int8"
+    spec = KVCacheSpec(max_len=32, num_heads=H, head_dim=D,
+                       dtype="float32")
+    (k0, v0), = init_paged_caches(
+        [spec], P, PS, kv_dtype="int8" if quantized else None
+    )
+    assert (k0.values if quantized else k0).shape == (P, PS, H * D)
+    rng = np.random.default_rng(8)
+    table = np.full((2, 4), P, np.int32)
+    table[0, :3] = [3, 1, 5]
+    table[1, :2] = [0, 2]
+    index = np.array([2, 0], np.int32)   # row 0: positions 2..8, 3 pages
+    valid = np.array([9, 5], np.int32)   # row 1: 5 of 7 written
+    k_new = rng.standard_normal((2, 7, H, D)).astype(np.float32)
+    v_new = rng.standard_normal((2, 7, H, D)).astype(np.float32)
+    k1, v1 = _update_kv(
+        k0, v0, jnp.asarray(k_new), jnp.asarray(v_new),
+        jnp.asarray(table), jnp.asarray(index), jnp.asarray(valid),
+    )
+    wants = [_write_4d(new, table, index, valid, quantized)
+             for new in (k_new, v_new)]
+    for slab, (want, want_scale) in zip((k1, v1), wants):
+        got = np.asarray(slab.values if quantized else slab)
+        assert got.shape == (P, PS, H * D)
+        assert got.tobytes() == want.astype(got.dtype).tobytes()
+        if quantized:  # untouched pages keep the fresh pool's zero scale
+            np.testing.assert_array_equal(
+                np.asarray(slab.scale), want_scale
+            )
+    # the gathered view is what takes the [.., H, D] shape
+    gathered = _gather_kv(k1, v1, jnp.asarray(table))
+    for got, (want, want_scale) in zip(gathered, wants):
+        assert got.shape == (2, 4 * PS, H, D)
+        if quantized:
+            want = want.astype(np.float32) * want_scale[:, None, :, None]
+        for r in range(2):
+            pages = np.minimum(table[r], P - 1)
+            np.testing.assert_array_equal(
+                np.asarray(got)[r], want[pages].reshape(4 * PS, H, D)
+            )

@@ -541,6 +541,58 @@ def test_paged_swap_and_recompute_preempt_identity(gpt):
     engine._pool.check_consistency()
 
 
+# stamped by the parent of the merged-heads pool (slabs stored
+# [num_pages, page_size, heads, head_dim]) for the run below, on this
+# suite's CPU backend: the swap record hashes contiguous bytes, which
+# the stored shape does not change
+SWAP_CHECKSUMS_OF_THE_4D_POOL = {
+    None: "36bd844b8e1c26f1f5e7132521f8b124"
+          "d554f8167d24daeab4adbdb309876913",
+    "int8": "714968c5b2058487c8eb76d49a9d19e1"
+            "3f32c6968a793663c362de724a1addfc",
+}
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"])
+def test_paged_swap_record_bytes_do_not_depend_on_the_stored_shape(
+    gpt, kv_dtype
+):
+    """A swap-out of the same tokens parks the same bytes under the
+    same checksum whether the pool stores ``[.., heads, head_dim]`` or
+    ``[.., heads * head_dim]``: page rows are contiguous either way."""
+    from skycomputing_tpu.serving.engine import _swap_record_checksum
+    from skycomputing_tpu.serving.kv_cache import QuantizedPages
+
+    layer_cfgs, params, _ = gpt
+    engine = paged_engine(layer_cfgs, params, kv_dtype=kv_dtype)
+    victim, *others = mixed_requests(
+        np.random.default_rng(13), [(6, 10), (5, 9), (4, 4)]
+    )
+    for r in (victim, *others):
+        engine.submit(r)
+    for _ in range(3):
+        engine.step()
+    engine.preempt(victim.request_id, mode="swap")
+    record = engine._swapped[victim.request_id]
+    assert record["checksum"] == SWAP_CHECKSUMS_OF_THE_4D_POOL[kv_dtype]
+
+    def as_4d(host):
+        if isinstance(host, QuantizedPages):
+            return QuantizedPages(as_4d(host.values), host.scale)
+        assert host.ndim == 3  # [table width, page_size, heads * head_dim]
+        return host.reshape(host.shape[:2] + (2, -1))
+
+    data_4d = [
+        [(as_4d(k), as_4d(v)) for k, v in stage] for stage in record["data"]
+    ]
+    assert _swap_record_checksum(
+        record["pages"], record["index"], data_4d
+    ) == record["checksum"]
+    engine.run()
+    assert engine.stats.swap_ins == 1 and victim.done
+    engine._pool.check_consistency()
+
+
 def test_paged_zero_steady_state_recompiles(gpt):
     """After one warmup request per bucket (distinct leading tokens so
     the prefix cache cannot collapse a bucket's tail into a smaller

@@ -70,8 +70,8 @@ def main() -> int:
     def run_case(name, R, Lq, tables, index, quantized=False):
         q = rng.standard_normal((R, Lq, H, D)).astype(np.float32)
         if quantized:
-            kq = rng.integers(-127, 128, (P, ps, H, D)).astype(np.int8)
-            vq = rng.integers(-127, 128, (P, ps, H, D)).astype(np.int8)
+            kq = rng.integers(-127, 128, (P, ps, H * D)).astype(np.int8)
+            vq = rng.integers(-127, 128, (P, ps, H * D)).astype(np.int8)
             ks = rng.uniform(0.005, 0.03, (P, H)).astype(np.float32)
             vs = rng.uniform(0.005, 0.03, (P, H)).astype(np.float32)
             out = _pa.paged_attention(
@@ -82,8 +82,8 @@ def main() -> int:
                 q, kq, vq, tables, index, k_scale=ks, v_scale=vs,
             )
         else:
-            k = rng.standard_normal((P, ps, H, D)).astype(np.float32)
-            v = rng.standard_normal((P, ps, H, D)).astype(np.float32)
+            k = rng.standard_normal((P, ps, H * D)).astype(np.float32)
+            v = rng.standard_normal((P, ps, H * D)).astype(np.float32)
             out = _pa.paged_attention(q, k, v, tables, index,
                                       interpret=True)
             ref = _pa.paged_attention_reference(q, k, v, tables, index)
