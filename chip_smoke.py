@@ -19,7 +19,7 @@ weights made from a seed:
   over microbatches; the reference does neither).
 - **flash** — one forward of the same stack and weights with
   ``use_flash_attention=True`` against the default einsum attention.
-- **server** — ``ServingEngine(kv_layout="paged")`` on ``GptConfig()``
+- **server** — ``ServingEngine`` on ``GptConfig()``
   (GPT-2 small as published), ``attn_impl`` left to auto so the compiled
   Pallas kernel runs, requests of mixed length that join and leave
   mid-decode, once on fp pages and once on int8 pages.  Every token
@@ -545,7 +545,7 @@ def server_phase(size, gpt, prompts, specs, refs, argmax_gaps, kv_dtype,
     if on_tpu:
         check(engine.attn_impl == "pallas",
               f"auto attn_impl on a TPU chose {engine.attn_impl!r}")
-        # the decode step exactly as _decode_tick_paged shapes it
+        # the decode step exactly as _decode_tick shapes it
         stage, rows = engine.stages[0], engine.max_concurrency
         width = engine.max_pages_per_request
         i32 = lambda *shape: jax.ShapeDtypeStruct(shape, np.int32)

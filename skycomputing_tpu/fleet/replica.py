@@ -230,7 +230,7 @@ class EngineReplica:
     def _leak_now(self, count: int) -> int:
         leaked = 0
         for _ in range(count):
-            slot = self.engine._allocate_slot()
+            slot = self.engine.stages[0].pool.allocate()
             if slot is None:
                 break
             self.leaked_slots.append(slot)

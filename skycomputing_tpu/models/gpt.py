@@ -253,7 +253,7 @@ class GptBlock_Attn(nn.Module):
 
         - ``"xla"`` (reference): gather the virtual per-row views
           (materialized in HBM — cost scales with the TABLE width) and
-          run the masked float32 softmax, exactly the slot path's math;
+          run the masked float32 softmax, exactly :meth:`decode`'s math;
         - ``"pallas"``: the fused kernel (``ops/paged_attention.py``)
           walks the page table inside the kernel, streaming pages
           through online-softmax accumulation, so the virtual view
@@ -264,7 +264,7 @@ class GptBlock_Attn(nn.Module):
         Both impls share the one visibility definition — logical
         position v visible to query q iff ``v <= index + q`` — so a
         sentinel-clamped or stale page reads as masked garbage exactly
-        like the slot layout's freed-row tail.  Returns
+        like a freed row's tail in :meth:`decode`.  Returns
         (new_hidden, k_slab, v_slab).
         """
         from ..serving.kv_cache import (
@@ -668,8 +668,7 @@ def apply_kv_paged(
     Both prefill (``Lq = bucket``, ``index`` = per-row shared-prefix
     offsets) and decode (``Lq = 1``) are this one function at different
     input shapes, so the steady state compiles exactly one decode
-    program and one prefill program per bucket — the slot layout's
-    recompile discipline, kept.
+    program and one prefill program per bucket (and table width).
     """
     if len(params_list) != len(modules):
         raise ValueError(

@@ -30,7 +30,7 @@ moves INSIDE a Pallas kernel, so the gathered view never exists —
   query + 1) / page_size)`` pages.  A page past that is neither
   fetched nor stepped, so a row costs what it holds and not the
   table's width (bounding the TABLE still bounds the scalar prefetch
-  and the XLA reference, see ``ServingEngine`` ``gather_pages``);
+  and the XLA reference, see ``ServingEngine._table_width``);
 - heads by shape: heads are taken ``G`` at a time as the lane-aligned
   slice of the chunk they share (``G * D`` a multiple of 128, or the
   whole row), their queries stacked on the rows with the other heads'

@@ -4,16 +4,15 @@ The training side of this repo reproduces the paper's contribution —
 profile-driven layer->device allocation for heterogeneous pipelines;
 this package is the serving side the ROADMAP's north star demands:
 
-- :mod:`.kv_cache` — the KV-cache device math for both layouts: slot
-  slabs (fixed ``[slots, max_len, heads, head_dim]``, also backing
-  ``models/gpt.py``'s single-request decoder) and paged pools
-  (``[num_pages, page_size, heads * head_dim]`` gather/scatter through
-  page tables; :class:`QuantizedPages` stores them int8 with
-  per-page-per-head scale slabs, quantized at write time), donation-
-  friendly in-place updates throughout — the fused decode kernel that
-  walks page tables in-kernel lives in ``ops/paged_attention.py`` and
-  is engine-selected via ``attn_impl=``;
-- :mod:`.paging` — the paged host bookkeeping (pure stdlib):
+- :mod:`.kv_cache` — the KV-cache device math: the engine's page
+  pools (``[num_pages, page_size, heads * head_dim]`` gather/scatter
+  through page tables; :class:`QuantizedPages` stores them int8 with
+  per-page-per-head scale slabs, quantized at write time) and the
+  row-per-sequence slabs of ``models/gpt.py``'s single-request
+  reference decoder, donation-friendly in-place updates throughout —
+  the fused decode kernel that walks page tables in-kernel lives in
+  ``ops/paged_attention.py`` and is engine-selected via ``attn_impl=``;
+- :mod:`.paging` — the page pool's host bookkeeping (pure stdlib):
   free-list page allocator with refcounts and copy-on-write grants,
   radix prefix index for compute-once shared prompts, decode-row
   ledger, swap-vs-recompute preemption policy;
@@ -21,10 +20,10 @@ this package is the serving side the ROADMAP's north star demands:
   a small fixed bucket set so steady-state decode compiles once);
 - :mod:`.engine` — :class:`ServingEngine`, iteration-level continuous
   batching (Orca-style: requests join/leave the running batch between
-  decode steps) over pipeline stages placed by the allocator, with
-  :class:`ServingStats` SLO metrics; ``prefill_chunk=`` interleaves
-  budgeted prefill chunks with decode ticks, ``spec_k=`` layers
-  draft-model speculative decoding on the paged layout;
+  decode steps) from one page pool over pipeline stages placed by the
+  allocator, with :class:`ServingStats` SLO metrics; ``prefill_chunk=``
+  interleaves budgeted prefill chunks with decode ticks, ``spec_k=``
+  layers draft-model speculative decoding on top;
 - :mod:`.speculative` — :class:`DraftModel`, the prefix-slice draft
   (shares the target's stage-0 params and page slabs) plus the greedy
   acceptance rule;
@@ -49,7 +48,6 @@ from .engine import ServingEngine, ServingStats
 from .kv_cache import (
     KVCacheSpec,
     QuantizedPages,
-    SlotKVCachePool,
     gather_kv_pages,
     init_layer_caches,
     init_paged_caches,
@@ -90,7 +88,6 @@ __all__ = [
     "ServingEngine",
     "ServingStats",
     "ShapeBucketer",
-    "SlotKVCachePool",
     "choose_preempt_mode",
     "gather_kv_pages",
     "greedy_accept_count",

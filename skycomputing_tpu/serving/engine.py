@@ -8,33 +8,25 @@ implemented here:
 
 - **continuous batching** (Orca, OSDI '22): scheduling happens at
   *decode-iteration* granularity.  Every tick the engine (1) admits
-  queued requests into free KV slots via a bucketed prefill wave and
-  (2) runs ONE single-token decode step over the whole slot slab.
-  A finishing request frees its slot between ticks; a joining request
-  occupies one between ticks; the running batch never drains to
-  accommodate either — the static-batching failure mode where every
-  member waits for the slowest.
-- **fixed-shape KV caching** in two layouts.  ``kv_layout="slot"``
-  (the compatibility default): per-stage preallocated ``[slots,
-  max_len, heads, head_dim]`` slabs (``serving/kv_cache.py``) — one
-  whole row per request.  ``kv_layout="paged"`` (PagedAttention,
-  SOSP '23 + SGLang-style radix prefix caching): per-stage
-  ``[num_pages, page_size, heads * head_dim]`` page pools addressed
-  through per-request page tables (host bookkeeping — free-list
-  allocator, refcounts, copy-on-write prefix sharing, radix index,
-  swap-preemption — in ``serving/paging.py``), so admission charges a
-  request its TRUE footprint in pages and concurrency floats with
-  memory instead of a slot count (>2x sustained at equal pool MB,
-  gated in ``BENCH_serving.json``).  The paged decode path picks its
-  attention body per engine (``attn_impl``: the fused Pallas kernel on
-  TPU, the XLA gather+softmax reference elsewhere), bounds each step's
-  page-table width to the wave's live span (``gather_pages="live"`` —
-  the table-capacity-proportional gather was PR 9's raw speed floor),
-  and can store pages int8 with per-page-per-head scale slabs
+  queued requests onto free decode rows via a bucketed prefill wave and
+  (2) runs ONE single-token decode step over all rows.  A finishing
+  request frees its row between ticks; a joining request occupies one
+  between ticks; the running batch never drains to accommodate either.
+- **a paged KV cache** (PagedAttention, SOSP '23 + SGLang-style radix
+  prefix caching): per-stage ``[num_pages, page_size, heads *
+  head_dim]`` page pools addressed through per-request page tables
+  (host bookkeeping — free-list allocator, refcounts, copy-on-write
+  prefix sharing, radix index, swap-preemption — in
+  ``serving/paging.py``), so admission charges a request its TRUE
+  footprint in pages and concurrency floats with memory.  The decode
+  path picks its attention body per engine (``attn_impl``: the fused
+  Pallas kernel on TPU, the XLA gather+softmax reference elsewhere),
+  bounds each step's page-table width to the wave's live span, and can
+  store pages int8 with per-page-per-head scale slabs
   (``kv_dtype="int8"`` — ~4x pages per MB at fp32 model dtype, bounded
-  error; see docs/serving.md).  Either way every compiled program
-  keeps a fixed shape regardless of which requests are live: decode
-  compiles ONCE; prefill compiles once per prompt-length bucket
+  error; see docs/serving.md).  Every compiled program keeps a fixed
+  shape regardless of which requests are live: one step program per
+  (bucket, table width) pair, warmed like prefill buckets
   (``serving/batcher.py``); after warmup the steady state is
   zero-recompile, pinned by ``xla_compile_count()`` in
   ``tests/test_serving.py``.
@@ -46,7 +38,7 @@ each stage's params and slabs are committed to its device, and
 inter-stage hidden-state/index hops ride ``device_put_elided`` so
 same-device handoffs are free and cross-device ones batch into one put.
 
-Inactive slots ride through the decode step computing masked garbage —
+Inactive rows ride through the decode step computing masked garbage —
 that waste is the price of a fixed shape, and ``ServingStats.
 batch_occupancy`` makes it visible instead of hidden.
 """
@@ -67,7 +59,6 @@ from ..builder import build_layer_stack
 from ..models.gpt import (
     GptEmbeddings,
     _gcfg,
-    apply_kv_cached,
     apply_kv_paged,
     attn_indices,
     decode_modules,
@@ -96,7 +87,6 @@ from .batcher import (
 )
 from .kv_cache import (
     QuantizedPages,
-    SlotKVCachePool,
     init_paged_caches,
     kv_spec_from_config,
 )
@@ -123,8 +113,8 @@ _argmax_tokens = jax.jit(
     lambda logits: jnp.argmax(logits, axis=-1).astype(jnp.int32)
 )
 
-# Process-level stage-program cache: the jit'd decode/prefill closures,
-# keyed by the stage's layer-config signature (+ max_len + donation).
+# Process-level stage-program cache: the jit'd step closures, keyed by
+# the stage's layer-config signature (+ max_len + donation).
 # jax's compilation cache is keyed by FUNCTION IDENTITY, so two engines
 # built from identical configs would otherwise re-trace and re-compile
 # every program — which makes a fleet replica's re-form pay the full
@@ -148,10 +138,9 @@ class ServingStats:
     ``compiles`` counts XLA backend compiles observed during engine
     calls — after bucket warmup it must stop moving (the steady-state
     zero-recompile contract).  ``queue_stalls`` counts iterations where
-    admission wanted a slot and none was free (the pool-exhaustion
-    queueing path); ``preemptions`` counts slot evictions
-    (recomputation-style: the request re-queues and its KV prefix is
-    rebuilt on re-admission).
+    admission wanted a row or pages and none were free (the
+    pool-exhaustion queueing path); ``preemptions`` counts evictions
+    (the request re-queues and resumes by recomputation or swap-in).
     """
 
     iterations: int = 0
@@ -168,7 +157,7 @@ class ServingStats:
     # shedding is only acceptable when it is visible
     queue_rejections: int = 0
     compiles: int = 0
-    # paged-KV accounting (kv_layout="paged"; zero on slot engines):
+    # page-pool accounting:
     # prefix_hits/prefix_tokens_reused measure the radix cache
     # (prefill compute NOT spent), cow_copies the partial-page clones
     # that keep shared pages read-only, swap_outs/swap_ins the
@@ -339,108 +328,7 @@ class ServingStats:
         )
 
 
-class _ServingStage:
-    """One pipeline stage: module slice + device + slabs + programs."""
-
-    def __init__(
-        self,
-        stage_index: int,
-        modules: Sequence[Any],
-        params: Sequence[Any],
-        device,
-        num_slots: int,
-        max_len: int,
-        program_key: Optional[str] = None,
-    ):
-        self.stage_index = stage_index
-        self.modules = list(modules)
-        self.device = device
-        # trace-lane name, same convention as StageRuntime.lane_name so
-        # serving and training timelines read identically in Perfetto
-        self.lane_name = f"stage {stage_index} [{device}]"
-        self.params: List[Any] = jax.device_put(list(params), device)
-        specs = [
-            kv_spec_from_config(
-                _gcfg(self.modules[i].config).to_dict(), max_len
-            )
-            for i in attn_indices(self.modules)
-        ]
-        self.specs = specs
-        self.pool = SlotKVCachePool(specs, num_slots, device=device)
-        cached = (
-            _STAGE_PROGRAMS.get(program_key)
-            if program_key is not None else None
-        )
-        if cached is not None:
-            # same config signature -> the closures (and jax's traced/
-            # compiled cache behind their identity) are reusable as-is
-            self._decode_donated, self._prefill_donated = cached
-            return
-        mods, stage_specs = self.modules, specs
-
-        def decode(params_list, data, caches, index):
-            return apply_kv_cached(mods, params_list, data, caches, index)
-
-        def prefill(params_list, data, slabs, slot_ids):
-            # scratch caches sized to the bucket: the prefix 0..L-1 is
-            # exactly what must land in the slabs, so the filled scratch
-            # IS the scatter payload
-            rows, bucket = data.shape[0], data.shape[1]
-            scratch = [
-                (
-                    jnp.zeros(
-                        (rows, bucket, s.num_heads, s.head_dim),
-                        jnp.dtype(s.dtype),
-                    ),
-                    jnp.zeros(
-                        (rows, bucket, s.num_heads, s.head_dim),
-                        jnp.dtype(s.dtype),
-                    ),
-                )
-                for s in stage_specs
-            ]
-            out, scratch = apply_kv_cached(
-                mods, params_list, data, scratch, 0
-            )
-            # rows assigned the sentinel slot id (padding rows of a
-            # half-full wave) drop out of the scatter entirely
-            new_slabs = [
-                (
-                    k_slab.at[slot_ids, :bucket].set(ks, mode="drop"),
-                    v_slab.at[slot_ids, :bucket].set(vs, mode="drop"),
-                )
-                for (ks, vs), (k_slab, v_slab) in zip(scratch, slabs)
-            ]
-            return out, new_slabs
-
-        # donated twins (convention: *_donated handles are consumed on
-        # call — the engine rebinds pool.slabs to the outputs on the
-        # same line).  Donation follows the backend like the training
-        # engine: in-place slab reuse pays on TPU/GPU, is inert on CPU.
-        if _donation_enabled():
-            self._decode_donated = jax.jit(decode, donate_argnums=(2,))
-            self._prefill_donated = jax.jit(prefill, donate_argnums=(2,))
-        else:
-            self._decode_donated = jax.jit(decode)
-            self._prefill_donated = jax.jit(prefill)
-        if program_key is not None:
-            _STAGE_PROGRAMS[program_key] = (
-                self._decode_donated, self._prefill_donated
-            )
-
-    def build_pool(self, num_slots: int) -> SlotKVCachePool:
-        """A fresh (unassigned) slab pool for a new slot count.
-
-        Engine ``reconfigure`` pre-builds every stage's new pool BEFORE
-        evicting anything, so a slab-allocation failure (device OOM on
-        a larger slot count) surfaces while the engine is still fully
-        intact.  The decode/prefill programs re-trace once for the new
-        slab shape — a deliberate, visible warmup cost, the same one
-        engine construction pays."""
-        return SlotKVCachePool(self.specs, num_slots, device=self.device)
-
-
-# small paged-slab utilities, module-level jits so every engine shares
+# small page-slab utilities, module-level jits so every engine shares
 # the executables (shape-keyed: one compile per slab geometry).
 # _copy_page is undonated, so on accelerators each COW event pays a
 # slab-sized copy; COW fires at most once per prefix-hit admission, so
@@ -456,12 +344,11 @@ _scatter_rows = jax.jit(
 )
 
 
-class _PagedServingStage:
-    """One pipeline stage under the PAGED layout: module slice + device
-    + per-attention-layer page slabs ``[num_pages, page_size, heads *
-    head_dim]`` + the one fused step program (prefill and decode are
-    the same function at different input shapes — see
-    ``models/gpt.apply_kv_paged``)."""
+class _ServingStage:
+    """One pipeline stage: module slice + device + per-attention-layer
+    page slabs ``[num_pages, page_size, heads * head_dim]`` + the one
+    fused step program (prefill and decode are the same function at
+    different input shapes — see ``models/gpt.apply_kv_paged``)."""
 
     def __init__(
         self,
@@ -478,6 +365,8 @@ class _PagedServingStage:
         self.stage_index = stage_index
         self.modules = list(modules)
         self.device = device
+        # trace-lane name, same convention as StageRuntime.lane_name so
+        # serving and training timelines read identically in Perfetto
         self.lane_name = f"stage {stage_index} [{device}]"
         self.params: List[Any] = jax.device_put(list(params), device)
         self.num_pages = int(num_pages)
@@ -496,6 +385,8 @@ class _PagedServingStage:
             if program_key is not None else None
         )
         if cached is not None:
+            # same config signature -> the closure (and jax's traced/
+            # compiled cache behind its identity) is reusable as-is
             self._step_donated = cached
             return
         mods = self.modules
@@ -507,6 +398,10 @@ class _PagedServingStage:
                 valid_len, attn_impl=impl,
             )
 
+        # convention: the *_donated handle consumes its slabs on call —
+        # the engine rebinds st.slabs to the outputs on the same line.
+        # Donation follows the backend like the training engine:
+        # in-place slab reuse pays on TPU/GPU, is inert on CPU.
         if _donation_enabled():
             self._step_donated = jax.jit(step, donate_argnums=(2,))
         else:
@@ -706,9 +601,8 @@ class ServingEngine(LiveMetricsMixin):
         worker_manager=None,
         partition: Optional[Sequence[int]] = None,
         devices: Optional[Sequence[Any]] = None,
-        static_batching: bool = False,
         preflight: bool = True,
-        kv_layout: str = "slot",
+        kv_layout: str = "paged",
         page_size: int = 16,
         num_pages: Optional[int] = None,
         max_pages_per_request: Optional[int] = None,
@@ -722,20 +616,19 @@ class ServingEngine(LiveMetricsMixin):
         draft_blocks: Optional[int] = None,
         kv_dtype: Optional[str] = None,
         attn_impl: Optional[str] = None,
-        gather_pages: str = "live",
     ):
-        if kv_layout not in ("slot", "paged"):
+        if kv_layout != "paged":
             raise ValueError(
-                f"kv_layout must be 'slot' or 'paged', got {kv_layout!r}"
+                f"kv_layout must be 'paged', got {kv_layout!r}: the "
+                f"slot layout was removed, every engine serves from "
+                f"the page pool"
             )
         if preempt_policy not in ("auto", "recompute", "swap"):
             raise ValueError(
                 f"preempt_policy must be 'auto', 'recompute' or 'swap', "
                 f"got {preempt_policy!r}"
             )
-        self.kv_layout = kv_layout
-        self._paged = kv_layout == "paged"
-        # --- the paged kernel/quantization operating point ------------
+        # --- the kernel/quantization operating point ------------------
         # kv_dtype: None keeps the model dtype; "int8" stores pages
         # quantized (per-page-per-head scale slabs, quantize-on-write)
         # — construction state like draft_blocks, NOT a reconfigure
@@ -744,12 +637,7 @@ class ServingEngine(LiveMetricsMixin):
         # TPU backend, the XLA reference elsewhere (interpret-mode
         # Pallas is available everywhere but is a correctness surface,
         # ~orders slower than XLA on CPU; pass "pallas" explicitly to
-        # use it off-TPU).  gather_pages: "live" bounds every step's
-        # page-table width to the wave's live span (ceil to page, then
-        # to the next power-of-two page count with the largest bucket
-        # as floor — a log-sized compile-shape set, each warmed like a
-        # prefill bucket); "full" keeps PR 9's full-table-width gather,
-        # the honest A/B baseline the bench measures against.
+        # use it off-TPU).
         if kv_dtype not in (None, "int8"):
             raise ValueError(
                 f"kv_dtype must be None (model dtype) or 'int8', "
@@ -760,28 +648,10 @@ class ServingEngine(LiveMetricsMixin):
                 f"attn_impl must be None (auto), 'xla' or 'pallas', "
                 f"got {attn_impl!r}"
             )
-        if gather_pages not in ("live", "full"):
-            raise ValueError(
-                f"gather_pages must be 'live' or 'full', "
-                f"got {gather_pages!r}"
-            )
-        if not self._paged and (kv_dtype is not None
-                                or attn_impl is not None
-                                or gather_pages != "live"):
-            raise ValueError(
-                "kv_dtype/attn_impl/gather_pages require "
-                "kv_layout='paged' (the kernel, the quantized pool, "
-                "and the bounded table gather are page-table "
-                "machinery)"
-            )
         self.kv_dtype = kv_dtype
-        self.gather_pages = gather_pages
-        if self._paged:
-            self.attn_impl = attn_impl or (
-                "pallas" if jax.default_backend() == "tpu" else "xla"
-            )
-        else:
-            self.attn_impl = None
+        self.attn_impl = attn_impl or (
+            "pallas" if jax.default_backend() == "tpu" else "xla"
+        )
         modules = decode_modules(build_layer_stack(list(model_cfg)))
         if not attn_indices(modules) or not isinstance(
             modules[0], GptEmbeddings
@@ -790,54 +660,48 @@ class ServingEngine(LiveMetricsMixin):
                 "expected a GPT stack: GptEmbeddings + GptBlock_Attn units"
             )
         max_pos = _gcfg(modules[0].config).max_position_embeddings
-        if self._paged:
-            # the paged operating point: max_len becomes the PER-REQUEST
-            # virtual span (max_pages_per_request x page_size), and the
-            # pool depth decouples from it entirely — num_pages defaults
-            # to the slot layout's byte-equal footprint
-            # (num_slots x pages_for(max_len)), the equal-memory pivot
-            self.page_size = int(page_size)
-            if self.page_size < 1:
-                raise ValueError(f"page_size must be >= 1, got {page_size}")
-            if max_pages_per_request is not None:
-                self.max_pages_per_request = int(max_pages_per_request)
-            else:
-                # derived default: cover max_len, but never let the
-                # page-rounded span outgrow the model's position table
-                # — a (max_len, page_size) pair that works under the
-                # slot layout must not be rejected by its own rounding
-                derived = pages_for(max_len, self.page_size)
-                if derived * self.page_size > max_pos:
-                    derived = max_pos // self.page_size
-                if derived < 1:
-                    raise ValueError(
-                        f"page_size={self.page_size} exceeds "
-                        f"max_position_embeddings={max_pos}"
-                    )
-                self.max_pages_per_request = derived
-            max_len = self.max_pages_per_request * self.page_size
-            self.num_pages = (
-                int(num_pages) if num_pages is not None
-                else int(num_slots) * pages_for(max_len, self.page_size)
-            )
-            self.max_concurrency = (
-                int(max_concurrency) if max_concurrency is not None
-                else min(self.num_pages, int(num_slots) * 4)
-            )
-            if self.max_concurrency < 1:
-                raise ValueError(
-                    f"max_concurrency must be >= 1, "
-                    f"got {self.max_concurrency}"
-                )
-            # decode rows are the concurrency lanes: num_slots becomes
-            # the row count so the fleet's slot-accounting, router load
-            # estimates, and chaos slot leaks stay meaningful unchanged
-            num_slots = self.max_concurrency
+        # the operating point: max_len becomes the PER-REQUEST virtual
+        # span (max_pages_per_request x page_size), and the pool depth
+        # decouples from it entirely — num_pages defaults to num_slots
+        # whole spans (num_slots x pages_for(max_len)), so num_slots
+        # sizes the pool when num_pages is not given
+        self.page_size = int(page_size)
+        if self.page_size < 1:
+            raise ValueError(f"page_size must be >= 1, got {page_size}")
+        if max_pages_per_request is not None:
+            self.max_pages_per_request = int(max_pages_per_request)
         else:
-            self.page_size = None
-            self.num_pages = None
-            self.max_pages_per_request = None
-            self.max_concurrency = None
+            # derived default: cover max_len, but never let the
+            # page-rounded span outgrow the model's position table — a
+            # max_len the model accepts must not be rejected by its own
+            # rounding to pages
+            derived = pages_for(max_len, self.page_size)
+            if derived * self.page_size > max_pos:
+                derived = max_pos // self.page_size
+            if derived < 1:
+                raise ValueError(
+                    f"page_size={self.page_size} exceeds "
+                    f"max_position_embeddings={max_pos}"
+                )
+            self.max_pages_per_request = derived
+        max_len = self.max_pages_per_request * self.page_size
+        self.num_pages = (
+            int(num_pages) if num_pages is not None
+            else int(num_slots) * pages_for(max_len, self.page_size)
+        )
+        self.max_concurrency = (
+            int(max_concurrency) if max_concurrency is not None
+            else min(self.num_pages, int(num_slots) * 4)
+        )
+        if self.max_concurrency < 1:
+            raise ValueError(
+                f"max_concurrency must be >= 1, "
+                f"got {self.max_concurrency}"
+            )
+        # decode rows are the concurrency lanes: num_slots becomes the
+        # row count, which is what the fleet's slot-accounting, router
+        # load estimates and chaos slot leaks read
+        num_slots = self.max_concurrency
         if max_len > max_pos:
             raise ValueError(
                 f"max_len={max_len} exceeds "
@@ -867,22 +731,17 @@ class ServingEngine(LiveMetricsMixin):
             max_queue=self.max_queue,
         )
         self.prefill_batch = int(prefill_batch)
-        # --- chunked prefill (paged-only): pure scheduling — split the
+        # --- chunked prefill: pure scheduling — split the
         # non-shared prefill tail into prefill_chunk-token chunks that
         # ride ticks alongside the decode slab
         self.prefill_chunk: Optional[int] = None
         self.max_chunk_rows: Optional[int] = None
         self._chunk_policy: Optional[ChunkBudgetPolicy] = None
         if prefill_chunk:
-            if not self._paged:
-                raise ValueError(
-                    "prefill_chunk requires kv_layout='paged' (partial "
-                    "prefill state lives in page tables)"
-                )
             self._set_chunking(int(prefill_chunk), max_chunk_rows)
         elif max_chunk_rows is not None:
             raise ValueError("max_chunk_rows requires prefill_chunk")
-        # --- speculative decoding (paged-only): a prefix-slice draft
+        # --- speculative decoding: a prefix-slice draft
         # proposes spec_k tokens per tick, the target verifies all
         # spec_k+1 positions in one batched forward
         self.spec_k = int(spec_k)
@@ -891,24 +750,12 @@ class ServingEngine(LiveMetricsMixin):
         self.draft_blocks = (
             int(draft_blocks) if draft_blocks is not None else None
         )
-        if self.spec_k > 0:
-            if not self._paged:
-                raise ValueError(
-                    "spec_k requires kv_layout='paged' (the draft "
-                    "shares the target's stage-0 page slabs)"
-                )
-            if self.draft_blocks is None:
-                raise ValueError(
-                    "spec_k > 0 requires draft_blocks (the prefix-"
-                    "slice depth of the draft model)"
-                )
+        if self.spec_k > 0 and self.draft_blocks is None:
+            raise ValueError(
+                "spec_k > 0 requires draft_blocks (the prefix-"
+                "slice depth of the draft model)"
+            )
         self._draft: Optional[DraftModel] = None
-        # static_batching is the NAIVE baseline policy, kept on the same
-        # kernels so tools/bench_serving.py isolates the scheduling
-        # policy: requests join only at batch boundaries (when the
-        # running batch has fully drained), so every member waits for
-        # the slowest — the failure mode continuous batching removes
-        self.static_batching = bool(static_batching)
         self.stats = ServingStats()
         # same snapshot() contract as the training runner's registry, so
         # one poller reads either subsystem identically
@@ -977,26 +824,20 @@ class ServingEngine(LiveMetricsMixin):
                 f"got {len(params_list)} param trees for "
                 f"{len(modules)} layers"
             )
-        # paged host state: ONE page pool governs the page-id space
-        # across all stages (page p = row p of every stage's slabs, the
-        # paged twin of cross-stage slot ids); rows are the decode
-        # concurrency lanes, shared as every stage's `.pool` facade so
-        # fleet slot accounting / chaos leaks work unchanged
-        if self._paged:
-            self._pool = PagedKVCachePool(
-                self.num_pages, self.page_size,
-                self.max_pages_per_request,
-                enable_prefix_cache=self.enable_prefix_cache,
-                max_prefix_entries=self._max_prefix_entries,
-                kv_dtype=self._pool_kv_dtype(),
-            )
-            self._rows = RowAllocator(self.max_concurrency)
-            # request_id -> host page copies + resume state (swap pool)
-            self._swapped: Dict[int, Dict[str, Any]] = {}
-        else:
-            self._pool = None
-            self._rows = None
-            self._swapped = {}
+        # host state: ONE page pool governs the page-id space across
+        # all stages (page p = row p of every stage's slabs); rows are
+        # the decode concurrency lanes, shared as every stage's `.pool`
+        # facade, which fleet slot accounting and chaos leaks read
+        self._pool = PagedKVCachePool(
+            self.num_pages, self.page_size,
+            self.max_pages_per_request,
+            enable_prefix_cache=self.enable_prefix_cache,
+            max_prefix_entries=self._max_prefix_entries,
+            kv_dtype=self._pool_kv_dtype(),
+        )
+        self._rows = RowAllocator(self.max_concurrency)
+        # request_id -> host page copies + resume state (swap pool)
+        self._swapped: Dict[int, Dict[str, Any]] = {}
         # banked totals of pools replaced by reconfigure (counter
         # monotonicity across geometry changes)
         self._pool_base = dict(
@@ -1009,37 +850,26 @@ class ServingEngine(LiveMetricsMixin):
             # everything the traced programs depend on: the exact layer
             # configs of this stage's slice, the layout, the cache
             # depth, and the donation mode (the input SHAPES — bucket,
-            # slot/row count, page geometry — are jit cache keys
-            # already, not closure identity)
+            # row count, page geometry — are jit cache keys already,
+            # not closure identity)
             program_key = json.dumps(
-                [self._model_cfg[cursor:cursor + n], self.kv_layout,
+                [self._model_cfg[cursor:cursor + n], kv_layout,
                  self.max_len, bool(_donation_enabled()),
                  self.kv_dtype, self.attn_impl],
                 sort_keys=True, default=str,
             )
-            if self._paged:
-                stage = _PagedServingStage(
-                    k,
-                    modules[cursor:cursor + n],
-                    list(params_list)[cursor:cursor + n],
-                    dev,
-                    self.num_pages,
-                    self.page_size,
-                    program_key=program_key,
-                    kv_dtype=self.kv_dtype,
-                    attn_impl=self.attn_impl,
-                )
-                stage.pool = self._rows  # shared row ledger facade
-            else:
-                stage = _ServingStage(
-                    k,
-                    modules[cursor:cursor + n],
-                    list(params_list)[cursor:cursor + n],
-                    dev,
-                    self.num_slots,
-                    self.max_len,
-                    program_key=program_key,
-                )
+            stage = _ServingStage(
+                k,
+                modules[cursor:cursor + n],
+                list(params_list)[cursor:cursor + n],
+                dev,
+                self.num_pages,
+                self.page_size,
+                program_key=program_key,
+                kv_dtype=self.kv_dtype,
+                attn_impl=self.attn_impl,
+            )
+            stage.pool = self._rows  # shared row ledger facade
             self.stages.append(stage)
             cursor += n
         self._last_device = self.stages[-1].device
@@ -1059,26 +889,21 @@ class ServingEngine(LiveMetricsMixin):
 
     def _serving_context(self) -> Dict[str, Any]:
         """The operating point the pre-flight verifier charges."""
-        if self._paged:
-            ctx = dict(
-                num_pages=self.num_pages, page_size=self.page_size,
-                max_pages_per_request=self.max_pages_per_request,
-                bucket=self.bucketer.max_bucket,
-            )
-            if self.kv_dtype is not None:
-                # the quantized byte width (+ scale slabs) is what the
-                # slabs will actually allocate — the verifier must
-                # charge the same formula or the two could disagree
-                ctx["kv_dtype"] = self.kv_dtype
-            if self._draft_mb:
-                # the speculative draft's head copy is real stage-0
-                # residency — the verifier must see it
-                ctx["draft_mb"] = self._draft_mb
-            return ctx
-        return dict(
-            slots=self.num_slots, max_len=self.max_len,
+        ctx = dict(
+            num_pages=self.num_pages, page_size=self.page_size,
+            max_pages_per_request=self.max_pages_per_request,
             bucket=self.bucketer.max_bucket,
         )
+        if self.kv_dtype is not None:
+            # the quantized byte width (+ scale slabs) is what the
+            # slabs will actually allocate — the verifier must
+            # charge the same formula or the two could disagree
+            ctx["kv_dtype"] = self.kv_dtype
+        if self._draft_mb:
+            # the speculative draft's head copy is real stage-0
+            # residency — the verifier must see it
+            ctx["draft_mb"] = self._draft_mb
+        return ctx
 
     def _set_chunking(self, prefill_chunk: int,
                       max_chunk_rows: Optional[int]) -> None:
@@ -1181,28 +1006,10 @@ class ServingEngine(LiveMetricsMixin):
             )
         return counts, stage_devices
 
-    # --- slot ledger (slot ids are global across stages) -------------------
+    # --- row ledger (one, shared: every stage's .pool IS self._rows) -------
     @property
     def free_slots(self) -> int:
         return self.stages[0].pool.free_slots
-
-    def _allocate_slot(self) -> Optional[int]:
-        if self._paged:
-            # one shared row ledger (every stage's .pool IS self._rows)
-            return self._rows.allocate()
-        slot = self.stages[0].pool.allocate()
-        if slot is None:
-            return None
-        for st in self.stages[1:]:
-            st.pool.acquire(slot)
-        return slot
-
-    def _release_slot(self, slot: int) -> None:
-        if self._paged:
-            self._rows.release(slot)
-            return
-        for st in self.stages:
-            st.pool.release(slot)
 
     # --- request-scoped tracing ---------------------------------------------
     # One stable id (request_id) threads the whole waterfall: every
@@ -1299,7 +1106,7 @@ class ServingEngine(LiveMetricsMixin):
 
     # --- request lifecycle --------------------------------------------------
     def submit(self, request: Request, *, force: bool = False) -> Request:
-        """Queue a request (admitted into a slot on a later ``step``).
+        """Queue a request (admitted onto a row on a later ``step``).
 
         With ``max_queue`` set, a full queue applies ``queue_policy``:
         ``"reject"`` refuses the newcomer (:class:`QueueFullError`
@@ -1386,9 +1193,8 @@ class ServingEngine(LiveMetricsMixin):
         """Evict a running request; it re-queues and resumes with its
         token stream intact.
 
-        Slot layout: always recomputation-style (the KV prefix is
-        rebuilt on re-admission).  Paged layout: ``mode`` (or the
-        engine's ``preempt_policy``) picks between **recompute** and
+        ``mode`` (or the engine's ``preempt_policy``) picks between
+        **recompute** (the KV prefix is rebuilt on re-admission) and
         **swap** — page contents copied to a host pool and paged back
         in on re-admission, no prefill replay.  ``"auto"`` chooses by
         resume cost (``paging.choose_preempt_mode``): recompute replays
@@ -1420,23 +1226,12 @@ class ServingEngine(LiveMetricsMixin):
                 "a mid-prefill request preempts by recomputation only"
             )
         resume_len = int(request.effective_prompt.size)
-        if not self._paged:
-            if mode not in (None, "recompute"):
-                raise ValueError(
-                    f"slot engines only preempt by recomputation, "
-                    f"got mode={mode!r}"
-                )
-            # validate the resume prefix fits a bucket BEFORE touching
-            # any state: a request grown past the largest bucket cannot
-            # resume by recomputation, and a failed preempt must leave
-            # it running
-            self.bucketer.bucket_for(resume_len)
-            mode = "recompute"
-        elif prefilling:
+        if prefilling:
             # validate the resume prefix still fits a bucket (the
-            # re-queue requires one), then recompute — no tokens were
-            # generated yet, so the replay is the same admission the
-            # request already passed
+            # re-queue requires one) BEFORE touching any state (a
+            # failed preempt must leave the request where it was), then
+            # recompute — no tokens were generated yet, so the replay
+            # is the same admission the request already passed
             self.bucketer.bucket_for(resume_len)
             mode = "recompute"
         else:
@@ -1453,7 +1248,9 @@ class ServingEngine(LiveMetricsMixin):
                     self.page_size, recompute_feasible=fits,
                 )
             if mode == "recompute" and not fits:
-                # surface the same diagnostic the slot path raises
+                # a request grown past the largest bucket cannot resume
+                # by recomputation: raise the bucketer's own diagnostic
+                # before any state is touched
                 self.bucketer.bucket_for(resume_len)
         swap_record = None
         if mode == "swap":
@@ -1480,9 +1277,8 @@ class ServingEngine(LiveMetricsMixin):
             request.prefilled_len = 0  # recompute replays the tail
         else:
             self._running.pop(request_id)
-        self._release_slot(request.slot)
-        if self._paged:
-            self._pool.release(request_id)
+        self._rows.release(request.slot)
+        self._pool.release(request_id)
         request.slot = None
         request.preemptions += 1
         self.stats.preemptions += 1
@@ -1527,11 +1323,10 @@ class ServingEngine(LiveMetricsMixin):
         returned — the caller decides whether to keep stepping this
         engine until it finishes or declare it failed.
 
-        Paged engines drain recomputation-style too: swap records are
-        host-local (another engine has no access to this one's host
-        pool), so migration resumes by re-prefilling the effective
-        prompt — and any swap records held for queued requests are
-        dropped with the same consequence."""
+        Swap records are host-local (another engine has no access to
+        this one's host pool), so migration resumes by re-prefilling
+        the effective prompt — and any swap records held for queued
+        requests are dropped with the same consequence."""
         for request_id in list(self._running) + list(self._prefilling):
             try:
                 # cross-engine resume is recompute by construction
@@ -1539,9 +1334,8 @@ class ServingEngine(LiveMetricsMixin):
             except ValueError:
                 continue  # documented: not resumable, stays running
         drained = self._queue.drain()
-        if self._paged:
-            for r in drained:
-                self._swapped.pop(r.request_id, None)
+        for r in drained:
+            self._swapped.pop(r.request_id, None)
         tracer = get_tracer()
         if tracer is not None:
             # each drained request's queue_wait segment ends HERE (on
@@ -1566,10 +1360,6 @@ class ServingEngine(LiveMetricsMixin):
         Returns the corrupted record's request id, or None when no
         record exists and none can be forced — the injector logs that
         honestly instead of inventing a fault that never happened."""
-        if not self._paged:
-            raise ValueError(
-                "swap records exist on paged engines only"
-            )
         if request_id is not None:
             if request_id not in self._swapped:
                 raise KeyError(
@@ -1626,11 +1416,6 @@ class ServingEngine(LiveMetricsMixin):
         front door) owns delivering the pair and conserving it in a
         ledger — after this returns, this engine holds NO state for the
         request."""
-        if not self._paged:
-            raise ValueError(
-                "handoff export needs a paged engine (swap records are "
-                "the carrier)"
-            )
         request = self._running.get(request_id)
         if request is None:
             raise KeyError(
@@ -1668,7 +1453,7 @@ class ServingEngine(LiveMetricsMixin):
         verified FIRST, before the record touches any engine state.
 
         True: the record passed its integrity gate and is parked; the
-        admission loop's existing swap-in path (``_admit_paged`` →
+        admission loop's existing swap-in path (``_admit`` →
         ``_swap_in``) restores the pages with NO prefill and decoding
         continues at the record's index — the resume path IS the
         swap-in path, no new compile shapes.  False: the checksum did
@@ -1679,11 +1464,6 @@ class ServingEngine(LiveMetricsMixin):
         record whose resume prefix fits no bucket is FAILED with a
         reasoned verdict, mirroring ``_swap_in``'s corruption verdict.
         """
-        if not self._paged:
-            raise ValueError(
-                "handoff import needs a paged engine (swap records are "
-                "the carrier)"
-            )
         rid = request.request_id
         if (rid in self._running or rid in self._prefilling
                 or rid in self._swapped
@@ -1759,11 +1539,10 @@ class ServingEngine(LiveMetricsMixin):
         return list(self._queue.requests)
 
     def _finish(self, request: Request, now: float) -> None:
-        self._release_slot(request.slot)
-        if self._paged:
-            # pages the radix index still references survive the
-            # release — the prefix cache's retention, not a leak
-            self._pool.release(request.request_id)
+        self._rows.release(request.slot)
+        # pages the radix index still references survive the release —
+        # the prefix cache's retention, not a leak
+        self._pool.release(request.request_id)
         request.slot = None
         request.status = FINISHED
         request.finished_s = now
@@ -1801,7 +1580,7 @@ class ServingEngine(LiveMetricsMixin):
         """One engine iteration: admit prefill waves (or, with
         ``prefill_chunk`` set, enroll admissions and advance at most a
         budgeted number of prefill chunks), then one decode tick over
-        the slot slab.  Requests join and leave the running batch only
+        every row.  Requests join and leave the running batch only
         here, between decode steps — iteration-level scheduling; the
         chunk budget bounds how much prefill any single decode tick
         can wait behind."""
@@ -1817,25 +1596,18 @@ class ServingEngine(LiveMetricsMixin):
                             "queue_stall", eng,
                             {"queued": self._queue.depth},
                         )
-                if self._paged:
-                    self._admit_paged()
-                else:
-                    self._admit()
-            if self._paged:
-                if self._chunk_policy is not None:
-                    self._chunk_tick()
-                if self.spec_k > 0 and self._draft is not None:
-                    self._spec_tick()
-                else:
-                    self._decode_tick_paged()
+                self._admit()
+            if self._chunk_policy is not None:
+                self._chunk_tick()
+            if self.spec_k > 0 and self._draft is not None:
+                self._spec_tick()
             else:
                 self._decode_tick()
             with sp.span("sky.serve.sync", eng):
                 self.stats.iterations += 1
                 self.stats.queue_depth = self._queue.depth
                 self.stats.batch_occupancy = self.stages[0].pool.occupancy
-                if self._paged:
-                    self._sync_paged_stats()
+                self._sync_paged_stats()
                 if self.timeseries is not None:
                     self.timeseries.sample()
                 if self.autotuner is not None:
@@ -1875,207 +1647,23 @@ class ServingEngine(LiveMetricsMixin):
     ) -> None:
         """Apply a new serving operating point IN PLACE, between steps.
 
-        The act half of the serving tuning loop: bucket set, slot count,
-        and prefill wave width are all shape knobs, so changing them
-        means new compiled programs — but not a new engine.  The queue
-        re-buckets under the new set; ONLY a slot-count change (which
-        rebuilds the per-stage slabs) additionally evicts the running
-        batch recomputation-style (the :meth:`preempt` machinery: token
-        streams preserved exactly, KV prefixes rebuilt on re-admission)
-        — bucket/wave-width changes leave running requests decoding
-        untouched.
-
-        Verify-then-apply: the knob set passes the pre-flight verifier
-        (``analysis/plan_check.verify_tuning_knobs``), a slot-count
-        change re-runs the constructor's serving memory pre-flight
-        (budget-charged slabs, when the engine was built from a worker
-        manager) AND pre-builds the new slabs, and every live request
-        is proven to fit the new bucket set — all BEFORE any state is
-        touched, so a rejected reconfigure (:class:`PlanError` /
-        ``ValueError`` / a slab-allocation failure) leaves the engine
-        exactly as it was.
-
-        Paged engines (``kv_layout="paged"``) additionally learn
-        ``num_pages``/``page_size``/``max_pages_per_request``/
-        ``max_concurrency`` (``num_slots`` aliases ``max_concurrency``,
-        so the autotuner's slot proposals keep working unchanged):
-        bucket and wave-width changes stay eviction-free, a
-        concurrency change re-seats the running batch
-        recomputation-style on the SAME page pool (swap records stay
-        valid), and a page-geometry change rebuilds pool + slabs —
-        running requests resume by recomputation, the prefix cache
-        restarts cold (its counters banked, never reset), and host
-        swap records (whose page shapes died with the geometry)
+        The act half of the serving tuning loop: bucket set, row count,
+        page geometry and prefill wave width are all shape knobs, so
+        changing them means new compiled programs — but not a new
+        engine.  The queue re-buckets under the new set; bucket and
+        wave-width changes leave running requests decoding untouched.
+        A concurrency change (``max_concurrency``; ``num_slots``
+        aliases it, which is the name the autotuner proposes) re-seats
+        the running batch recomputation-style on the SAME page pool
+        (the :meth:`preempt` machinery: token streams preserved
+        exactly, KV prefixes rebuilt on re-admission; swap records
+        stay valid).  A page-geometry change (``num_pages`` /
+        ``page_size`` / ``max_pages_per_request``) rebuilds pool +
+        slabs — running requests resume by recomputation, the prefix
+        cache restarts cold (its counters banked, never reset), and
+        host swap records (whose page shapes died with the geometry)
         convert to recomputation resumes only after every affected
-        request is proven to fit a prefill bucket.  Paged engines also
-        learn the scheduler knobs ``prefill_chunk``/``max_chunk_rows``
-        (chunked prefill) and ``spec_k`` (speculative decoding) — see
-        :meth:`_reconfigure_paged` for their enable/disable semantics.
-        """
-        from ..analysis.plan_check import verify_tuning_knobs
-
-        if self._paged:
-            self._reconfigure_paged(
-                buckets=buckets, num_slots=num_slots,
-                prefill_batch=prefill_batch, num_pages=num_pages,
-                page_size=page_size,
-                max_pages_per_request=max_pages_per_request,
-                max_concurrency=max_concurrency,
-                prefill_chunk=prefill_chunk,
-                max_chunk_rows=max_chunk_rows, spec_k=spec_k,
-            )
-            return
-        if any(k is not None for k in
-               (num_pages, page_size, max_pages_per_request,
-                max_concurrency, prefill_chunk, max_chunk_rows,
-                spec_k)):
-            raise ValueError(
-                "page knobs (num_pages/page_size/max_pages_per_request/"
-                "max_concurrency/prefill_chunk/max_chunk_rows/spec_k) "
-                "require kv_layout='paged'"
-            )
-        if buckets is not None:
-            # same normalization the constructor's ShapeBucketer applies,
-            # so reconfigure accepts exactly the inputs construction
-            # does; a malformed entry is left raw for the knob verifier
-            # to reject with a diagnostic (never a bare TypeError here)
-            try:
-                new_buckets = tuple(sorted(set(int(b) for b in buckets)))
-            except (TypeError, ValueError):
-                new_buckets = tuple(buckets)
-        else:
-            new_buckets = self.bucketer.buckets
-        new_slots = (
-            int(num_slots) if num_slots is not None else self.num_slots
-        )
-        new_batch = (
-            int(prefill_batch)
-            if prefill_batch is not None else self.prefill_batch
-        )
-        verify_tuning_knobs(
-            buckets=new_buckets, max_len=self.max_len,
-            num_slots=new_slots, prefill_batch=new_batch,
-        ).raise_if_failed()
-        if (self._preflight and self._worker_manager is not None
-                and (new_slots != self.num_slots
-                     or max(new_buckets) > self.bucketer.max_bucket)):
-            # same pre-flight the constructor ran, against the PROPOSED
-            # operating point: a slab or prefill activation that no
-            # longer fits the budgets (more slots, OR a raised max
-            # bucket) must be rejected abstractly, not discovered as an
-            # allocation OOM mid-serving.  A slot change is charged at
-            # old+new slots: the atomic apply below holds BOTH pools
-            # resident for a moment, and that transient peak — not the
-            # steady state — is what the apply must actually fit.
-            from ..analysis.plan_check import verify_plan
-
-            charged_slots = new_slots + (
-                self.num_slots if new_slots != self.num_slots else 0
-            )
-            verify_plan(
-                self._model_cfg, self._worker_manager,
-                (np.zeros((new_slots, 1), np.int32),),
-                memory="error", check_donation=False,
-                serving=dict(slots=charged_slots, max_len=self.max_len,
-                             bucket=max(new_buckets)),
-            ).raise_if_failed()
-        new_bucketer = ShapeBucketer(new_buckets)
-        # only a slot-count change rebuilds the slabs and therefore
-        # forces eviction; bucket/prefill_batch changes keep the running
-        # batch decoding untouched (running requests never consult the
-        # bucketer mid-decode) and only re-bucket the queue
-        must_evict = new_slots != self.num_slots
-        # feasibility covers the RUNNING batch even when it stays
-        # resident: a running request that no longer fits any bucket
-        # could never be preempted or rolled back again — a latent trap
-        # the engine must refuse to set
-        live = list(self._running.values()) + list(self._queue.requests)
-        for r in live:
-            # a request grown past the largest NEW bucket cannot resume
-            # by recomputation; reject before any eviction
-            try:
-                new_bucketer.bucket_for(int(r.effective_prompt.size))
-            except ValueError as exc:
-                raise ValueError(
-                    f"reconfigure rejected: request {r.request_id} "
-                    f"cannot resume under buckets {list(new_buckets)}: "
-                    f"{exc}"
-                ) from None
-        # pre-build every stage's new slabs BEFORE touching any request
-        # state: an allocation failure here leaves the engine exactly as
-        # it was (the atomicity the docstring promises); old slabs free
-        # as soon as the swap below drops them
-        new_pools = (
-            [st.build_pool(new_slots) for st in self.stages]
-            if must_evict else None
-        )
-
-        tracer = get_tracer()
-        old = dict(buckets=list(self.bucketer.buckets),
-                   slots=self.num_slots, prefill_batch=self.prefill_batch)
-        evicted: List[Request] = []
-        if must_evict:
-            for r in list(self._running.values()):
-                self._running.pop(r.request_id)
-                self._release_slot(r.slot)
-                r.slot = None
-                r.preemptions += 1
-                self.stats.preemptions += 1
-                evicted.append(r)
-                if tracer is not None:
-                    # same instant preempt() emits, so trace-derived
-                    # preemption counts agree with ServingStats
-                    tracer.instant(
-                        "preempt", tracer.lane("serving", "engine"),
-                        {"request": r.request_id, "reconfigure": True},
-                    )
-                    self._trace_close_decode(r, tracer,
-                                             reconfigure=True)
-        queued = self._queue.drain()
-        if tracer is not None:
-            for r in queued:
-                self._trace_close_queue(r, tracer, rebucketed=True)
-        if new_pools is not None:
-            self.num_slots = new_slots
-            for st, pool in zip(self.stages, new_pools):
-                st.pool = pool
-        self.bucketer = new_bucketer
-        self.prefill_batch = new_batch
-        self._queue = AdmissionQueue(new_bucketer, prefill_batch=new_batch,
-                                     max_queue=self.max_queue)
-        # evicted requests were admitted before anything still queued:
-        # they re-enter at the head so reconfiguration cannot starve
-        # them; force — every one of these was already admitted, and a
-        # reconfigure must never shed what it only meant to re-bucket
-        for r in evicted + queued:
-            self._queue.submit(r, force=True)
-            self._trace_queued(r, tracer)
-        self.stats.queue_depth = self._queue.depth
-        if tracer is not None:
-            tracer.instant(
-                "reconfigure", tracer.lane("serving", "engine"),
-                dict(old=old, new=dict(buckets=list(new_buckets),
-                                       slots=new_slots,
-                                       prefill_batch=new_batch),
-                     evicted=len(evicted)),
-            )
-
-    def _reconfigure_paged(
-        self,
-        *,
-        buckets=None,
-        num_slots=None,
-        prefill_batch=None,
-        num_pages=None,
-        page_size=None,
-        max_pages_per_request=None,
-        max_concurrency=None,
-        prefill_chunk=None,
-        max_chunk_rows=None,
-        spec_k=None,
-    ) -> None:
-        """The paged half of :meth:`reconfigure` (same verify-then-
-        apply contract; see its docstring for the knob semantics).
+        request is proven to fit a prefill bucket.
 
         ``prefill_chunk`` and ``spec_k`` are the chunked-prefill and
         speculative-decoding knobs: ``None`` keeps the current setting,
@@ -2088,10 +1676,26 @@ class ServingEngine(LiveMetricsMixin):
         warmup, the same one construction pays per bucket).  Enabling
         speculation requires the engine to have been built with
         ``draft_blocks`` (the draft's layer slice is construction
-        state)."""
+        state).
+
+        Verify-then-apply: the knob set passes the pre-flight verifier
+        (``analysis/plan_check.verify_tuning_knobs``), a geometry
+        change, a raised max bucket or a newly resident draft re-runs
+        the constructor's serving memory pre-flight (budget-charged
+        slabs, when the engine was built from a worker manager), a
+        geometry change pre-builds the new slabs, and every live
+        request is proven to fit the new operating point — all BEFORE
+        any state is touched, so a rejected reconfigure
+        (:class:`PlanError` / ``ValueError`` / a slab-allocation
+        failure) leaves the engine exactly as it was.
+        """
         from ..analysis.plan_check import verify_tuning_knobs
 
         if buckets is not None:
+            # same normalization the constructor's ShapeBucketer applies,
+            # so reconfigure accepts exactly the inputs construction
+            # does; a malformed entry is left raw for the knob verifier
+            # to reject with a diagnostic (never a bare TypeError here)
             try:
                 new_buckets = tuple(sorted(set(int(b) for b in buckets)))
             except (TypeError, ValueError):
@@ -2101,7 +1705,7 @@ class ServingEngine(LiveMetricsMixin):
         if max_concurrency is not None and num_slots is not None and (
                 int(max_concurrency) != int(num_slots)):
             raise ValueError(
-                "num_slots aliases max_concurrency on a paged engine; "
+                "num_slots aliases max_concurrency; "
                 f"got conflicting {num_slots} and {max_concurrency}"
             )
         new_rows = int(
@@ -2185,8 +1789,8 @@ class ServingEngine(LiveMetricsMixin):
             # ANY geometry change pre-builds a full second slab set
             # while the old one is still resident, so the transient
             # peak is old+new pool depth even when the new pool is
-            # SMALLER — charge exactly what the apply holds (the slot
-            # path's transient-peak rule, at page granularity)
+            # SMALLER — charge exactly what the apply holds: that
+            # transient peak, not the steady state, is what must fit
             from ..analysis.plan_check import verify_plan
 
             charged = new_pages + (
@@ -2279,7 +1883,7 @@ class ServingEngine(LiveMetricsMixin):
                 r.prefilled_len = 0  # recompute replays the tail
             else:
                 self._running.pop(r.request_id)
-            self._release_slot(r.slot)
+            self._rows.release(r.slot)
             self._pool.release(r.request_id)
             r.slot = None
             r.preemptions += 1
@@ -2402,197 +2006,30 @@ class ServingEngine(LiveMetricsMixin):
 
     # --- live observability (LiveMetricsMixin provides the wiring) ----------
     def _health_snapshot(self) -> Dict[str, Any]:
-        snap = dict(
+        return dict(
             status="ok",
             queue_depth=self._queue.depth,
             running=len(self._running),
             free_slots=self.free_slots,
             iterations=self.stats.iterations,
+            kv_layout="paged",
+            free_pages=self._pool.free_pages,
+            pages_in_use=self._pool.pages_in_use,
+            swapped=len(self._swapped),
+            prefilling=len(self._prefilling),
+            # the active kernel/quantization operating point, so a
+            # scrape can tell WHICH decode path a replica runs
+            kv_dtype=self._pool.kv_dtype,
+            attn_impl=self.attn_impl,
         )
-        if self._paged:
-            snap.update(
-                kv_layout="paged",
-                free_pages=self._pool.free_pages,
-                pages_in_use=self._pool.pages_in_use,
-                swapped=len(self._swapped),
-                prefilling=len(self._prefilling),
-                # the active kernel/quantization operating point, so a
-                # scrape can tell WHICH decode path a replica runs
-                kv_dtype=self._pool.kv_dtype,
-                attn_impl=self.attn_impl,
-            )
-        return snap
 
     # --- internals ----------------------------------------------------------
     def _admit(self) -> None:
-        if self.static_batching and self._running:
-            return  # batch boundary only: the naive baseline policy
-        while True:
-            wave = self._queue.next_wave(self.free_slots)
-            if not wave:
-                break
-            self._prefill_wave(wave)
-
-    def _prefill_wave(self, wave: List[Request]) -> None:
-        bucket = wave[0].bucket
-        rows = self.prefill_batch
-        sp, eng = self._sp, self._eng_lane
-        tracer = sp.tracer
-        # tokens (true, un-padded) ride along so trace analysis can
-        # compute per-bucket padding waste — the skewed-bucket
-        # signature the autotuner acts on; the member request ids (ring
-        # only) make the wave attributable from the engine lane too
-        wave_tokens = int(sum(int(r.effective_prompt.size) for r in wave))
-        wave_args = {"bucket": bucket, "wave": len(wave),
-                     "tokens": wave_tokens}
-        with sp.span("sky.serve.prefill", eng, wave_args):
-            with sp.span("sky.serve.build", eng):
-                ids, lengths = self.bucketer.pad_batch(
-                    [r.effective_prompt for r in wave], bucket, rows,
-                    self.pad_id
-                )
-                # sentinel = num_slots: padding rows scatter out of
-                # range -> drop
-                slot_ids = np.full((rows,), self.num_slots, np.int32)
-                for i, r in enumerate(wave):
-                    slot = self._allocate_slot()
-                    assert slot is not None  # next_wave capped by free_slots
-                    r.slot = slot
-                    slot_ids[i] = slot
-
-            t0 = time.perf_counter()
-            compiles0 = xla_compile_count()
-            run_args = wave_args if tracer is None else dict(
-                wave_args, requests=[r.request_id for r in wave])
-            with sp.span("sky.serve.run", eng, run_args,
-                         ring="prefill") as run:
-                data: Any = ids
-                for k, st in enumerate(self.stages):
-                    with sp.span("sky.serve.put", eng):
-                        data = device_put_elided(data, st.device)
-                        sids = device_put_elided(slot_ids, st.device)
-                    with sp.span("sky.serve.stage",
-                                 sp.lane(st.lane_name, "dispatch"),
-                                 {"stage": k, "bucket": bucket},
-                                 ring="prefill"):
-                        data, st.pool.slabs = st._prefill_donated(
-                            st.params, data, st.pool.slabs, sids
-                        )
-                pos = device_put_elided(lengths - 1, self._last_device)
-                logits = _gather_last(data, pos)  # [rows, V]
-                tokens = _argmax_tokens(logits)
-                with sp.span("sky.serve.wait", eng):
-                    jax.block_until_ready(tokens)
-            now = time.perf_counter()
-            self.stats.prefill_s += now - t0
-            with sp.span("sky.serve.commit", eng):
-                if tracer is not None:
-                    for r in wave:
-                        tracer.instant(
-                            "admit", eng,
-                            {"request": r.request_id, "slot": r.slot},
-                        )
-                        # request-lane waterfall: the queue_wait segment
-                        # ends where the wave's run began, the prefill
-                        # segment spans it, and the decode segment opens
-                        # at its end
-                        self._trace_close_queue(r, tracer,
-                                                end_us=run.start_us)
-                        lane = tracer.request_lane(r.request_id,
-                                                   lease=False)
-                        if lane is not None:
-                            tracer.complete(
-                                "prefill", lane, run.start_us,
-                                {"request": r.request_id,
-                                 "replica": self.trace_name,
-                                 "bucket": bucket, "slot": r.slot},
-                                dur_us=run.end_us - run.start_us,
-                            )
-                        r.trace_marks["decode"] = run.end_us
-                self.stats.prefill_waves += 1
-                self.stats.prefill_tokens += wave_tokens
-                # per-call delta, not a process-global diff: foreign jit
-                # work in the same process must not read as engine
-                # recompiles
-                self.stats.compiles += xla_compile_count() - compiles0
-
-                tokens_np = np.asarray(tokens)
-                sampled = self._sampled_rows(
-                    logits, [(i, r) for i, r in enumerate(wave)]
-                )
-                for i, r in enumerate(wave):
-                    tok = self._pick_token(r, tokens_np[i], sampled.get(i))
-                    r.tokens.append(tok)
-                    r.index = int(lengths[i])
-                    r.status = RUNNING
-                    self._running[r.request_id] = r
-                    if r.first_token_s is None:
-                        r.first_token_s = now
-                    self.stats.generated_tokens += 1
-                    if r.done:
-                        self._finish(r, now)
-
-    def _decode_tick(self) -> None:
-        active = list(self._running.values())
-        if not active:
-            return
-        sp, eng = self._sp, self._eng_lane
-        tick_args = {"active": len(active)}
-        with sp.span("sky.serve.decode", eng, tick_args):
-            with sp.span("sky.serve.build", eng):
-                tokens = np.zeros((self.num_slots,), np.int32)
-                index = np.zeros((self.num_slots,), np.int32)
-                for r in active:
-                    tokens[r.slot] = r.tokens[-1]
-                    index[r.slot] = r.index
-
-            t0 = time.perf_counter()
-            compiles0 = xla_compile_count()
-            with sp.span("sky.serve.run", eng, tick_args, ring="decode"):
-                data: Any = tokens[:, None]  # [slots, 1]
-                for k, st in enumerate(self.stages):
-                    with sp.span("sky.serve.put", eng):
-                        data = device_put_elided(data, st.device)
-                        idx = device_put_elided(index, st.device)
-                    with sp.span("sky.serve.stage",
-                                 sp.lane(st.lane_name, "dispatch"),
-                                 {"stage": k}, ring="decode"):
-                        data, st.pool.slabs = st._decode_donated(
-                            st.params, data, st.pool.slabs, idx
-                        )
-                logits = data[:, 0]  # [slots, V]
-                nxt = _argmax_tokens(logits)
-                with sp.span("sky.serve.wait", eng):
-                    jax.block_until_ready(nxt)
-            now = time.perf_counter()
-            self.stats.decode_s += now - t0
-            with sp.span("sky.serve.commit", eng):
-                self.stats.decode_tokens += len(active)
-                self.stats.generated_tokens += len(active)
-                self.stats.compiles += xla_compile_count() - compiles0
-
-                nxt_np = np.asarray(nxt)
-                sampled = self._sampled_rows(
-                    logits, [(r.slot, r) for r in active]
-                )
-                for r in active:
-                    tok = self._pick_token(r, nxt_np[r.slot],
-                                           sampled.get(r.slot))
-                    r.tokens.append(tok)
-                    r.index += 1
-                    if r.done:
-                        self._finish(r, now)
-
-    # --- the paged scheduling loop ------------------------------------------
-    def _admit_paged(self) -> None:
         """Admit from the queue while rows AND pages allow — admission
         charges PAGES (the request's reserved footprint), so
-        concurrency floats with actual memory use instead of a slot
-        count.  FIFO: the head either admits (prefill wave or swap-in)
-        or stalls the queue — a later small request never jumps a
-        starved head."""
-        if self.static_batching and self._running:
-            return  # batch boundary only: the naive baseline policy
+        concurrency floats with actual memory use.  FIFO: the head
+        either admits (prefill wave or swap-in) or stalls the queue — a
+        later small request never jumps a starved head."""
         sp, eng = self._sp, self._eng_lane
         while True:
             queued = self._queue.requests
@@ -2622,11 +2059,11 @@ class ServingEngine(LiveMetricsMixin):
                     return
                 continue
             with sp.span("sky.serve.select_wave", eng):
-                wave = self._select_paged_wave()
+                wave = self._select_wave()
             if wave is None:
                 self._stall_on_pages()
                 return
-            self._prefill_wave_paged(wave)
+            self._prefill_wave(wave)
 
     def _enroll_chunked(self, request: Request) -> bool:
         """Admit the queue head under chunked prefill: charge its page
@@ -2779,7 +2216,7 @@ class ServingEngine(LiveMetricsMixin):
                 wave_args, requests=[r.request_id for r in wave])
             with sp.span("sky.serve.run", eng, run_args,
                          ring="prefill") as run:
-                data = self._run_paged_stages(
+                data = self._run_stages(
                     ids, tables, index, valid, "prefill",
                     {"bucket": bucket, "chunk": True},
                 )
@@ -2859,16 +2296,15 @@ class ServingEngine(LiveMetricsMixin):
                  "free_pages": self._pool.free_pages},
             )
 
-    def _select_paged_wave(self) -> Optional[List[Any]]:
-        """Dequeue the next prefill wave under the paged layout, or
-        None when the head cannot be charged.
+    def _select_wave(self) -> Optional[List[Any]]:
+        """Dequeue the next prefill wave, or None when the head cannot
+        be charged.
 
         The head's TAIL bucket (prompt minus its radix-shared prefix)
         fixes the wave's compile shape; later queued requests whose
         tails land in the same bucket pack in, each charged its own
         page grant.  Buckets are pure compile-shape classes here —
-        admission capacity is pages + rows, never 'a slot of the
-        head's size' (the decoupling the slot layout could not offer).
+        admission capacity is pages + rows.
         """
         queued = self._queue.requests
         head = queued[0]
@@ -2913,12 +2349,12 @@ class ServingEngine(LiveMetricsMixin):
             self._queue.remove(r)
         return wave
 
-    def _prefill_wave_paged(self, wave: List[Any]) -> None:
+    def _prefill_wave(self, wave: List[Any]) -> None:
         """Prefill a wave of (request, grant) pairs: COW-clone partial
         shared pages, compute ONLY the non-shared tails, scatter their
         K/V through the page tables, and seat each request on a decode
         row.  A full-prefix hit costs one bucket of tail compute — the
-        TTFT-drops-with-prefix-length effect the bench gates."""
+        TTFT-drops-with-prefix-length effect."""
         rows = self.prefill_batch
         sp, eng = self._sp, self._eng_lane
         tracer = sp.tracer
@@ -2970,7 +2406,7 @@ class ServingEngine(LiveMetricsMixin):
                 wave_args, requests=[r.request_id for r, _ in wave])
             with sp.span("sky.serve.run", eng, run_args,
                          ring="prefill") as run:
-                data = self._run_paged_stages(
+                data = self._run_stages(
                     ids, tables, index, valid, "prefill",
                     {"bucket": bucket},
                 )
@@ -3032,18 +2468,13 @@ class ServingEngine(LiveMetricsMixin):
                         self._finish(r, now)
 
     def _table_width(self, valid) -> int:
-        """Page-table columns this step actually needs (the PR 12
-        honest-gather fix): the wave's max live length, ceiled to a
-        page, then to the next power-of-two page count with the largest
-        bucket's span as floor — so the XLA reference gathers O(live
-        tokens), not O(max_pages) (the kernel walks each row's own live
-        pages whatever the width; ``attn_pages_live``), while
-        the distinct compile-shape set stays logarithmic and warmable
-        exactly like prefill buckets.  ``gather_pages="full"`` keeps
-        the PR 9 behavior: the full table width every step (the
-        materializing baseline the bench A/Bs against)."""
-        if self.gather_pages == "full":
-            return self.max_pages_per_request
+        """Page-table columns this step actually needs: the wave's max
+        live length, ceiled to a page, then to the next power-of-two
+        page count with the largest bucket's span as floor — so the XLA
+        reference gathers O(live tokens), not O(max_pages) (the kernel
+        walks each row's own live pages whatever the width;
+        ``attn_pages_live``), while the distinct compile-shape set
+        stays logarithmic and warmable exactly like prefill buckets."""
         need = max(1, pages_for(int(np.max(valid)), self.page_size))
         floor = pages_for(self.bucketer.max_bucket, self.page_size)
         width = max(need, floor)
@@ -3073,7 +2504,7 @@ class ServingEngine(LiveMetricsMixin):
         self.stats.dequant_blocks += int(rows) * int(width)
 
     def _count_attn_pages(self, query_ends, width: int) -> Dict[str, int]:
-        """Bank one paged decode forward's page walk and return it as
+        """Bank one decode forward's page walk and return it as
         span arguments.  ``query_ends``: ``index + Lq`` of each active
         row; the program's other rows sit at index 0 and cost the one
         page the kernel always reads."""
@@ -3088,9 +2519,9 @@ class ServingEngine(LiveMetricsMixin):
         self.stats.attn_pages_table += counts["attn_pages_table"]
         return counts
 
-    def _run_paged_stages(self, data, tables, index, valid, ring,
-                          span_args=None):
-        """Thread one paged step through every stage — the ONE
+    def _run_stages(self, data, tables, index, valid, ring,
+                    span_args=None):
+        """Thread one step through every stage — the ONE
         dispatch idiom shared by tail-prefill waves, chunk waves,
         decode ticks, and the speculative verify forward: per-stage
         device puts, the donated step program with its same-statement
@@ -3187,7 +2618,7 @@ class ServingEngine(LiveMetricsMixin):
             request.trace_marks["decode"] = now_us
         return True
 
-    def _decode_tick_paged(self) -> None:
+    def _decode_tick(self) -> None:
         active = list(self._running.values())
         if not active:
             return
@@ -3219,7 +2650,7 @@ class ServingEngine(LiveMetricsMixin):
             t0 = time.perf_counter()
             compiles0 = xla_compile_count()
             with sp.span("sky.serve.run", eng, tick_args, ring="decode"):
-                data = self._run_paged_stages(
+                data = self._run_stages(
                     tokens[:, None], tables, index, valid, "decode"
                 )
                 logits = data[:, 0]  # [rows, V]
@@ -3272,7 +2703,7 @@ class ServingEngine(LiveMetricsMixin):
         if not active:
             return
         if all(r.temperature > 0.0 for r in active):
-            self._decode_tick_paged()
+            self._decode_tick()
             return
         k = self.spec_k
         sp, eng = self._sp, self._eng_lane
@@ -3345,7 +2776,7 @@ class ServingEngine(LiveMetricsMixin):
             # --- verify: one Lq=k+1 forward over the whole pipeline
             with sp.span("sky.serve.run", eng, tick_args, ring="decode"):
                 verify_in = np.concatenate([tokens[:, None], drafted], axis=1)
-                logits3 = self._run_paged_stages(
+                logits3 = self._run_stages(
                     verify_in, tables, index0, valid, "decode"
                 )  # [rows, k+1, V]
                 target = _argmax_tokens(logits3)  # [rows, k+1]

@@ -1,29 +1,30 @@
-"""Slot-based KV-cache slabs: the single KV-cache implementation.
+"""KV-cache device math: the one place attention keys/values are
+written and read back.
 
-Every decoding path in the repo — ``models/gpt.py``'s
-``CachedGptDecoder``/``generate_cached`` and the continuous-batching
-``ServingEngine`` — stores attention keys/values in fixed-shape slabs
-``[slots, max_len, heads, head_dim]`` updated in place and reads them
-through the helpers here.  One implementation means one set of
-invariants:
+Two fixed-shape stores share this module.  ``models/gpt.py``'s
+``CachedGptDecoder``/``generate_cached`` — the single-request
+reference the serving tests compare token streams with — keeps
+row-per-sequence slabs ``[batch, max_len, heads, head_dim]``
+(:func:`init_layer_caches`, :func:`update_kv_cache`).  The
+continuous-batching ``ServingEngine`` keeps page pools ``[num_pages,
+page_size, heads * head_dim]`` addressed through per-request page
+tables (:func:`init_paged_caches`, :func:`paged_update_kv`,
+:func:`gather_kv_pages`; the host bookkeeping is ``serving/paging.py``).
+Both hold the same invariants:
 
-- **fixed shapes**: slabs are preallocated once; a request joining or
+- **fixed shapes**: a store is preallocated once; a request joining or
   leaving the batch never changes a compiled program's signature (the
   SKY002 recompile discipline applied to serving);
 - **in-place, donation-friendly updates**: :func:`update_kv_cache` is a
   ``dynamic_update_slice`` (scalar index) or a vmapped per-row one
-  (per-slot index vector), so a caller that donates the slab argument
-  and rebinds to the output lets XLA reuse the buffer instead of
-  copying ``slots x max_len`` every token;
+  (per-row index vector) and :func:`paged_update_kv` a scatter through
+  a bitcast view, so a caller that donates the store and rebinds to
+  the output lets XLA reuse the buffer instead of copying it every
+  token;
 - **masked staleness**: positions at or beyond a row's current index
   hold stale garbage by design; :func:`decode_visibility` masks them
-  out of attention, so a freed slot can be handed to a new request
-  without any zeroing pass.
-
-The pool (:class:`SlotKVCachePool`) adds the host-side free-slot
-allocator per pipeline stage: slots are tickets, requests borrow one
-for their lifetime, and exhaustion is a queueing condition for the
-admission layer — never an error.
+  out of attention, so a freed row or page can be handed to a new
+  request without any zeroing pass.
 
 No model imports here: ``models/gpt.py`` depends on this module (its
 ``decode`` methods call the update/visibility helpers), not the other
@@ -50,12 +51,11 @@ def update_kv_cache(k_cache, v_cache, k_new, v_new, index):
     ``k_cache``/``v_cache``: [B, max_len, heads, head_dim] slabs;
     ``k_new``/``v_new``: [B, Lq, heads, head_dim]; ``index``: either a
     scalar (all rows write at the same offset — the single-request
-    decode path) or a [B] vector (each row writes at its own offset —
-    the continuous-batching path, where every slot sits at a different
-    sequence position).  Returns the updated ``(k_cache, v_cache)``.
-    Out-of-range indices clamp (``dynamic_update_slice`` semantics), so
-    an inactive slot carried through a full-slab decode step can never
-    write outside its own row.
+    decode path) or a [B] vector (each row writes at its own offset,
+    every row at a different sequence position).  Returns the updated
+    ``(k_cache, v_cache)``.  Out-of-range indices clamp
+    (``dynamic_update_slice`` semantics), so an inactive row carried
+    through a full-slab decode step can never write outside itself.
     """
     k_new = k_new.astype(k_cache.dtype)
     v_new = v_new.astype(v_cache.dtype)
@@ -303,8 +303,8 @@ def gather_kv_pages(k_slab, v_slab, page_table, num_heads: int):
     table entries clamp into the slab and read garbage — those virtual
     positions are at or beyond the row's current length by the pool's
     covering invariant, so
-    :func:`decode_visibility` masks them exactly like the slot layout
-    masks a freed row's stale tail.
+    :func:`decode_visibility` masks them exactly as it masks a freed
+    row's stale tail.
 
     :class:`QuantizedPages` slabs dequantize during the gather (int8
     value x its page's per-head scale), returning float32 views — the
@@ -381,8 +381,7 @@ def init_layer_caches(
     specs: Sequence[KVCacheSpec], slots: int, device=None
 ) -> List[Tuple[jax.Array, jax.Array]]:
     """Zeroed (k, v) slab pairs, one per attention layer, optionally
-    committed to ``device``.  This is the one allocation site both the
-    single-request decoder and the serving pool build on."""
+    committed to ``device``: the single-request decoder's store."""
     caches = []
     for spec in specs:
         shape = spec.slab_shape(slots)
@@ -392,75 +391,6 @@ def init_layer_caches(
             pair = jax.device_put(pair, device)
         caches.append(pair)
     return caches
-
-
-class SlotKVCachePool:
-    """Preallocated per-stage slabs + a host-side free-slot allocator.
-
-    One pool per pipeline stage: the slabs live on the stage's device
-    (allocated once, updated in place), while slot bookkeeping is pure
-    host state.  A slot id is valid across every layer of the stage —
-    request r owns row ``slot`` of all ``len(specs)`` slab pairs.
-
-    Exhaustion contract: :meth:`allocate` returns ``None`` when no slot
-    is free — the admission layer queues the request; nothing raises.
-    """
-
-    def __init__(
-        self, specs: Sequence[KVCacheSpec], slots: int, device=None
-    ):
-        if slots < 1:
-            raise ValueError(f"need at least 1 slot, got {slots}")
-        self.specs = list(specs)
-        self.num_slots = int(slots)
-        self.device = device
-        self.slabs = init_layer_caches(self.specs, self.num_slots, device)
-        # LIFO free list: reusing the hottest row keeps its pages warm
-        self._free: List[int] = list(range(self.num_slots))[::-1]
-
-    @property
-    def free_slots(self) -> int:
-        return len(self._free)
-
-    @property
-    def used_slots(self) -> int:
-        return self.num_slots - len(self._free)
-
-    @property
-    def occupancy(self) -> float:
-        return self.used_slots / self.num_slots
-
-    def allocate(self) -> Optional[int]:
-        """One free slot id, or None when the pool is exhausted."""
-        if not self._free:
-            return None
-        return self._free.pop()
-
-    def acquire(self, slot: int) -> None:
-        """Claim a SPECIFIC free slot — the multi-stage engine allocates
-        a slot id once and acquires the same row in every other stage's
-        pool, so one id addresses a request's cache across the whole
-        pipeline."""
-        if slot not in self._free:
-            raise ValueError(f"slot {slot} is not free")
-        self._free.remove(slot)
-
-    def release(self, slot: int) -> None:
-        if not 0 <= slot < self.num_slots:
-            raise ValueError(
-                f"slot {slot} out of range [0, {self.num_slots})"
-            )
-        if slot in self._free:
-            raise ValueError(f"slot {slot} double-released")
-        # no zeroing: stale rows are masked by decode_visibility and
-        # fully overwritten (prefix [:bucket]) on the next prefill
-        self._free.append(slot)
-
-    def total_mb(self) -> float:
-        """Preallocated slab memory of this pool in MB (all layers)."""
-        return float(
-            sum(spec.slab_mb(self.num_slots) for spec in self.specs)
-        )
 
 
 def init_paged_caches(
@@ -475,9 +405,7 @@ def init_paged_caches(
     kernel copies pages in and the scatter writes rows in, so no
     program ever relays a slab out (on a TPU ``[.., heads, head_dim]``
     and ``[.., heads * head_dim]`` tile the same bytes differently, and
-    a reshape between them copies the whole slab).  Same total bytes as
-    a slot slab whenever ``num_pages * page_size == slots * max_len`` —
-    the equal-memory pivot the paged-vs-slot bench holds fixed.
+    a reshape between them copies the whole slab).
 
     ``kv_dtype="int8"`` allocates :class:`QuantizedPages` pairs instead:
     int8 value slabs plus float32 ``[num_pages, heads]`` scale slabs
@@ -520,8 +448,8 @@ def paged_kv_mb_per_layer(
     """Per-layer paged-pool MB for a layer-config list — the paged twin
     of :func:`kv_mb_per_layer`.  ``kv_dtype=None`` keeps the model
     dtype through the permissive ``jnp.dtype`` itemsize (byte-identical
-    to the slot formula at equal positions — any jnp-valid model dtype
-    stays accountable, exactly as before quantization existed); an
+    to :func:`kv_mb_per_layer` at equal positions — any jnp-valid model
+    dtype stays accountable); an
     EXPLICIT ``kv_dtype`` charges through
     ``serving/paging.paged_pool_mb`` — the ONE quantized-width formula
     the allocator, the profiler, and the pre-flight verifier all share
@@ -572,7 +500,6 @@ def kv_mb_per_layer(
 __all__ = [
     "KVCacheSpec",
     "QuantizedPages",
-    "SlotKVCachePool",
     "decode_positions",
     "decode_visibility",
     "gather_kv_pages",

@@ -5,11 +5,11 @@ host-side decision logic over ints and tuples — no jax, no numpy — so
 ``tools/paging_smoke.py`` can load this file by path on a bare CI
 runner and exercise every allocator/refcount/radix decision without an
 accelerator stack installed.  The device half (slab gather/scatter
-math) lives in ``serving/kv_cache.py`` next to the slot-slab helpers.
+math) lives in ``serving/kv_cache.py``.
 
-Why pages.  The slot layout strands memory: one request = one fixed
-``[max_len]`` cache row, so a 14-token prompt in a 192-position row
-wastes ~93% of it and concurrency is hard-capped at the slot count.
+Why pages.  A cache of whole rows strands memory: one request = one
+fixed ``[max_len]`` cache row, so a 14-token prompt in a 192-position
+row wastes ~93% of it and concurrency is hard-capped at the row count.
 PagedAttention (Kwon et al., SOSP '23) recovers that memory by slicing
 the slab into fixed ``page_size``-position **pages** handed out from a
 free list; a request holds ``ceil(len / page_size)`` pages instead of a
@@ -38,9 +38,9 @@ The invariants, in one place:
   (``ceil((len + max_new) / page_size)`` minus the fully-shared pages)
   up front, evicting least-recently-used cache entries when the free
   list runs short.  A request that cannot be charged queues (``None``),
-  never corrupts — the slot pool's exhaustion-is-queueing contract at
-  page granularity, and full reservation means a running request can
-  never die of page exhaustion mid-decode.
+  never corrupts — exhaustion is a queueing condition — and full
+  reservation means a running request can never die of page
+  exhaustion mid-decode.
 """
 
 from __future__ import annotations
@@ -297,8 +297,7 @@ class PagedKVCachePool:
 
     Host bookkeeping only — one instance per engine governs the page id
     space across every pipeline stage (page id p addresses row p of all
-    stages' slabs, the paged twin of the slot pool's cross-stage slot
-    ids).  Exhaustion contract: :meth:`acquire` returns ``None`` when
+    stages' slabs).  Exhaustion contract: :meth:`acquire` returns ``None`` when
     the request cannot be charged even after evicting reusable cache
     entries — a queueing condition for the admission layer, never an
     error, and never a partial mutation.
@@ -336,7 +335,7 @@ class PagedKVCachePool:
         # pool_mb's byte table is strict, and only when asked).
         self.kv_dtype = str(kv_dtype)
         self.enable_prefix_cache = bool(enable_prefix_cache)
-        # LIFO free list, same warm-row rationale as the slot pool
+        # LIFO free list: reusing the hottest page keeps it warm
         self._free: List[int] = list(range(self.num_pages))[::-1]
         self._refs: Dict[int, int] = {}
         self._tables: Dict[int, List[int]] = {}  # request_id -> pages
@@ -655,10 +654,10 @@ class RowAllocator:
     The paged decode program is still a fixed shape — ``[rows, 1]``
     tokens against ``[rows, max_pages]`` page tables — so a running
     request occupies a *row*, which is pure bookkeeping (its KV lives
-    in pages).  Mirrors the slot pool's host interface
-    (``allocate``/``acquire``/``release``/``free_slots``/...) so fleet
-    replicas' slot-accounting and chaos fault surface work unchanged on
-    paged engines; ``total_mb`` is 0 — rows own no device memory.
+    in pages).  Its host interface (``allocate``/``acquire``/
+    ``release``/``free_slots``/...) is what fleet replicas'
+    slot-accounting and the chaos fault surface read; ``total_mb`` is
+    0 — rows own no device memory.
     """
 
     def __init__(self, rows: int):

@@ -165,21 +165,13 @@ class ServingAutotuner:
             return
         if self.tunes >= self.max_tunes:
             return
-        blocked = set(self.blocked)
-        if not getattr(engine, "_paged", False):
-            # prefill_chunk is a paged-only knob: a slot engine would
-            # reject the proposal and burn the signature forever —
-            # mask it instead of spending a blocked slot on it
-            from .advisor import DECODE_TAIL
-
-            blocked.add(DECODE_TAIL)
         proposal = self.advisor.propose_serving(
             report,
             buckets=engine.bucketer.buckets,
             num_slots=engine.num_slots,
             max_len=engine.max_len,
-            prefill_chunk=getattr(engine, "prefill_chunk", None),
-            blocked=blocked,
+            prefill_chunk=engine.prefill_chunk,
+            blocked=self.blocked,
         )
         if proposal is None:
             self._record(NO_OP)
@@ -240,11 +232,9 @@ class ServingAutotuner:
         engine = self.engine
         revert = dict(buckets=list(engine.bucketer.buckets),
                       num_slots=engine.num_slots,
-                      prefill_batch=engine.prefill_batch)
-        if getattr(engine, "_paged", False):
-            # 0 = "chunking off" in reconfigure's knob language; slot
-            # engines never see the key (they would reject it)
-            revert["prefill_chunk"] = engine.prefill_chunk or 0
+                      prefill_batch=engine.prefill_batch,
+                      # 0 = "chunking off" in reconfigure's knob language
+                      prefill_chunk=engine.prefill_chunk or 0)
         self._arc_id += 1
         tracer.async_begin("autotune", self._lane(tracer), self._arc_id,
                            proposal.describe())

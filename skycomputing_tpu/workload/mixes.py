@@ -3,8 +3,8 @@
 The stdlib scenario core (:mod:`.scenario`) owns NEW workloads; this
 module owns the two workloads the repo had ALREADY committed bench
 artifacts against before the workload plane existed —
-``bench_serving``'s prefill-vs-decode interference mix and
-``bench_fleet``'s bursty steady-state arrivals.  Those artifacts gate
+a prefill-vs-decode interference mix and ``bench_fleet``'s bursty
+steady-state arrivals.  Those artifacts gate
 on numbers measured under specific ``numpy.random.Generator`` draw
 sequences, so porting them onto ``random.Random`` would silently
 change every committed workload.  Instead the EXACT legacy draw
@@ -54,8 +54,8 @@ def build_mix(name: str, rng: np.random.Generator, **cfg) -> Any:
 def interference_specs(
     rng: np.random.Generator, icfg: Dict[str, Any]
 ) -> List[Tuple[np.ndarray, int]]:
-    """The prefill-vs-decode interference mix (ROADMAP item 3's
-    workload, formerly ``bench_serving.build_interference_workload``):
+    """The prefill-vs-decode interference mix (the workload chunked
+    prefill was built against):
     long-prompt/short-decode CHURNERS whose admission waves are
     expensive, interleaved with short-prompt/short-decode requests
     whose inter-token latency measures the damage.  Shuffled so

@@ -83,9 +83,13 @@ def mixed_requests(rng, specs):
 
 def fast_supervisor(**kw):
     """Supervisor tuned for seconds-scale tests: detect every tick, one
-    missed beat is death."""
+    missed beat is death.  The latency probe is OFF unless a test asks
+    for it: it compares wall-clock tick times, and on a loaded machine
+    one tick is easily three times another, so a test that injects no
+    latency fault would see a spurious SICK verdict and re-form."""
     defaults = dict(check_every=1, heartbeat_misses=1, grace_ticks=2,
-                    baseline_ticks=3, k_checks=2, sick_threshold=3.0)
+                    baseline_ticks=3, k_checks=2,
+                    sick_threshold=float("inf"))
     defaults.update(kw)
     return FleetSupervisor(**defaults)
 
@@ -455,7 +459,7 @@ def test_fleet_sick_replica_drains_to_survivors(gpt, devices):
     fleet = ServingFleet(
         layer_cfgs, params, replicas=2,
         engine_kwargs=dict(num_slots=2, max_len=64, buckets=(8, 16)),
-        supervisor=fast_supervisor(),
+        supervisor=fast_supervisor(sick_threshold=3.0),
         fault_injector=FleetFaultInjector(plan),
         devices=devices,
     )
@@ -482,7 +486,8 @@ def test_fleet_slot_leak_detected_and_reformed(gpt, devices):
     )
     fleet = ServingFleet(
         layer_cfgs, params, replicas=2,
-        engine_kwargs=dict(num_slots=2, max_len=64, buckets=(8,)),
+        engine_kwargs=dict(num_slots=2, max_len=64, buckets=(8,),
+                           max_concurrency=2),
         supervisor=fast_supervisor(),
         fault_injector=FleetFaultInjector(plan),
         devices=devices,
@@ -687,10 +692,12 @@ def test_fleet_observability_e2e_demo(gpt, devices):
     )
     fleet = ServingFleet(
         layer_cfgs, params, replicas=3,
-        engine_kwargs=dict(num_slots=2, max_len=64, buckets=(8, 16)),
-        # sick detection OFF (huge threshold): the spike must BURN the
-        # SLO rather than be healed away before the monitor sees it
-        supervisor=fast_supervisor(sick_threshold=1e9),
+        engine_kwargs=dict(num_slots=2, max_len=64, buckets=(8, 16),
+                           max_concurrency=2),
+        # sick detection OFF (fast_supervisor's default): the spike must
+        # BURN the SLO rather than be healed away before the monitor
+        # sees it
+        supervisor=fast_supervisor(),
         fault_injector=FleetFaultInjector(plan),
         devices=devices,
         slo=SloMonitor([
@@ -922,7 +929,8 @@ def test_successful_reforms_refund_the_budget(gpt, devices):
     )
     fleet = ServingFleet(
         layer_cfgs, params, replicas=2,
-        engine_kwargs=dict(num_slots=2, max_len=64, buckets=(8, 16)),
+        engine_kwargs=dict(num_slots=2, max_len=64, buckets=(8, 16),
+                           max_concurrency=2),
         supervisor=fast_supervisor(max_reforms=2),
         fault_injector=FleetFaultInjector(plan),
         devices=devices,

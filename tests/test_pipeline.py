@@ -238,7 +238,12 @@ def test_measure_stage_times_dedups_identical_stages(devices):
             if keys[i] == keys[j]:
                 assert times[i] == times[j], (i, j, times)
     # at least one pair must have deduped in this partition
-    assert any(
-        keys[i] == keys[j] and times[i] == times[j]
-        for i in range(4) for j in range(i + 1, 4)
+    i, j = next(
+        (i, j) for i in range(4) for j in range(i + 1, 4)
+        if keys[i] == keys[j] and times[i] == times[j]
     )
+    # the emulated-degradation factor multiplies the shared raw sample,
+    # so a straggler is visible to this pass (self-healing confirms on it)
+    model.stages[j].slowdown = 3.0
+    slowed = model.measure_stage_times(data, repeats=1, inner_iters=1)
+    assert slowed[j] == 3.0 * slowed[i]

@@ -2,15 +2,12 @@
 
 The continuous-batching engine's correctness story is token identity:
 whatever the scheduler does — mixed-length batches, requests joining and
-leaving mid-decode, slot exhaustion, preemption — every request's output
+leaving mid-decode, exhaustion, preemption — every request's output
 must equal the one-shot full-forward ``generate`` for that prompt.  The
 performance story is the compile discipline: after one warmup pass per
 prompt bucket, the steady state pins ZERO XLA recompiles via
 ``xla_compile_count()``.
 """
-
-import subprocess
-import sys
 
 import jax
 import numpy as np
@@ -20,24 +17,24 @@ from skycomputing_tpu.builder import build_layer_stack
 from skycomputing_tpu.models.gpt import (
     GptConfig,
     generate,
+    generate_cached,
     gpt_layer_configs,
 )
 from skycomputing_tpu.parallel.pipeline import xla_compile_count
 from skycomputing_tpu.serving import (
-    KVCacheSpec,
     PagedKVCachePool,
     Request,
+    RowAllocator,
     ServingEngine,
     ServingStats,
     ShapeBucketer,
-    SlotKVCachePool,
 )
 
 pytestmark = pytest.mark.serving
 
 
 def paged_engine(layer_cfgs, params, **kw):
-    """A paged-layout engine with small-test defaults."""
+    """An engine with small-test defaults (pages of 8)."""
     base = dict(num_slots=3, max_len=48, buckets=(8, 16),
                 kv_layout="paged", page_size=8)
     base.update(kw)
@@ -78,28 +75,42 @@ def mixed_requests(rng, specs):
 # --------------------------------------------------------------------------
 
 
-def test_mixed_length_batch_token_identity(gpt, devices):
-    """Every request of a mixed-length, mixed-generation batch served
-    over a 2-stage pipeline matches its one-shot decode exactly."""
+@pytest.mark.parametrize("engine_kw, specs", [
+    # three rows for six requests, over a 2-stage pipeline: rows run out
+    pytest.param(
+        dict(num_slots=3, max_len=64, prefill_batch=2, partition=[2, 4],
+             max_concurrency=3),
+        [(5, 9), (3, 4), (12, 7), (7, 1), (16, 6), (2, 11)],
+        id="rows_exhausted_two_stages"),
+    # eight rows over a pool of six pages: pages run out
+    pytest.param(
+        dict(max_len=48, page_size=8, num_pages=6, max_concurrency=8),
+        [(4, 6), (5, 3), (12, 8), (6, 2), (2, 5), (9, 4)],
+        id="pages_exhausted"),
+])
+def test_mixed_length_batch_token_identity(gpt, devices, engine_kw, specs):
+    """More mixed-length, mixed-generation requests than the engine can
+    seat at once: admission queues on the exhausted resource (never
+    errors, never corrupts), the pool never over-allocates, every
+    request still finishes token-identical to its one-shot decode, and
+    the refcount audit passes after the drain."""
     layer_cfgs, params, fwd = gpt
-    engine = ServingEngine(
-        layer_cfgs, params, num_slots=3, max_len=64, buckets=(8, 16),
-        prefill_batch=2, partition=[2, 4], devices=devices[:2],
-    )
-    rng = np.random.default_rng(0)
-    requests = mixed_requests(
-        rng, [(5, 9), (3, 4), (12, 7), (7, 1), (16, 6), (2, 11)]
-    )
-    outputs = engine.run(requests)
+    engine = ServingEngine(layer_cfgs, params, buckets=(8, 16),
+                           devices=devices[:2], **engine_kw)
+    requests = mixed_requests(np.random.default_rng(0), specs)
     for r in requests:
-        np.testing.assert_array_equal(
-            outputs[r.request_id], reference(fwd, r)
-        )
+        engine.submit(r)
+    pages_seen = []
+    while engine.has_work():
+        engine.step()
+        pages_seen.append(engine._pool.pages_in_use)
+    assert max(pages_seen) <= engine.num_pages  # never over-allocates
+    assert engine.stats.queue_stalls > 0  # exhaustion queued
     assert engine.stats.finished == len(requests)
     assert engine.stats.queue_depth == 0
-    # slots were contended (6 requests, 3 slots) -> the admission layer
-    # queued rather than erroring
-    assert engine.stats.queue_stalls > 0
+    for r in requests:
+        np.testing.assert_array_equal(r.output(), reference(fwd, r))
+    engine._pool.check_consistency()
 
 
 def test_join_and_leave_mid_decode(gpt):
@@ -132,6 +143,7 @@ def test_slot_exhaustion_queues_not_crashes(gpt):
     layer_cfgs, params, fwd = gpt
     engine = ServingEngine(
         layer_cfgs, params, num_slots=2, max_len=64, buckets=(8,),
+        max_concurrency=2,
     )
     rng = np.random.default_rng(2)
     requests = mixed_requests(
@@ -173,21 +185,104 @@ def test_preemption_requeues_with_stream_intact(gpt):
     np.testing.assert_array_equal(other.output(), reference(fwd, other))
 
 
+def test_default_engine_is_paged_and_token_identical(gpt):
+    """``ServingEngine(cfgs, params)`` with no layout argument serves
+    from a page pool (the derived defaults: 4 rows' worth of whole
+    spans, 16 decode rows) and a mixed batch matches
+    ``generate_cached``, the single-request reference, token for
+    token."""
+    layer_cfgs, params, _ = gpt
+    stack = build_layer_stack(layer_cfgs)
+    engine = ServingEngine(layer_cfgs, params)
+    assert isinstance(engine._pool, PagedKVCachePool)
+    # max_position_embeddings = 64 clamps the default max_len of 128
+    assert (engine.page_size, engine.max_pages_per_request,
+            engine.max_len) == (16, 4, 64)
+    assert (engine.num_pages, engine.max_concurrency,
+            engine.num_slots) == (16, 16, 16)
+    assert engine.free_slots == 16 and engine.attn_impl == "xla"
+    assert engine._health_snapshot()["kv_layout"] == "paged"
+    requests = mixed_requests(
+        np.random.default_rng(8), [(5, 9), (3, 4), (40, 7), (20, 1), (60, 4)]
+    )
+    outputs = engine.run(requests)
+    for r in requests:
+        np.testing.assert_array_equal(
+            outputs[r.request_id],
+            generate_cached(stack, params, r.prompt, r.max_new_tokens,
+                            context_length=64)[0],
+        )
+    engine._pool.check_consistency()
+
+
+@pytest.mark.parametrize("removed", [
+    dict(kv_layout="slot"),
+    dict(static_batching=True),
+    dict(gather_pages="full"),
+], ids=lambda kw: next(iter(kw)))
+def test_slot_layout_is_rejected_by_name(gpt, removed):
+    """The slot layout and the two options that only its harness set
+    are gone: the layout by a ``ValueError`` that says so, the options
+    as the unexpected keywords they now are."""
+    layer_cfgs, params, _ = gpt
+    if "kv_layout" in removed:
+        with pytest.raises(ValueError, match="slot layout was removed"):
+            ServingEngine(layer_cfgs, params, **removed)
+    else:
+        with pytest.raises(TypeError, match="unexpected keyword"):
+            ServingEngine(layer_cfgs, params, **removed)
+
+
 # --------------------------------------------------------------------------
 # compile discipline
 # --------------------------------------------------------------------------
 
 
-def test_zero_steady_state_recompiles_after_bucket_warmup(gpt):
-    """One warmup request per bucket compiles every program; a second,
-    larger mixed wave then runs with ZERO XLA backend compiles."""
+def _warm_each_bucket(rng):
+    # one request per bucket; the second decodes past 16 positions, so
+    # the next page-table width is warm too
+    return [mixed_requests(rng, [(4, 3), (12, 6)])]
+
+
+def _warm_buckets_and_a_prefix_hit(rng):
+    # distinct leading tokens so the prefix cache cannot collapse a
+    # bucket's tail into a smaller one, then a shared-prefix pair: the
+    # 2nd hits the 1st's prefix, which warms the COW copy program
+    runs = [
+        [Request(prompt=np.full((b,), b + 1, np.int32), max_new_tokens=2)]
+        for b in (8, 16)
+    ]
+    system = rng.integers(1, 512, (12,)).astype(np.int32)
+    runs += [
+        [Request(prompt=np.concatenate(
+            [system, rng.integers(1, 512, (2,)).astype(np.int32)]),
+            max_new_tokens=2)]
+        for _ in range(2)
+    ]
+    return runs
+
+
+@pytest.mark.parametrize("engine_kw, warmup, prefix_hits", [
+    pytest.param(dict(num_slots=3, max_len=64, max_concurrency=3),
+                 _warm_each_bucket, 0, id="pages_of_16"),
+    pytest.param(dict(num_slots=3, max_len=48, page_size=8),
+                 _warm_buckets_and_a_prefix_hit, 1,
+                 id="pages_of_8_with_prefix_hits"),
+])
+def test_zero_steady_state_recompiles_after_bucket_warmup(
+    gpt, engine_kw, warmup, prefix_hits
+):
+    """A warmup pass compiles every program (one step shape per bucket
+    and table width, and the COW copy where a prefix is shared); a
+    second, larger mixed wave then runs with ZERO XLA backend compiles,
+    with tracing off and with tracing on."""
     layer_cfgs, params, fwd = gpt
-    engine = ServingEngine(
-        layer_cfgs, params, num_slots=3, max_len=64, buckets=(8, 16),
-        prefill_batch=2,
-    )
+    engine = ServingEngine(layer_cfgs, params, buckets=(8, 16),
+                           prefill_batch=2, **engine_kw)
     rng = np.random.default_rng(4)
-    engine.run(mixed_requests(rng, [(4, 3), (12, 3)]))  # one per bucket
+    for run in warmup(rng):
+        engine.run(run)
+    assert engine.stats.prefix_hits >= prefix_hits  # the warmup really hit
     warm = xla_compile_count()
     wave = mixed_requests(rng, [(6, 8), (2, 3), (15, 5), (9, 4), (11, 2)])
     outputs = engine.run(wave)
@@ -241,26 +336,27 @@ def test_bucketer_contract():
     assert ids[0, :3].tolist() == [1, 2, 3] and ids[0, 3:].sum() == 0
 
 
-def test_slot_pool_contract():
-    spec = KVCacheSpec(max_len=16, num_heads=2, head_dim=4)
-    pool = SlotKVCachePool([spec, spec], slots=2)
-    assert pool.free_slots == 2 and pool.occupancy == 0.0
-    a, b = pool.allocate(), pool.allocate()
-    assert {a, b} == {0, 1}
-    assert pool.allocate() is None  # exhaustion is a None, not a raise
-    pool.release(a)
+def test_row_allocator_contract():
+    rows = RowAllocator(2)
+    assert rows.free_slots == 2 and rows.occupancy == 0.0
+    a, b = rows.allocate(), rows.allocate()
+    assert {a, b} == {0, 1} and rows.used_slots == 2
+    assert rows.allocate() is None  # exhaustion is a None, not a raise
+    rows.release(a)
     with pytest.raises(ValueError, match="double-released"):
-        pool.release(a)
-    pool.acquire(a)  # the multi-stage lockstep claim
+        rows.release(a)
+    with pytest.raises(ValueError, match="out of range"):
+        rows.release(2)
+    rows.acquire(a)  # claims one specific free row
     with pytest.raises(ValueError, match="not free"):
-        pool.acquire(a)
-    assert len(pool.slabs) == 2  # one (k, v) pair per layer
-    assert pool.slabs[0][0].shape == (2, 16, 2, 4)
-    assert pool.total_mb() == pytest.approx(2 * spec.slab_mb(2))
+        rows.acquire(a)
+    assert rows.total_mb() == 0.0  # rows own no device memory
+    with pytest.raises(ValueError, match="at least 1 row"):
+        RowAllocator(0)
 
 
 def test_engine_preflight_rejects_over_budget_kv_slabs(gpt, devices):
-    """An allocation whose KV slabs blow a worker's mem_limit dies at
+    """An allocation whose page pool blows a worker's mem_limit dies at
     engine construction — before any slab allocates or program compiles
     — with the serving operating point in the diagnostic."""
     from skycomputing_tpu.analysis.plan_check import PlanError
@@ -278,7 +374,7 @@ def test_engine_preflight_rejects_over_budget_kv_slabs(gpt, devices):
         w.model_config = layer_cfgs[cursor:cursor + c]
         w.order = w.rank + 1
         cursor += c
-    with pytest.raises(PlanError, match="KV slots"):
+    with pytest.raises(PlanError, match="KV pages"):
         ServingEngine(
             layer_cfgs, params, num_slots=64, max_len=64, buckets=(8,),
             worker_manager=wm, devices=devices,
@@ -402,12 +498,7 @@ def test_serving_allocate_balances_decode_costs(gpt, devices):
 
 
 # --------------------------------------------------------------------------
-# benchmark smoke (the perf-marker path)
-# --------------------------------------------------------------------------
-
-
-# --------------------------------------------------------------------------
-# paged KV cache + prefix reuse
+# page pool + prefix reuse
 # --------------------------------------------------------------------------
 
 
@@ -446,32 +537,6 @@ def test_paging_pool_contract():
     assert pool.free_pages == 8
     with pytest.raises(KeyError):
         pool.release(42)
-
-
-def test_paged_token_identity_and_page_exhaustion_queues(gpt):
-    """More requests than the page pool holds: admission queues on
-    page exhaustion (never corrupts), every request still finishes
-    token-identical to its one-shot decode, and the refcount audit
-    passes after the drain."""
-    layer_cfgs, params, fwd = gpt
-    engine = paged_engine(layer_cfgs, params, num_pages=6,
-                          max_concurrency=8)
-    rng = np.random.default_rng(11)
-    requests = mixed_requests(
-        rng, [(4, 6), (5, 3), (12, 8), (6, 2), (2, 5), (9, 4)]
-    )
-    for r in requests:
-        engine.submit(r)
-    pages_seen = []
-    while engine.has_work():
-        engine.step()
-        pages_seen.append(engine._pool.pages_in_use)
-    assert max(pages_seen) <= 6  # the pool never over-allocates
-    assert engine.stats.queue_stalls > 0  # exhaustion queued
-    assert engine.stats.finished == len(requests)
-    for r in requests:
-        np.testing.assert_array_equal(r.output(), reference(fwd, r))
-    engine._pool.check_consistency()
 
 
 def test_paged_prefix_reuse_cow_identity(gpt):
@@ -593,41 +658,11 @@ def test_paged_swap_record_bytes_do_not_depend_on_the_stored_shape(
     engine._pool.check_consistency()
 
 
-def test_paged_zero_steady_state_recompiles(gpt):
-    """After one warmup request per bucket (distinct leading tokens so
-    the prefix cache cannot collapse a bucket's tail into a smaller
-    one) plus a shared-prefix pair (warms the COW copy program), a
-    mixed wave with live prefix hits runs with ZERO XLA compiles."""
-    layer_cfgs, params, fwd = gpt
-    engine = paged_engine(layer_cfgs, params, prefill_batch=2)
-    rng = np.random.default_rng(14)
-    for b in (8, 16):
-        engine.run([Request(prompt=np.full((b,), b + 1, np.int32),
-                            max_new_tokens=2)])
-    system = rng.integers(1, 512, (12,)).astype(np.int32)
-    for _ in range(2):  # 2nd hits the 1st's prefix -> COW program warm
-        engine.run([Request(
-            prompt=np.concatenate(
-                [system, rng.integers(1, 512, (2,)).astype(np.int32)]),
-            max_new_tokens=2)])
-    assert engine.stats.prefix_hits >= 1  # the warmup pair really hit
-    warm = xla_compile_count()
-    wave = mixed_requests(rng, [(6, 8), (2, 3), (15, 5), (9, 4), (11, 2)])
-    outputs = engine.run(wave)
-    assert xla_compile_count() == warm, (
-        "steady-state paged serving recompiled after warmup"
-    )
-    for r in wave:
-        np.testing.assert_array_equal(
-            outputs[r.request_id], reference(fwd, r)
-        )
-
-
 def test_paged_admission_decouples_buckets_from_capacity(gpt):
-    """Buckets are pure compile-shape classes under paged admission:
-    a short prompt padded to a bucket charges pages for its TRUE span,
-    so four requests whose bucket-padded sizes would blow a slot pool
-    all run concurrently on the pages their tokens actually need."""
+    """Buckets are pure compile-shape classes: a short prompt padded to
+    a bucket charges pages for its TRUE span, so four requests whose
+    bucket-padded sizes exceed the pool all run concurrently on the
+    pages their tokens actually need."""
     layer_cfgs, params, fwd = gpt
     # pool = 4 pages x 8 positions = 32 positions; each request spans
     # <= 8 positions (1 page) but pads to the 16-bucket for compile
@@ -646,20 +681,20 @@ def test_paged_admission_decouples_buckets_from_capacity(gpt):
     engine.run()
     for r in requests:
         np.testing.assert_array_equal(r.output(), reference(fwd, r))
-    # slot-mode contrast: the same bucket set hard-caps concurrency at
-    # the slot count regardless of true prompt lengths
-    slot = ServingEngine(layer_cfgs, params, num_slots=2, max_len=32,
-                         buckets=(16,), prefill_batch=4)
+    # the other cap: two rows seat two requests however many pages and
+    # however wide a prefill wave there are
+    rows = ServingEngine(layer_cfgs, params, num_slots=2, max_len=32,
+                         buckets=(16,), prefill_batch=4, max_concurrency=2)
     for r in mixed_requests(rng, [(5, 3), (6, 2), (4, 4), (5, 2)]):
-        slot.submit(r)
-    slot.step()
-    assert len(slot.running_requests) + slot.stats.finished <= 2
+        rows.submit(r)
+    rows.step()
+    assert len(rows.running_requests) + rows.stats.finished <= 2
 
 
 def test_paged_default_span_clamps_to_position_table(gpt):
     """The derived max_pages_per_request never rounds the per-request
-    span past max_position_embeddings: a (max_len, page_size) pair the
-    slot layout accepts must not be rejected by its own rounding."""
+    span past max_position_embeddings: a max_len the model accepts
+    must not be rejected by its own rounding to pages."""
     layer_cfgs, params, _ = gpt  # max_position_embeddings = 64
     engine = ServingEngine(
         layer_cfgs, params, num_slots=2, max_len=60, buckets=(8,),
@@ -675,11 +710,11 @@ def test_paged_default_span_clamps_to_position_table(gpt):
 
 
 def test_paged_reconfigure_verify_then_apply(gpt):
-    """Paged knob classes: bucket-only changes are eviction-free; a
+    """Knob classes: bucket-only changes are eviction-free; a
     concurrency change evicts recomputation-style on the same pool; a
     geometry change rebuilds pool+slabs with counters banked (never
-    backwards); slot engines reject page knobs; infeasible points are
-    rejected with the engine untouched."""
+    backwards); infeasible points are rejected with the engine
+    untouched."""
     layer_cfgs, params, fwd = gpt
     engine = paged_engine(layer_cfgs, params, max_concurrency=4)
     rng = np.random.default_rng(16)
@@ -692,7 +727,7 @@ def test_paged_reconfigure_verify_then_apply(gpt):
     assert engine.stats.preemptions == 0  # bucket-only: no eviction
     engine.reconfigure(max_concurrency=6)
     assert engine.stats.preemptions > 0
-    assert engine.num_slots == 6  # rows are the paged 'slots'
+    assert engine.num_slots == 6  # num_slots is the row count
     engine.step()
     hits_before = engine.stats.prefix_hits
     old_pool = engine._pool
@@ -709,11 +744,6 @@ def test_paged_reconfigure_verify_then_apply(gpt):
     with pytest.raises(PlanError, match="max_pages_per_request"):
         engine.reconfigure(max_pages_per_request=100)
     assert engine.num_pages == 12
-    # slot engines reject page knobs outright
-    slot = ServingEngine(layer_cfgs, params, num_slots=2, max_len=32,
-                         buckets=(8,))
-    with pytest.raises(ValueError, match="kv_layout='paged'"):
-        slot.reconfigure(num_pages=8)
 
 
 # --------------------------------------------------------------------------
@@ -721,31 +751,33 @@ def test_paged_reconfigure_verify_then_apply(gpt):
 # --------------------------------------------------------------------------
 
 
-def test_paged_gather_bound_live_vs_full_identity(gpt):
-    """The bounded live-width gather (gather_pages="live", the default)
-    is pure shape bookkeeping: outputs are token-identical to the
-    full-table-width baseline AND to one-shot generate — positions a
+def test_paged_gather_bound_live_identity(gpt):
+    """The bounded live-width gather is pure shape bookkeeping: outputs
+    are token-identical to ``generate_cached``'s stream, which reads
+    each request's whole row, AND to one-shot generate — positions a
     narrower gather drops were exactly the ones the causal mask already
     zeroed."""
     layer_cfgs, params, fwd = gpt
-    specs = [(5, 8), (3, 4), (14, 6), (9, 3)]
-    rng = np.random.default_rng(31)
-    live_reqs = mixed_requests(rng, specs)
-    full_reqs = [
-        Request(prompt=r.prompt.copy(), max_new_tokens=r.max_new_tokens)
-        for r in live_reqs
-    ]
-    live = paged_engine(layer_cfgs, params)
-    assert live.gather_pages == "live"
-    full = paged_engine(layer_cfgs, params, gather_pages="full")
-    l_out = live.run(live_reqs)
-    f_out = full.run(full_reqs)
-    for lr, fr in zip(live_reqs, full_reqs):
+    stack = build_layer_stack(layer_cfgs)
+    requests = mixed_requests(
+        np.random.default_rng(31), [(5, 8), (3, 4), (14, 6), (9, 3)]
+    )
+    engine = paged_engine(layer_cfgs, params)
+    # the widest table (6 columns) is never asked for: 2 is the floor
+    # (the 16-bucket) and 14 + 6 positions need 3, so 4
+    assert engine.max_pages_per_request == 6
+    outputs = engine.run(requests)
+    assert engine.stats.attn_pages_table <= (
+        engine.stats.iterations * engine.max_concurrency * 4
+    )
+    for r in requests:
         np.testing.assert_array_equal(
-            l_out[lr.request_id], reference(fwd, lr)
+            outputs[r.request_id],
+            generate_cached(stack, params, r.prompt, r.max_new_tokens,
+                            context_length=64)[0],
         )
         np.testing.assert_array_equal(
-            l_out[lr.request_id], f_out[fr.request_id]
+            outputs[r.request_id], reference(fwd, r)
         )
 
 
@@ -791,7 +823,7 @@ def test_paged_attn_impl_pallas_identity_and_recompile_pin(gpt):
 
 
 def test_paged_decode_counts_live_and_table_pages(gpt):
-    """``attn_pages_live`` / ``attn_pages_table`` per paged decode tick,
+    """``attn_pages_live`` / ``attn_pages_table`` per decode tick,
     against a table worked out by hand: pages of 4, three rows, prompts
     of 14 and 5 tokens (so first queries at 14 and 5), the 16-bucket's
     four columns as the table's floor and eight once a row needs a
@@ -828,9 +860,7 @@ def test_paged_decode_counts_live_and_table_pages(gpt):
     assert ServingStats.FIELD_TYPES["attn_pages_table"] == "counter"
 
 
-# re-tiered slow: tier-1 wall-clock budget; the full run keeps it, and
-# the int8 agreement/identity contract is additionally gated on every
-# BENCH_serving.json regeneration (kernel_quant section)
+# re-tiered slow: tier-1 wall-clock budget; the full run keeps it
 @pytest.mark.slow
 def test_paged_int8_agreement_and_observability(gpt):
     """kv_dtype="int8": bounded-error pages keep greedy streams in high
@@ -883,8 +913,8 @@ def test_paged_int8_agreement_and_observability(gpt):
 def test_paged_kv_dtype_charging_and_validation(gpt):
     """The pre-flight charges int8 pools at the quantized byte width
     (values + scale slabs, the allocator's own formula) — ~4x below a
-    float32 pool — and malformed/misplaced kv_dtype knobs are rejected
-    with named diagnostics, never silently mis-accounted."""
+    float32 pool — and malformed kv_dtype knobs are rejected with
+    named diagnostics, never silently mis-accounted."""
     from skycomputing_tpu.analysis.plan_check import (
         _serving_kv_profile,
     )
@@ -922,10 +952,6 @@ def test_paged_kv_dtype_charging_and_validation(gpt):
         layer_cfgs, dict(slots=2, max_len=32, kv_dtype="int8"),
         bad, "error",
     ) is None and "paged" in bad[0].message
-    # the engine rejects the knob off the paged layout outright
-    with pytest.raises(ValueError, match="kv_layout='paged'"):
-        ServingEngine(layer_cfgs, params, num_slots=2, max_len=32,
-                      buckets=(8,), kv_dtype="int8")
     with pytest.raises(ValueError, match="kv_dtype"):
         paged_engine(layer_cfgs, params, kv_dtype="int4")
     # the decode profiler stamps + charges the same formula
@@ -979,7 +1005,7 @@ def test_chunk_budget_policy_contract():
 
 def test_chunked_prefill_token_identity(gpt):
     """Chunked prefill is pure scheduling: every output matches the
-    one-shot `generate` AND the unchunked paged engine, with chunk
+    one-shot `generate` AND the unchunked engine, with chunk
     waves actually taken and decode interleaved between them."""
     layer_cfgs, params, fwd = gpt
     rng = np.random.default_rng(21)
@@ -1107,14 +1133,34 @@ def test_spec_acceptance_commits_multiple_tokens(gpt):
     engine._pool.check_consistency()
 
 
+def zero_tail_residuals(layer_cfgs, params_list, draft_blocks):
+    """Zero the residual output projections (``c_proj``) of every
+    block at or past ``draft_blocks``, making those blocks exact
+    identities.  The prefix-slice draft then agrees with the target at
+    EVERY position (accept rate 1.0).  The target still pays its full
+    per-layer compute: zeroed matmuls cost the same FLOPs."""
+    new = list(params_list)
+    block = -1
+    for i, cfg in enumerate(layer_cfgs):
+        lt = cfg.get("layer_type")
+        if lt == "GptBlock_Attn":
+            block += 1
+        if lt in ("GptBlock_Attn", "GptBlock_Mlp") and \
+                block >= draft_blocks:
+            layer = dict(new[i])
+            layer["c_proj"] = jax.tree_util.tree_map(
+                np.zeros_like, layer["c_proj"]
+            )
+            new[i] = layer
+    return new
+
+
 def test_spec_exact_draft_accept_rate_is_one(gpt):
-    """With a PERFECT draft (tail blocks' residual projections zeroed,
-    the bench's exact-draft surgery) the accept rate reads exactly 1.0
+    """With a PERFECT draft (tail blocks' residual projections zeroed)
+    the accept rate reads exactly 1.0
     and no rollback fires — even when generation budgets are not
     multiples of spec_k+1, because the denominator counts only USABLE
     proposals (a final tick's surplus drafts are not failures)."""
-    from tools.bench_serving import zero_tail_residuals
-
     layer_cfgs, params, _ = gpt
     sparams = zero_tail_residuals(layer_cfgs, list(params), 1)
     spec = paged_engine(layer_cfgs, sparams, prefill_batch=2,
@@ -1336,126 +1382,3 @@ def test_spec_preflight_charges_draft_memory():
     assert any("spec_k" in i.message for i in report.errors)
     report = verify_tuning_knobs(max_len=4, spec_k=8)
     assert any("verify window" in i.message for i in report.errors)
-
-
-@pytest.mark.slow
-def test_bench_serving_chunk_spec_smoke(tmp_path):
-    """`bench_serving --chunked --spec --smoke` completes with the
-    mechanics gates green (token identity both ways, zero steady-state
-    recompiles, chunks and drafts counted) and the artifact carries
-    the ITL/accept-rate schema the full-run gates read."""
-    out = tmp_path / "BENCH_chunk_spec.json"
-    proc = subprocess.run(
-        [sys.executable, "-m", "tools.bench_serving", "--chunked",
-         "--spec", "--smoke", "--out", str(out)],
-        capture_output=True, text=True, timeout=560,
-    )
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    import json
-
-    report = json.loads(out.read_text())
-    chunked = report["chunked_prefill"]
-    assert chunked["gates"]["chunk_token_identical"]
-    assert chunked["gates"]["chunk_matches_unchunked"]
-    assert chunked["gates"]["zero_steady_state_recompiles"]
-    assert chunked["chunked"]["itl_p95_s"] is not None
-    spec = report["speculative"]
-    assert spec["gates"]["spec_token_identical"]
-    assert spec["gates"]["spec_matches_plain"]
-    assert spec["gates"]["zero_steady_state_recompiles"]
-    assert spec["draft_exact"] is True
-    assert spec["accept_rate"] == 1.0
-
-
-@pytest.mark.slow
-def test_bench_serving_kernel_smoke(tmp_path):
-    """`bench_serving --kernel --smoke` completes with the mechanics
-    gates green (live-gather and pallas token identity, zero
-    steady-state recompiles on every impl, pages/MB gain, int8
-    agreement, quant counters) and stamps the kernel/quant schema the
-    full-run timing gates read."""
-    out = tmp_path / "BENCH_kernel.json"
-    proc = subprocess.run(
-        [sys.executable, "-m", "tools.bench_serving", "--kernel",
-         "--smoke", "--out", str(out)],
-        capture_output=True, text=True, timeout=560,
-    )
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    import json
-
-    report = json.loads(out.read_text())
-    kq = report["kernel_quant"]
-    gates = kq["gates"]
-    assert gates["live_token_identical"]
-    assert gates["live_matches_full_gather"]
-    assert gates["pallas_matches_xla"]
-    assert gates["zero_steady_state_recompiles_xla"]
-    assert gates["zero_steady_state_recompiles_pallas"]
-    assert gates["zero_steady_state_recompiles_int8"]
-    assert gates["pages_per_mb_gain_over_1_9x"]
-    assert kq["pages_per_mb_gain"] >= 1.9
-    assert gates["int8_agreement_over_0_7"]
-    assert gates["quant_counters_move"]
-    assert kq["int8"]["kv_dtype"] == "int8"
-    assert kq["pallas_leg"]["pallas"]["attn_impl"] == "pallas"
-
-
-@pytest.mark.slow
-def test_bench_serving_paged_smoke(tmp_path):
-    """`bench_serving --paged --smoke` completes with every gate green:
-    >2x sustained concurrency at equal pool MB, zero steady-state
-    recompiles, paged/slot/one-shot token identity, and prefix-cache
-    hits counted on the shared-prompt workload."""
-    out = tmp_path / "BENCH_paged.json"
-    proc = subprocess.run(
-        [sys.executable, "-m", "tools.bench_serving", "--paged",
-         "--smoke", "--out", str(out)],
-        capture_output=True, text=True, timeout=560,
-    )
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    import json
-
-    report = json.loads(out.read_text())
-    paged = report["paged"]
-    assert paged["gates"]["concurrency_gain_over_2x"]
-    assert paged["gates"]["paged_token_identical"]
-    assert paged["gates"]["zero_steady_state_recompiles"]
-    assert paged["gates"]["prefix_hits_counted"]
-    assert paged["concurrency_gain"] > 2.0
-    assert (paged["operating_point"]["pool_positions"]
-            == paged["operating_point"]["num_pages"]
-            * paged["operating_point"]["page_size"])
-
-
-@pytest.mark.perf
-# slow: drives tools/bench_serving.py end to end (~6 s); the serving
-# token-identity/recompile/exhaustion contracts it exercises are all
-# pinned by dedicated tier-1 tests above (870 s budget re-tier,
-# >=15% headroom — perf-and-slow per the pytest.ini tiering contract).
-@pytest.mark.slow
-def test_bench_serving_smoke(tmp_path):
-    """`bench_serving --smoke` completes, demonstrates a continuous-vs-
-    static win on a mixed workload, and its artifact carries the SLO
-    schema downstream consumers read."""
-    out = tmp_path / "BENCH_serving.json"
-    proc = subprocess.run(
-        [sys.executable, "-m", "tools.bench_serving", "--smoke",
-         "--out", str(out)],
-        capture_output=True, text=True, timeout=560,
-    )
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    import json
-
-    report = json.loads(out.read_text())
-    assert report["token_identical"] is True
-    assert report["throughput_speedup"] > 0
-    for mode in ("continuous", "static"):
-        stats = report[mode]["stats"]
-        for key in ("ttft_p50_s", "tpot_p50_s", "tokens_per_s",
-                    "queue_stalls", "preemptions", "batch_occupancy"):
-            assert key in stats
-    # continuous batching keeps slots busier than the static baseline
-    cont = report["continuous"]["stats"]
-    stat = report["static"]["stats"]
-    assert (cont["decode_tokens"] / max(cont["iterations"], 1)
-            >= stat["decode_tokens"] / max(stat["iterations"], 1))

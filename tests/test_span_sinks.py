@@ -304,7 +304,7 @@ SERVE_PARENT = {
 }
 
 
-def _tiny_engine(kv_layout):
+def _tiny_engine():
     from skycomputing_tpu.builder import build_layer_stack
     from skycomputing_tpu.models.gpt import GptConfig, gpt_layer_configs
     from skycomputing_tpu.serving import Request, ServingEngine
@@ -315,11 +315,9 @@ def _tiny_engine(kv_layout):
     layer_cfgs = gpt_layer_configs(cfg, deterministic=True)
     stack = build_layer_stack(layer_cfgs)
     params = stack.init(jax.random.key(0), np.ones((1, 5), np.int32))
-    extra = dict(kv_layout="paged", page_size=8) if kv_layout == "paged" \
-        else {}
     engine = ServingEngine(layer_cfgs, list(params), num_slots=3,
                            max_len=48, buckets=(8, 16), prefill_batch=2,
-                           **extra)
+                           kv_layout="paged", page_size=8)
     rng = np.random.default_rng(9)
 
     def requests():
@@ -332,10 +330,8 @@ def _tiny_engine(kv_layout):
     return engine, requests
 
 
-@pytest.mark.parametrize("kv_layout", ["paged", "slot"])
-def test_serving_leaves_the_catalogue_in_the_profilers_trace(
-        tmp_path, kv_layout):
-    engine, requests = _tiny_engine(kv_layout)
+def test_serving_leaves_the_catalogue_in_the_profilers_trace(tmp_path):
+    engine, requests = _tiny_engine()
     engine.run(requests())  # compile every shape first
     for request in requests():
         engine.submit(request)
@@ -350,10 +346,7 @@ def test_serving_leaves_the_catalogue_in_the_profilers_trace(
     assert xla_compile_count() == compiles0
 
     spans = _sky_spans(tmp_path)
-    expected = set(SERVE_PARENT)
-    if kv_layout == "slot":  # no pages to choose or to copy
-        expected -= {"sky.serve.select_wave", "sky.serve.cow"}
-    assert {name for name, _, _ in spans} == expected
+    assert {name for name, _, _ in spans} == set(SERVE_PARENT)
     for name, _, parent in spans:
         assert parent in SERVE_PARENT[name], (name, parent)
     by_name = {}
@@ -366,19 +359,17 @@ def test_serving_leaves_the_catalogue_in_the_profilers_trace(
     waves = sorted((s["bucket"], s["wave"], s["tokens"])
                    for s in by_name["sky.serve.prefill"])
     assert waves == [(8, 1, 5), (16, 1, 14)]
-    if kv_layout == "paged":
-        assert all(s["shared"] == 0 for s in by_name["sky.serve.prefill"])
+    assert all(s["shared"] == 0 for s in by_name["sky.serve.prefill"])
     assert [s["active"] for s in by_name["sky.serve.decode"]] == [2, 2, 2]
-    if kv_layout == "paged":
-        # pages of 8; queries at 14, 15, 16 and 5, 6, 7, ten idle rows of
-        # the program's twelve at a page each; the 16-bucket's 2 columns
-        # until a row needs a third
-        rows = engine.max_concurrency
-        assert rows == 12
-        assert [(s["attn_pages_live"], s["attn_pages_table"])
-                for s in by_name["sky.serve.decode"]] \
-            == [(2 + 1 + 10, rows * 2), (2 + 1 + 10, rows * 2),
-                (3 + 1 + 10, rows * 4)]
+    # pages of 8; queries at 14, 15, 16 and 5, 6, 7, ten idle rows of
+    # the program's twelve at a page each; the 16-bucket's 2 columns
+    # until a row needs a third
+    rows = engine.max_concurrency
+    assert rows == 12
+    assert [(s["attn_pages_live"], s["attn_pages_table"])
+            for s in by_name["sky.serve.decode"]] \
+        == [(2 + 1 + 10, rows * 2), (2 + 1 + 10, rows * 2),
+            (3 + 1 + 10, rows * 4)]
     assert all(s["stage"] == 0 for s in by_name["sky.serve.stage"])
     for name in ("sky.serve.put", "sky.serve.stage", "sky.serve.wait"):
         # one stage: one put, one dispatch and one barrier to a run

@@ -130,7 +130,7 @@ _HLO_RESULT = re.compile(
 @pytest.mark.parametrize("shape", list(STEP_SHAPES))
 def test_paged_step_never_relays_a_slab_out(on_v5e, monkeypatch, shape):
     """The stage's step program (``apply_kv_paged`` as
-    ``_PagedServingStage`` jits it: slabs donated, the Pallas kernel)
+    ``_ServingStage`` jits it: slabs donated, the Pallas kernel)
     holds no ``copy``, ``transpose`` or ``reshape`` whose result is as
     large as a slab: the scatter's flat view and the kernel's operand
     are bitcasts of the stored ``[num_pages, page_size, heads *

@@ -583,7 +583,9 @@ def _gpt_world(buckets=(16,), num_slots=2, max_len=48, prefill_batch=1):
     layer_cfgs = gpt_layer_configs(cfg, deterministic=True)
     stack = build_layer_stack(layer_cfgs)
     params = stack.init(jax.random.key(0), np.ones((1, 5), np.int32))
+    # every test here means decode rows by num_slots
     engine = ServingEngine(layer_cfgs, list(params), num_slots=num_slots,
+                           max_concurrency=num_slots,
                            max_len=max_len, buckets=buckets,
                            prefill_batch=prefill_batch)
     return engine, layer_cfgs, params

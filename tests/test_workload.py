@@ -171,7 +171,7 @@ def test_interference_mix_matches_legacy_draw_order():
     icfg = dict(n_churn=4, churn_prompt=(60, 90), churn_new=(4, 8),
                 n_small=8, small_prompt=(8, 24), small_new=(10, 16))
 
-    # the pre-workload-plane bench_serving loop, verbatim
+    # the pre-workload-plane inline loop, verbatim
     def legacy(rng):
         specs = []
         for _ in range(icfg["n_churn"]):

@@ -163,9 +163,8 @@ def run_bench(plan_names, out: Optional[str], seed: int) -> int:
             # the autoscaler's burn signal (the bench_scenarios
             # queue_pressure target): without a monitor it can only
             # ever scale DOWN
-            # threshold 2 (not bench_scenarios' 4): paged replicas run
-            # more concurrent decodes than slot engines, so the same
-            # peak produces a shallower queue
+            # threshold 2: a replica's decode rows float with its
+            # pages, so the scenario's peak produces a shallow queue
             fleet.attach_slo(SloMonitor([
                 SloTarget(name="queue_pressure",
                           metric="fleet.queue_depth",
