@@ -124,7 +124,7 @@ def pages_per_mb(
 
 
 class _TrieNode:
-    __slots__ = ("children", "entry")
+    __slots__ = ("children", "entry", "ends")
 
     def __init__(self):
         self.children: Dict[int, _TrieNode] = {}
@@ -133,6 +133,10 @@ class _TrieNode:
         # covering this prefix", because any sequence through the node
         # shares the node's full root path
         self.entry: Optional["_PrefixEntry"] = None
+        # the entry whose token sequence ends exactly here, if any: an
+        # eviction reads it to tell a node only the victim passes
+        # through from one a shorter cached prompt still needs
+        self.ends: Optional["_PrefixEntry"] = None
 
 
 @dataclass
@@ -140,6 +144,7 @@ class _PrefixEntry:
     tokens: Tuple[int, ...]
     pages: Tuple[int, ...]
     stamp: int  # logical LRU clock, bumped on every hit
+    born: int  # the clock at insertion: orders "most recently inserted"
 
 
 class RadixPrefixIndex:
@@ -153,10 +158,14 @@ class RadixPrefixIndex:
     bounded (``max_entries``) and evicted least-recently-used; eviction
     returns the evicted entry so the pool can drop its page refs.
 
-    The trie is rebuilt from the surviving entries on eviction — entry
-    counts are bounded and prompts are short relative to rebuild cost,
-    and a rebuild can never leave a stale ``node.entry`` pointing at
-    freed pages (the failure mode incremental unlinking invites).
+    An eviction costs the victim's own length, not the size of the
+    index: it unlinks the victim's path and builds nothing.  What keeps
+    that safe is one invariant, which ``tests/test_prefix_index.py``
+    walks the trie for after every operation: a node exists iff some
+    live entry passes through it, and ``node.entry`` is the most
+    recently inserted of those — so no ``node.entry`` ever points at an
+    evicted entry's freed pages, and the trie is at all times the one a
+    fresh build from the surviving entries would give.
     """
 
     def __init__(self, max_entries: int = 256):
@@ -189,14 +198,16 @@ class RadixPrefixIndex:
         if existing is not None:
             existing.stamp = self._tick()
             return False
+        stamp = self._tick()
         entry = _PrefixEntry(key, tuple(int(p) for p in pages),
-                             self._tick())
+                             stamp, stamp)
         self._entries[key] = entry
         node = self._root
         node.entry = entry
         for t in key:
             node = node.children.setdefault(t, _TrieNode())
             node.entry = entry
+        node.ends = entry
         return True
 
     def lookup(self, tokens: Sequence[int]) -> Tuple[int, Tuple[int, ...]]:
@@ -237,14 +248,15 @@ class RadixPrefixIndex:
         token sequence — the donor of an in-flight grant must survive
         the eviction its own admission triggers).  Returns the evicted
         entry so the caller drops its page refs, or None."""
-        victims = [
-            e for k, e in self._entries.items() if k != tuple(protect)
-        ]
-        if not victims:
+        protect = tuple(protect)
+        victim = min(
+            (e for k, e in self._entries.items() if k != protect),
+            key=lambda e: e.stamp, default=None,
+        )
+        if victim is None:
             return None
-        victim = min(victims, key=lambda e: e.stamp)
         del self._entries[victim.tokens]
-        self._rebuild()
+        self._unlink(victim)
         return victim
 
     def clear(self) -> List[_PrefixEntry]:
@@ -255,14 +267,36 @@ class RadixPrefixIndex:
         self._root = _TrieNode()
         return dropped
 
-    def _rebuild(self) -> None:
-        self._root = _TrieNode()
-        for entry in self._entries.values():
-            node = self._root
-            node.entry = entry
-            for t in entry.tokens:
-                node = node.children.setdefault(t, _TrieNode())
-                node.entry = entry
+    def _unlink(self, victim: _PrefixEntry) -> None:
+        """Take ``victim`` (already out of ``_entries``) off the trie:
+        cut the suffix of its path that no other entry passes through,
+        and hand every remaining node that named it to the most
+        recently inserted entry that still passes through."""
+        path = [self._root]
+        for t in victim.tokens:
+            path.append(path[-1].children[t])
+        kept = len(victim.tokens)  # depth of the deepest node that stays
+        path[kept].ends = None
+        if not path[kept].children:
+            # Nobody extends the victim, so its last node goes, and
+            # with it every node above at which no entry ends and
+            # nothing else branches off.  One ``del`` at the shallowest
+            # of them drops the whole chain.
+            kept -= 1
+            while (kept > 0 and path[kept].ends is None
+                   and len(path[kept].children) == 1):
+                kept -= 1
+            del path[kept].children[victim.tokens[kept]]
+        # The nodes that named the victim are a suffix of the path (a
+        # later insert through a node overwrote it and all its
+        # ancestors), so the walk up stops at the first that does not.
+        for node in path[kept::-1]:
+            if node.entry is not victim:
+                break
+            through = [c.entry for c in node.children.values()]
+            if node.ends is not None:
+                through.append(node.ends)
+            node.entry = max(through, key=lambda e: e.born, default=None)
 
 
 # --------------------------------------------------------------------------
