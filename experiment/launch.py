@@ -57,6 +57,10 @@ def _phase(name: str, seconds: dict):
                       seconds_into=seconds)
 
 
+#: positions of a token table the parameter server's probe keeps
+_INIT_PROBE_POSITIONS = 128
+
+
 def run(cfg, logger: Logger) -> int:
     phases: dict = {}
     devices = jax.devices()
@@ -96,9 +100,16 @@ def run(cfg, logger: Logger) -> int:
 
     # --- parameter server (host copy of the full model) ---------------------
     with _phase("parameter_server", phases):
-        probe = next(iter(BatchAdapter()))
+        # parameters depend on no batch size and on no count of positions:
+        # initialising on the host runs one row through, and of a table of
+        # token ids ([rows, positions] integers) its first positions
+        probe = tuple(
+            x[:1, :_INIT_PROBE_POSITIONS]
+            if x.ndim == 2 and np.issubdtype(x.dtype, np.integer) else x[:1]
+            for x in map(np.asarray, next(iter(BatchAdapter()))[0])
+        )
         parameter_server = ParameterServer(
-            cfg.model_config, example_inputs=probe[0], rng=jax.random.key(0)
+            cfg.model_config, example_inputs=probe, rng=jax.random.key(0)
         )
     logger.info(f"parameter server: {parameter_server.num_layers} layers")
 
@@ -106,12 +117,19 @@ def run(cfg, logger: Logger) -> int:
     # (the profilers are built here and run inside the allocator: their
     # ``allocator.profiles`` / ``bench.device`` / ``bench.model`` spans
     # nest under ``sky.launch.allocate``)
+    optimizer = build_optimizer(cfg.train_config["optim_cfg"])
     with _phase("profile", phases):
         bench_cfg = cfg.allocator_config["benchmark_config"]
         model_bench = ModelBenchmarker(
             cfg.model_config,
             build_data_generator(**bench_cfg["model"]["data_generator_cfg"]),
             param_scale=bench_cfg["model"].get("param_scale", 2),
+            # measured seconds a layer instead of XLA's FLOP count: what
+            # layers of unequal kind need (a scan and a matrix product of
+            # equal FLOPs are not equally long); "programs" times the
+            # stage programs themselves and needs the job's optimizer
+            timed=bench_cfg["model"].get("timed", False),
+            optimizer=optimizer,
         )
         stimulator = (
             Stimulator(worker_manager.size)
@@ -159,7 +177,7 @@ def run(cfg, logger: Logger) -> int:
         model = PipelineModel(
             worker_manager,
             parameter_server,
-            build_optimizer(cfg.train_config["optim_cfg"]),
+            optimizer,
             build_loss(cfg.train_config["loss_cfg"]),
             devices=devices,
             num_microbatches=getattr(cfg, "NUM_MICROBATCHES", 1),

@@ -90,8 +90,9 @@ class LintConfig:
 # the device queues.  Nested functions inherit hotness from the enclosing
 # definition.
 HOT_FN_RE = re.compile(
-    r"^(train_step|forward|forward_placed|backward|compute_gradients"
-    r"|_compute_gradients\w*|do_fwd|do_bwd|accumulate|apply_gradients"
+    r"^(train_step|forward|forward_placed|forward_saving|backward"
+    r"|compute_gradients|_compute_gradients\w*|do_fwd|do_bwd|accumulate"
+    r"|apply_gradients"
     r"|before_train_iter|after_train_iter|before_iter|after_iter"
     r"|_train_loop|issue\w*)$"
 )

@@ -63,11 +63,15 @@ class LayerStack:
         params_list: Sequence[Any],
         *inputs,
         dropout_rng: Optional[jax.Array] = None,
+        counters: bool = False,
     ):
         """Forward the tuple of inputs through every layer.
 
         Returns the final layer's raw output (tensor or tuple), matching the
-        reference where the last stage's output lands in the loss.
+        reference where the last stage's output lands in the loss.  With
+        ``counters=True`` returns ``(output, [what each layer sowed into its
+        "counters" collection])``: an auxiliary output beside the data (a
+        layer that sows nothing gives ``{}``).
         """
         if len(params_list) != len(self.modules):
             raise ValueError(
@@ -75,12 +79,18 @@ class LayerStack:
             )
         data = tuple(inputs)
         out = data if len(data) > 1 else data[0]
+        sown = []
         for i, (module, params) in enumerate(zip(self.modules, params_list)):
             rngs = None
             if dropout_rng is not None:
                 rngs = {"dropout": jax.random.fold_in(dropout_rng, i)}
-            out = module.apply({"params": params}, *data, rngs=rngs)
+            if counters:
+                out, state = module.apply({"params": params}, *data,
+                                          rngs=rngs, mutable=["counters"])
+                sown.append(dict(state.get("counters", {})))
+            else:
+                out = module.apply({"params": params}, *data, rngs=rngs)
             data = as_tuple(out)
-        return out
+        return (out, sown) if counters else out
 
 __all__ = ["LayerStack", "as_tuple"]

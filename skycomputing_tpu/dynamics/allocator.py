@@ -138,6 +138,11 @@ class Allocator:
             f"optimal_allocate: {len(layer_flops)} layers over "
             f"{len(worker_ranks)} workers; device_time={device_time}"
         )
+        # the profile the partition is solved on (relative magnitudes:
+        # FLOPs, or seconds where the profiler timed the layers)
+        self._logger.info(
+            "layer costs: " + " ".join(f"{c:.4g}" for c in layer_flops)
+        )
 
         with trace_span(
             "allocator.solve", "dynamics", "allocator",

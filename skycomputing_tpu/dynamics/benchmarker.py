@@ -173,8 +173,15 @@ class DeviceBenchmarker(BaseBenchmarker):
 
 
 def _layer_key(layer_cfg: Dict, input_avals) -> str:
+    """What two layers must share to be profiled once: the kind (the
+    registered ``layer_type``), the rest of the config whatever the order
+    of its keys, and the shapes that come in.  The kind stands first and
+    apart, so two layers of different kinds never collide, equal though
+    their options may be."""
+    cfg = dict(layer_cfg)
+    kind = cfg.pop("layer_type", None)
     shapes = [(tuple(a.shape), str(a.dtype)) for a in input_avals]
-    return json.dumps([layer_cfg, shapes], sort_keys=True, default=str)
+    return json.dumps([kind, cfg, shapes], sort_keys=True, default=str)
 
 
 class ModelBenchmarker(BaseBenchmarker):
@@ -207,11 +214,21 @@ class ModelBenchmarker(BaseBenchmarker):
         device: Optional[str] = None,  # accepted for config parity; unused
         timed: bool = False,
         timed_iterations: int = 8,
+        optimizer: Any = None,
     ):
         self._model_config = model_config
         self._data_generator = data_generator
         self._dtype = dtype
         self._param_scale = param_scale
+        # ``timed="programs"``: time the very programs a pipeline stage
+        # will run for the layer (``StageRuntime`` of one layer, built
+        # with the job's ``optimizer`` so that the engine finds the same
+        # programs in its cache): nothing is compiled for the profile
+        # alone
+        self._stage_programs = timed == "programs"
+        self._optimizer = optimizer
+        if self._stage_programs and optimizer is None:
+            raise ValueError('timed="programs" needs the job\'s optimizer')
         self._timed = bool(timed)
         self._timed_iterations = int(timed_iterations)
         self._result: Optional[Tuple[List[float], List[float]]] = None
@@ -239,6 +256,44 @@ class ModelBenchmarker(BaseBenchmarker):
             self._result = self._benchmark()
         return self._result
 
+    def _time_stage_programs(self, layer_cfg, module, inputs, first: bool):
+        """(outputs, seconds of one forward + one backward program) of the
+        layer as a one-layer pipeline stage runs it: the engine's own
+        programs, donation and recomputation included."""
+        import time
+
+        import jax.numpy as jnp
+
+        from ..parallel.pipeline import StageRuntime
+
+        inputs = tuple(jnp.asarray(x) for x in inputs)
+        k_params, k_dropout = jax.random.split(jax.random.key(0))
+        params = jax.jit(lambda key: module.init(
+            {"params": key, "dropout": k_dropout}, *inputs)["params"]
+        )(k_params)
+        stage = StageRuntime(0, [layer_cfg], [params], jax.devices()[0],
+                             self._optimizer, differentiable_inputs=not first)
+        rng = jax.random.key(1)
+        outputs = stage.forward_placed(inputs, rng)
+        dy = jax.tree_util.tree_map(jnp.ones_like, outputs)
+        fresh = lambda: jax.tree_util.tree_map(jnp.copy, inputs)
+
+        def once():
+            out = stage.forward_placed(inputs, rng)
+            grads, _ = stage.backward(fresh(), rng, dy)  # donates its input
+            return out, grads
+
+        jax.block_until_ready(once())
+        best = float("inf")
+        for _ in range(3):
+            start = time.perf_counter()
+            for _ in range(self._timed_iterations):
+                result = once()
+            jax.block_until_ready(result)
+            best = min(best, (time.perf_counter() - start)
+                       / self._timed_iterations)
+        return outputs, best
+
     def _benchmark(self) -> Tuple[List[float], List[float]]:
         data = self._data_generator.generate()
         data = data if isinstance(data, tuple) else (data,)
@@ -261,9 +316,16 @@ class ModelBenchmarker(BaseBenchmarker):
                     cfg = dict(layer_cfg)
                     layer_type = cfg.pop("layer_type")
                     module = build_layer(layer_type, **cfg)
-                    outputs, seconds = Estimator.benchmark_train_time(
-                        module, current, iterations=self._timed_iterations
-                    )
+                    if self._stage_programs:
+                        outputs, seconds = self._time_stage_programs(
+                            layer_cfg, module, current,
+                            first=not cost_list,
+                        )
+                    else:
+                        outputs, seconds = Estimator.benchmark_train_time(
+                            module, current,
+                            iterations=self._timed_iterations,
+                        )
                     # memory stays the static formula so the allocator's
                     # capacity model is identical across modes (no FLOPs
                     # compile — the cost here is the measured seconds)

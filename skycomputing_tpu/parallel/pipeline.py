@@ -32,6 +32,7 @@ choose (bfloat16 by default for MXU-friendly matmuls).
 from __future__ import annotations
 
 import os
+import re
 import time
 import warnings
 from dataclasses import dataclass, field
@@ -268,6 +269,58 @@ def _step_rngs(rng, M: int, S: int):
     ]
 
 
+def _avals(tree):
+    """Shapes, dtypes and (where an array is committed to one) shardings of
+    a tree of arrays, ``None`` leaves staying: what lowers a program to
+    the module the call with the arrays themselves lowered it to, so that
+    compiling it again is a cache hit and not a second entry."""
+    def aval(a):
+        committed = getattr(a, "committed", getattr(a, "_committed", False))
+        return jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=a.sharding if committed else None)
+
+    return jax.tree_util.tree_map(aval, tree)
+
+
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?(%[\w.\-]+) = (\S+) .*op_name=\"([^\"]*)\"")
+
+
+def scoped_instructions_of(hlo_text: str, scopes: Sequence[str]):
+    """``[(instruction, result type, scope)]`` over the instructions of an
+    optimized HLO module that carry an ``op_name``: ``scope`` is the first
+    of ``scopes`` (each a ``jax.named_scope``; the backward pass keeps it
+    inside ``transpose(jvp(...))``) found in it, or ``""``.  A device
+    trace names its events by instruction, and by nothing else: this is
+    the way back to the scope."""
+    rows = []
+    for line in hlo_text.splitlines():
+        found = _INSTRUCTION.match(line)
+        if found:
+            name, result, op_name = found.groups()
+            scope = next((s for s in scopes if s in op_name), "")
+            rows.append((name, result, scope))
+    return rows
+
+
+def _is_last(path) -> bool:
+    """Is the sown leaf at ``path`` one that holds the LATEST call's value
+    (a name that starts with ``last_`` somewhere on the way to it) and not
+    a running total?"""
+    return any(str(getattr(k, "key", "")).startswith("last_") for k in path)
+
+
+def fold_counters(totals, sown):
+    """What a stage keeps of its layers' ``counters`` collection after one
+    more forward: a leaf is ADDED to its running total, unless its name
+    (or a name above it) starts with ``last_``: then the new value stands
+    in the old one's place (what the last call chose, what state it ended
+    in: read against a reference outside any timed window)."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, total, new: new if _is_last(path) else total + new,
+        totals, sown)
+
+
 def _split_microbatches(tree, num_microbatches: int, what: str = "microbatches"):
     """Leading-axis split of every leaf into equal shards."""
     def split(x):
@@ -346,6 +399,23 @@ class _StagePrograms:
                 return as_tuple(eval_stack.apply(params, *inputs))
             return as_tuple(stack.apply(params, *inputs, dropout_rng=rng))
 
+        # layers that sow counters (an expert layer's tokens by expert):
+        # a forward twin that takes the stage's running totals and returns
+        # them with this call's added, so they never leave the device
+        self.has_counters = any(
+            getattr(m, "has_counters", False) for m in stack.modules
+        )
+
+        def fwd_new_counters(params, inputs, rng):
+            which = eval_stack if rng is None else stack
+            out, sown = which.apply(params, *inputs, dropout_rng=rng,
+                                    counters=True)
+            return as_tuple(out), sown
+
+        def fwd_counted(params, inputs, rng, totals):
+            out, sown = fwd_new_counters(params, inputs, rng)
+            return out, fold_counters(totals, sown)
+
         def bwd(params, inputs, rng, dy):
             # Rematerialize forward inside backward: trades FLOPs for HBM —
             # activations never persist between fwd and bwd passes.
@@ -378,6 +448,12 @@ class _StagePrograms:
         self._raw_bwd = bwd
         self._raw_bwd_params_only = bwd_params_only
         self.fwd = jax.jit(fwd)
+        self.fwd_new_counters = fwd_new_counters
+        self.fwd_counted = (
+            jax.jit(fwd_counted,
+                    donate_argnums=(3,) if _donation_enabled() else ())
+            if self.has_counters else None
+        )
         self.bwd = jax.jit(bwd)
         self.bwd_params_only = jax.jit(bwd_params_only)
         self.grad_add = jax.jit(grad_add)
@@ -479,6 +555,15 @@ class StageRuntime:
         programs = get_stage_programs(layer_cfgs, optimizer)
         self.stack = programs.stack
         self._fwd = programs.fwd
+        self._fwd_counted = programs.fwd_counted
+        self._fwd_new_counters = programs.fwd_new_counters
+        # running totals of what the stage's layers sow, on the device
+        # (None: the stage sows nothing, or has not run yet)
+        self.counters = None
+        # shapes of the first forward's and the first backward's arguments
+        # (for ``scoped_instructions``; arrays are not kept)
+        self._fwd_avals = None
+        self._bwd_avals = None
         self._bwd = programs.bwd
         self._bwd_params_only = programs.bwd_params_only
         self._bwd_donated = programs.bwd_donated
@@ -511,17 +596,65 @@ class StageRuntime:
         them for backward), so the placement pass here would be a no-op
         tree traversal per microbatch per stage."""
         _DISPATCH_STATS["programs"] += 1
-        out = self._fwd(self.params, inputs, rng)
+        if self._fwd_avals is None:
+            self._fwd_avals = _avals((inputs, rng))
+        if self._fwd_counted is None:
+            out = self._fwd(self.params, inputs, rng)
+        else:
+            if self.counters is None:
+                _, shapes = jax.eval_shape(
+                    self._fwd_new_counters, self.params, inputs, rng)
+                self.counters = jax.device_put(
+                    jax.tree_util.tree_map(
+                        lambda a: np.zeros(a.shape, a.dtype), shapes),
+                    self.device,
+                )
+            out, self.counters = self._fwd_counted(
+                self.params, inputs, rng, self.counters)
         self._emulate_slowdown(out)
         return out
+
+    def forward_saving(self, inputs: Tuple, rng) -> Tuple[Any, Tuple]:
+        """``(saved, outputs)``: the forward the issue loops drive.  They
+        hold ``saved`` until the microbatch's backward is issued and hand
+        it to ``backward_accumulate`` in ``inputs``' place: here it IS the
+        placed inputs (the backward recomputes the stage from them)."""
+        return inputs, self.forward_placed(inputs, rng)
+
+    def timed_forward(self, inputs: Tuple, rng) -> Tuple:
+        """The forward for a profiler that runs it again and again on the
+        same buffers: nothing donated, nothing counted, nothing saved."""
+        return self._fwd(self.params, inputs, rng)
+
+    def timed_backward(self, inputs: Tuple, rng, dy: Tuple):
+        """The backward for the same profiler, undonated: ``(gradients,
+        dx)``, or the gradients alone where the inputs take none."""
+        if self._differentiable_inputs:
+            return self._bwd(self.params, inputs, rng, dy)
+        return self._bwd_params_only(self.params, inputs, rng, dy)
+
+    def timed_step(self, inputs: Tuple, rng, dy: Tuple):
+        """One forward and one backward as a step issues them, for that
+        profiler; returns something to block on."""
+        self.timed_forward(inputs, rng)
+        return self.timed_backward(inputs, rng, dy)
+
+    def layer_counters(self) -> List[Any]:
+        """What each of the stage's layers has sown, a layer an entry in
+        layer order (``{}``: the layer sows nothing, or has not run)."""
+        if self.counters is None:
+            return [{} for _ in range(self.num_layers)]
+        return list(self.counters)
 
     def backward(self, inputs: Tuple, rng, dy: Tuple):
         """Issue the donating backward: ``inputs`` is consumed (the issue
         loops own the last reference once a microbatch's backward goes
         out); profiling paths that re-execute with the same buffers must
-        use the undonated ``_bwd``/``_bwd_params_only`` directly."""
+        use ``timed_forward`` / ``timed_backward``."""
         dy = device_put_elided(dy, self.device)
         _DISPATCH_STATS["programs"] += 1
+        if self._bwd_avals is None:
+            self._bwd_avals = _avals((inputs, rng, dy))
         if self._differentiable_inputs:
             grads, dx = self._bwd_donated(self.params, inputs, rng, dy)
         else:
@@ -556,6 +689,28 @@ class StageRuntime:
             self.params, self.opt_state, grads
         )
 
+    def program_holders(self) -> List["StageRuntime"]:
+        """The runtimes whose programs this stage runs (itself)."""
+        return [self]
+
+    def compiled_programs(self) -> List[Any]:
+        """The stage's forward and backward programs as compiled for the
+        shapes they first ran with (``jax.stages.Compiled``: from the
+        persistent cache where there is one, else a compile; never inside
+        a timed window).  Empty until the stage has run."""
+        if self._fwd_avals is None or self._bwd_avals is None:
+            return []
+        params = _avals(self.params)
+        inputs, rng = self._fwd_avals
+        if self._fwd_counted is None:
+            fwd = self._fwd.lower(params, inputs, rng)
+        else:
+            fwd = self._fwd_counted.lower(params, inputs, rng,
+                                          _avals(self.counters))
+        bwd = (self._bwd_donated if self._differentiable_inputs
+               else self._bwd_params_only_donated)
+        return [fwd.compile(), bwd.lower(params, *self._bwd_avals).compile()]
+
     # --- weights exchange ---------------------------------------------------
     def get_state_dict(self) -> List[Any]:
         return jax.tree_util.tree_map(np.asarray, self.params)
@@ -570,6 +725,182 @@ class StageRuntime:
         self.opt_state = jax.device_put(
             self._optimizer.init(self.params), self.device
         )
+
+
+class LayeredStageRuntime:
+    """A pipeline stage that runs its layers as ONE PROGRAM A LAYER.
+
+    ``StageRuntime`` compiles a stage's whole slice into one forward and
+    one backward program: right for a stack of small equal layers (BERT:
+    68 programs a step are already host-bound), wasteful for a few large
+    layers of a few kinds, where every stage shape is a new executable
+    as large as its layers together and a layer kind is compiled once a
+    stage it appears in.  Here a stage holds one single-layer
+    ``StageRuntime`` a layer; layers of one kind and config share their
+    programs through the program cache whichever stage they sit in, so a
+    model compiles one forward, one backward and one update a layer KIND,
+    whatever the partition, and a re-allocation compiles nothing.  The
+    allocator still decides which layers a worker (a device) holds.
+
+    The cost is dispatches: one a layer instead of one a stage, and each
+    layer keeps its input for its own backward (which recomputes the
+    layer, not the stage).  ``PipelineModel`` takes this form where the
+    model's layers are large (``programs_a_layer``).
+    """
+
+    def __init__(self, stage_index, layer_cfgs, params, device, optimizer,
+                 slowdown: float = 1.0, differentiable_inputs: bool = True):
+        import json as _json
+
+        self.stage_index = stage_index
+        self.device = device
+        self.num_layers = len(layer_cfgs)
+        self.lane_name = f"stage {stage_index} [{device}]"
+        self.slowdown = float(slowdown)
+        self._differentiable_inputs = differentiable_inputs
+        self.config_key = _json.dumps(list(layer_cfgs), sort_keys=True,
+                                      default=str)
+        self.layers: List[StageRuntime] = [
+            StageRuntime(
+                stage_index, [cfg], [layer_params], device, optimizer,
+                slowdown=slowdown,
+                differentiable_inputs=differentiable_inputs or i > 0,
+            )
+            for i, (cfg, layer_params) in enumerate(zip(layer_cfgs, params))
+        ]
+
+    # what callers read off a stage
+    @property
+    def stack(self):
+        from ..builder import LayerStack
+
+        return LayerStack([l.stack.modules[0] for l in self.layers])
+
+    @property
+    def params(self) -> List[Any]:
+        return [l.params[0] for l in self.layers]
+
+    @params.setter
+    def params(self, params) -> None:
+        for layer, layer_params in zip(self.layers, params):
+            layer.params = [layer_params]
+
+    @property
+    def opt_state(self) -> List[Any]:
+        return [l.opt_state for l in self.layers]
+
+    @opt_state.setter
+    def opt_state(self, states) -> None:
+        for layer, state in zip(self.layers, states):
+            layer.opt_state = state
+
+    def layer_counters(self) -> List[Any]:
+        return [l.layer_counters()[0] for l in self.layers]
+
+    def program_holders(self) -> List[StageRuntime]:
+        return list(self.layers)
+
+    # execution
+    def _through(self, inputs: Tuple, rng, run) -> Tuple[List[Tuple], Tuple]:
+        """``(each layer's input, the last layer's output)`` of ``run(layer,
+        input, rng)`` through the layers in turn."""
+        acts, layer_inputs = inputs, []
+        for i, layer in enumerate(self.layers):
+            layer_inputs.append(acts)
+            acts = run(layer, acts, self._layer_rng(rng, i))
+        return layer_inputs, acts
+
+    def forward(self, inputs: Tuple, rng) -> Tuple:
+        return self.forward_placed(
+            device_put_elided(inputs, self.device), rng)
+
+    def forward_placed(self, inputs: Tuple, rng) -> Tuple:
+        """Forward alone (evaluation): no layer's input outlives the next
+        layer's program."""
+        return self._through(inputs, rng, StageRuntime.forward_placed)[1]
+
+    def forward_saving(self, inputs: Tuple, rng) -> Tuple[Any, Tuple]:
+        """``(saved, outputs)``: ``saved`` is every layer's input, which
+        the issue loops hold until the backward (each layer's backward
+        recomputes that layer from its own input)."""
+        return self._through(inputs, rng, StageRuntime.forward_placed)
+
+    def timed_forward(self, inputs: Tuple, rng) -> Tuple:
+        return self._through(inputs, rng, StageRuntime.timed_forward)[1]
+
+    def timed_step(self, inputs: Tuple, rng, dy: Tuple):
+        layer_inputs, _ = self._through(inputs, rng,
+                                        StageRuntime.timed_forward)
+        grads: List[Any] = [None] * len(self.layers)
+        for i in reversed(range(len(self.layers))):
+            got = self.layers[i].timed_backward(
+                layer_inputs[i], self._layer_rng(rng, i), dy)
+            grads[i], dy = (
+                got if self.layers[i]._differentiable_inputs else (got, None))
+        return grads
+
+    @staticmethod
+    def _layer_rng(rng, i: int):
+        if rng is None or i == 0:
+            return rng
+        _DISPATCH_STATS["programs"] += 1
+        return _fold1(rng, i)
+
+    def backward(self, saved: List[Tuple], rng, dy: Tuple):
+        """``saved``: what ``forward_saving`` returned for the microbatch
+        (consumed: each layer's backward donates its input)."""
+        grads: List[Any] = [None] * len(self.layers)
+        for i in reversed(range(len(self.layers))):
+            (grads[i],), dy = self.layers[i].backward(
+                saved[i], self._layer_rng(rng, i), dy)
+        return grads, dy
+
+    def accumulate(self, total, grads):
+        if total is None:
+            return grads
+        return [layer.accumulate([t], [g])[0]
+                for layer, t, g in zip(self.layers, total, grads)]
+
+    def backward_accumulate(self, total, saved, rng, dy: Tuple):
+        grads, dx = self.backward(saved, rng, dy)
+        return self.accumulate(total, grads), dx
+
+    def apply_gradients(self, grads) -> None:
+        for layer, g in zip(self.layers, grads):
+            layer.apply_gradients([g])
+
+    def get_state_dict(self) -> List[Any]:
+        return [l.get_state_dict()[0] for l in self.layers]
+
+    def load_weights(self, state_dict_list: Sequence[Any]) -> None:
+        if len(state_dict_list) != self.num_layers:
+            raise ValueError(
+                f"stage {self.stage_index} holds {self.num_layers} layers, "
+                f"got {len(state_dict_list)} state dicts"
+            )
+        for layer, state in zip(self.layers, state_dict_list):
+            layer.load_weights([state])
+
+
+#: mean parameter bytes a layer from which a model's stages run one
+#: program a LAYER (``LayeredStageRuntime``) instead of one a stage.  A
+#: layer that reads tens of megabytes of weights runs for milliseconds on
+#: any batch worth a chip, so a dispatch a layer hides behind the device,
+#: and sharing executables by layer kind is what pays (compile time, cache
+#: bytes).  Under it (BERT-large's 75 units average 18 MB) the host's issue
+#: loop is the floor and a stage stays one program.  Between the two forms
+#: in the benchmark (18 MB and 242 MB a layer) the line is not measured.
+LAYER_PROGRAM_MIN_BYTES = 64 << 20
+
+
+def programs_a_layer(params_by_layer: Sequence[Any]) -> bool:
+    """Does a model with these parameter trees, a layer each, run one
+    program a layer?  By what the engine can see: their mean size."""
+    if not params_by_layer:
+        return False
+    total = sum(np.asarray(leaf).nbytes
+                for leaf in jax.tree_util.tree_leaves(list(params_by_layer)))
+    return total / len(params_by_layer) >= LAYER_PROGRAM_MIN_BYTES
 
 
 @dataclass
@@ -604,6 +935,13 @@ class PipelineStats:
     # engine collapses from O(devices) to O(stages) per microbatch tick
     program_dispatches: int = 0
     put_dispatches: int = 0
+    # expert-layer counters, totals since the stages were built.  NOT
+    # per-step: the stages accumulate them on the device and
+    # ``PipelineModel.read_counters()`` (one device_get, called by whoever
+    # wants them, outside any timed window) writes them here.
+    tokens_routed_here: int = 0
+    dropped_tokens: int = 0
+    expert_load_max_over_mean: float = 0.0
 
     #: metric classification (telemetry.MetricsRegistry contract): the
     #: model rebinds ``stats`` to a FRESH object every step, so every
@@ -614,6 +952,8 @@ class PipelineStats:
         "compute_wait_s": "gauge", "transfers": "gauge",
         "transfers_elided": "gauge", "compiles": "gauge",
         "program_dispatches": "gauge", "put_dispatches": "gauge",
+        "tokens_routed_here": "gauge", "dropped_tokens": "gauge",
+        "expert_load_max_over_mean": "gauge",
     }
 
     def snapshot(self) -> Dict[str, Any]:
@@ -699,6 +1039,9 @@ class PipelineModel:
             self._worker_manager.worker_pool, key=lambda w: w.rank
         )
         stage_idx = 0
+        stage_class = (LayeredStageRuntime
+                       if programs_a_layer(self._parameter_server.params)
+                       else StageRuntime)
         for worker in workers:
             layer_cfgs = worker.model_config or []
             if not layer_cfgs:
@@ -708,7 +1051,7 @@ class PipelineModel:
             )
             device = self._devices[worker.device_index % len(self._devices)]
             self.stages.append(
-                StageRuntime(
+                stage_class(
                     stage_index=stage_idx,
                     layer_cfgs=layer_cfgs,
                     params=params,
@@ -834,6 +1177,78 @@ class PipelineModel:
             )
         return total_loss
 
+    def _layer_counters(self) -> List[Any]:
+        return [c for stage in self.stages for c in stage.layer_counters()]
+
+    def read_counters(self) -> Dict[str, Any]:
+        """The running totals the stages' layers have sown since they were
+        built, read from the devices in ONE ``device_get`` (a host sync:
+        call it outside a timed window).  Returns ``{"expert_tokens": [one
+        int array a counting layer, in layer order], "tokens_routed_here",
+        "dropped_tokens", "expert_load_max_over_mean"}`` and writes the
+        three scalars into ``self.stats``; ``{}`` if no layer counts."""
+        held = [
+            leaf for path, leaf in jax.tree_util.tree_flatten_with_path(
+                self._layer_counters())[0] if not _is_last(path)
+        ]
+        if not held:
+            return {}
+        per_layer = [np.asarray(v) for v in jax.device_get(held)]
+        # a layer's vector: tokens to each held expert ..., routed, dropped
+        tokens = [v[:-2] for v in per_layer]
+        routed = int(sum(int(v[-2]) for v in per_layer))
+        dropped = int(sum(int(v[-1]) for v in per_layer))
+        ratios = [float(t.max() / t.mean()) for t in tokens if t.sum() > 0]
+        skew = max(ratios) if ratios else 0.0
+        self.stats.tokens_routed_here = routed
+        self.stats.dropped_tokens = dropped
+        self.stats.expert_load_max_over_mean = skew
+        return dict(expert_tokens=tokens, tokens_routed_here=routed,
+                    dropped_tokens=dropped, expert_load_max_over_mean=skew)
+
+    def last_sown(self) -> List[Dict[str, Any]]:
+        """What each layer's LAST forward sowed under a ``last_`` name (the
+        router's input and choices, the scan's inputs and final state), a
+        layer an entry in layer order, ``{}`` where a layer sows none: the
+        device arrays themselves, nothing fetched.  They are the timed
+        programs' own values: whoever holds them to a reference reads
+        them outside a timed window."""
+        def last_of(tree):
+            if not isinstance(tree, dict):
+                return {}
+            found = {}
+            for name, value in tree.items():
+                if str(name).startswith("last_"):
+                    found[name] = value
+                else:
+                    found.update(last_of(value))
+            return found
+
+        return [last_of(c) for c in self._layer_counters()]
+
+    def scoped_instructions(self, scopes: Sequence[str]):
+        """``[(program, instruction, result type, scope)]`` over the stage
+        programs that have run: which HLO instruction of which program
+        (``jit_fwd_counted``, ``jit_bwd`` ...) lies under which named
+        scope (``""``: under none of them; kept, because two programs of
+        one name can hold an instruction of one name).  Stages that share
+        programs are read once.  Costs a cache
+        load (or a compile) a program: call it outside a timed window."""
+        rows, seen = set(), set()
+        holders = [h for stage in self.stages
+                   for h in stage.program_holders()]
+        for holder in holders:
+            key = (holder.config_key, holder._differentiable_inputs)
+            if key in seen:
+                continue
+            seen.add(key)
+            for compiled in holder.compiled_programs():
+                text = compiled.as_text()
+                program = text.split(None, 2)[1].rstrip(",")
+                rows.update((program, *row) for row in
+                            scoped_instructions_of(text, scopes))
+        return sorted(rows)
+
     def _span_lanes(self):
         """(span sinks, the host lane, per-stage lane list).
 
@@ -936,10 +1351,10 @@ class PipelineModel:
                 acts = micro_data[m]
                 for k, stage in enumerate(self.stages):
                     acts = device_put_elided(acts, stage.device)
-                    stage_inputs[k].append(acts)
                     with sp.span("sky.pipe.fwd", lanes[k],
                                  {"stage": k, "mb": m}, ring="fwd"):
-                        acts = stage.forward_placed(acts, rngs[m][k])
+                        saved, acts = stage.forward_saving(acts, rngs[m][k])
+                    stage_inputs[k].append(saved)
                 final_acts_per_mb.append(acts)
         dispatch_s = time.perf_counter() - t0
         if block:
@@ -1059,10 +1474,10 @@ class PipelineModel:
             )
             with sp.span("sky.pipe.fwd_issue", host):
                 acts = device_put_elided(acts, stage.device)
-                stage_inputs[k][m] = acts
                 with sp.span("sky.pipe.fwd", lanes[k],
                              {"stage": k, "mb": m}, ring="fwd"):
-                    out = stage.forward_placed(acts, rngs[m][k])
+                    stage_inputs[k][m], out = stage.forward_saving(
+                        acts, rngs[m][k])
             if k < S - 1:
                 stage_outputs[k][m] = out
             else:
@@ -1190,7 +1605,7 @@ class PipelineModel:
         for k, stage in enumerate(self.stages):
             stage_rng = jax.random.fold_in(rng, k)
             inputs = device_put_elided(acts, stage.device)
-            out = stage._fwd(stage.params, inputs, stage_rng)
+            out = stage.timed_forward(inputs, stage_rng)
             key = (
                 stage.config_key,
                 tuple((tuple(x.shape), str(x.dtype)) for x in inputs),
@@ -1203,12 +1618,7 @@ class PipelineModel:
             dy = jax.tree_util.tree_map(jnp.zeros_like, out)
 
             def one_iter():
-                stage._fwd(stage.params, inputs, stage_rng)
-                if stage._differentiable_inputs:
-                    return stage._bwd(stage.params, inputs, stage_rng, dy)
-                return stage._bwd_params_only(
-                    stage.params, inputs, stage_rng, dy
-                )
+                return stage.timed_step(inputs, stage_rng, dy)
 
             # warm both programs
             jax.block_until_ready(one_iter())

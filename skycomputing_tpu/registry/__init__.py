@@ -20,6 +20,8 @@ class Registry:
         self._name = name
         self._registry: Dict[str, Any] = {}
         self._fallback_module = fallback_module
+        # name prefix -> module that registers such names when imported
+        self._lazy: Dict[str, str] = {}
 
     @property
     def name(self) -> str:
@@ -52,7 +54,19 @@ class Registry:
         """Non-decorator registration under an explicit name (aliases)."""
         self._registry[name] = obj
 
+    def register_lazy(self, prefix: str, module: str) -> None:
+        """Names starting with ``prefix`` are registered by importing
+        ``module``, which happens the first time one is asked for: a model
+        family nobody names costs the package's import nothing."""
+        self._lazy[prefix] = module
+
     def get_module(self, name: str) -> Any:
+        if name not in self._registry:
+            for prefix, module in self._lazy.items():
+                if name.startswith(prefix):
+                    import importlib
+
+                    importlib.import_module(module)
         if name in self._registry:
             return self._registry[name]
         if self._fallback_module is not None and hasattr(self._fallback_module, name):
@@ -99,6 +113,7 @@ class _LazyFallback:
 
 
 LAYER = Registry("layer", fallback_module=_LazyFallback(_linen))
+LAYER.register_lazy("NemotronH", "skycomputing_tpu.models.nemotron_h")
 DATASET = Registry("dataset")
 HOOKS = Registry("hooks")
 DATA_GENERATOR = Registry("data_generator")
