@@ -48,12 +48,14 @@ parameters, norms, softmax, router, decays and scan states.  Scopes in the
 traced programs: ``ssd_scan``, ``moe_route``, ``moe_experts``,
 ``shared_expert``, ``gqa_attn``.  Counters: an ``E`` block sows
 ``counters/moe`` = ``[tokens to each held expert ..., pairs routed here,
-pairs dropped]`` (int32), which the pipeline engine accumulates on the
-device (``PipelineModel.read_counters``), and ``last_route`` (the router's
-input and choices); an ``M`` block sows ``last_scan`` (the scan's inputs
-and final state of the call's last sequence).  Of a ``last_`` name the
-engine keeps the latest call's (``PipelineModel.last_sown``): the values
-the timed programs themselves computed, for a reference to be held to.
+pairs dropped]`` and ``counters/moe_blocks`` = ``[blocks of the expert
+order the dropless layer walked, calls]`` (int32), which the pipeline
+engine accumulates on the device (``PipelineModel.read_counters``), and
+``last_route`` (the router's input and choices); an ``M`` block sows
+``last_scan`` (the scan's inputs and final state of the call's last
+sequence).  Of a ``last_`` name the engine keeps the latest call's
+(``PipelineModel.last_sown``): the values the timed programs themselves
+computed, for a reference to be held to.
 """
 
 from __future__ import annotations
@@ -297,16 +299,19 @@ class MoeMixer(nn.Module):
         idx, weights = moe_route(tokens, gate, bias, cfg)
         # the router's input and choices of the call, for the same reason
         _sow_last(self, "last_route", dict(tokens=tokens, idx=idx))
-        with jax.named_scope("moe_experts"):
-            w_up = self.param("experts_up", init, (E, d, f), jnp.float32)
-            w_down = self.param("experts_down", init, (E, f, d), jnp.float32)
-            routed, counts = dropless_experts(
-                tokens, idx, weights, w_up, w_down,
-                held_start=cfg.experts_held_start,
-            )
-        self.sow("counters", "moe", counts,
-                 init_fn=lambda: jnp.zeros_like(counts),
-                 reduce_fn=lambda total, new: total + new)
+        w_up = self.param("experts_up", init, (E, d, f), jnp.float32)
+        w_down = self.param("experts_down", init, (E, f, d), jnp.float32)
+        # opens the scope ``moe_experts`` itself, inside its loop's body
+        routed, counts, blocks = dropless_experts(
+            tokens, idx, weights, w_up, w_down,
+            held_start=cfg.experts_held_start,
+            num_experts=cfg.n_routed_experts,
+        )
+        walked = jnp.stack([blocks, jnp.ones_like(blocks)])
+        for name, value in (("moe", counts), ("moe_blocks", walked)):
+            self.sow("counters", name, value,
+                     init_fn=lambda value=value: jnp.zeros_like(value),
+                     reduce_fn=lambda total, new: total + new)
         with jax.named_scope("shared_expert"):
             hidden = relu2(_linear(
                 self, "shared_up", tokens,

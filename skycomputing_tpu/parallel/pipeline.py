@@ -942,6 +942,11 @@ class PipelineStats:
     tokens_routed_here: int = 0
     dropped_tokens: int = 0
     expert_load_max_over_mean: float = 0.0
+    # calls of the dropless expert layer and the blocks of the expert order
+    # they walked (``ops/moe_dropless.py``: 1 a call when the pairs routed
+    # here fit one block)
+    moe_blocks: int = 0
+    moe_calls: int = 0
 
     #: metric classification (telemetry.MetricsRegistry contract): the
     #: model rebinds ``stats`` to a FRESH object every step, so every
@@ -954,6 +959,7 @@ class PipelineStats:
         "program_dispatches": "gauge", "put_dispatches": "gauge",
         "tokens_routed_here": "gauge", "dropped_tokens": "gauge",
         "expert_load_max_over_mean": "gauge",
+        "moe_blocks": "gauge", "moe_calls": "gauge",
     }
 
     def snapshot(self) -> Dict[str, Any]:
@@ -1185,26 +1191,36 @@ class PipelineModel:
         built, read from the devices in ONE ``device_get`` (a host sync:
         call it outside a timed window).  Returns ``{"expert_tokens": [one
         int array a counting layer, in layer order], "tokens_routed_here",
-        "dropped_tokens", "expert_load_max_over_mean"}`` and writes the
-        three scalars into ``self.stats``; ``{}`` if no layer counts."""
-        held = [
-            leaf for path, leaf in jax.tree_util.tree_flatten_with_path(
-                self._layer_counters())[0] if not _is_last(path)
-        ]
-        if not held:
+        "dropped_tokens", "expert_load_max_over_mean", "moe_blocks",
+        "moe_calls"}`` and writes the five scalars into ``self.stats``;
+        ``{}`` if no layer counts."""
+        sown: Dict[str, List[Any]] = {"moe": [], "moe_blocks": []}
+        for path, leaf in jax.tree_util.tree_flatten_with_path(
+                self._layer_counters())[0]:
+            name = str(getattr(path[-1], "key", ""))
+            if name in sown and not _is_last(path):
+                sown[name].append(leaf)
+        if not sown["moe"]:
             return {}
-        per_layer = [np.asarray(v) for v in jax.device_get(held)]
+        sown = {name: [np.asarray(v) for v in values]
+                for name, values in jax.device_get(sown).items()}
         # a layer's vector: tokens to each held expert ..., routed, dropped
-        tokens = [v[:-2] for v in per_layer]
-        routed = int(sum(int(v[-2]) for v in per_layer))
-        dropped = int(sum(int(v[-1]) for v in per_layer))
+        tokens = [v[:-2] for v in sown["moe"]]
+        routed = int(sum(int(v[-2]) for v in sown["moe"]))
+        dropped = int(sum(int(v[-1]) for v in sown["moe"]))
         ratios = [float(t.max() / t.mean()) for t in tokens if t.sum() > 0]
         skew = max(ratios) if ratios else 0.0
+        # a layer's pair: blocks walked, calls
+        blocks, calls = (int(sum(int(v[i]) for v in sown["moe_blocks"]))
+                         for i in (0, 1))
         self.stats.tokens_routed_here = routed
         self.stats.dropped_tokens = dropped
         self.stats.expert_load_max_over_mean = skew
+        self.stats.moe_blocks = blocks
+        self.stats.moe_calls = calls
         return dict(expert_tokens=tokens, tokens_routed_here=routed,
-                    dropped_tokens=dropped, expert_load_max_over_mean=skew)
+                    dropped_tokens=dropped, expert_load_max_over_mean=skew,
+                    moe_blocks=blocks, moe_calls=calls)
 
     def last_sown(self) -> List[Dict[str, Any]]:
         """What each layer's LAST forward sowed under a ``last_`` name (the

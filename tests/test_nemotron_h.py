@@ -102,16 +102,93 @@ def test_shares_of_the_experts_add_up_to_the_uncut_layer():
     assert close(total.reshape(uncut.shape), uncut, 2e-5)
 
 
-# -- (iv) dropless under skew -----------------------------------------------
+# -- (iv) dropless at every load, and what it lowers to ----------------------
 
+def dense_experts(tokens, idx, w, w_up, w_down, held_start):
+    """Every held expert over every token, weighted where it was chosen."""
+    want = jnp.zeros((tokens.shape[0], w_down.shape[2]))
+    for e in range(w_up.shape[0]):
+        weight = jnp.where(idx == held_start + e, w, 0.0).sum(1)
+        want = want + weight[:, None] * (
+            jnp.square(jax.nn.relu(tokens @ w_up[e])) @ w_down[e])
+    return want
+
+
+#: first choices ``[(expert, tokens) ...]`` of 256 tokens choosing 2 of 32
+#: experts, of which 4..7 are held: a block is 128 rows of 512.  The other
+#: tokens, and every second choice but ``every``'s, go to absent experts.
+LOADS = {
+    "no_pair_held": ([], 0),
+    "under_one_block": ([(4, 60), (6, 40)], 1),
+    "whole_blocks": ([(5, 128), (7, 128)], 2),
+    "every_pair_held": (None, 4),
+    "straddles_an_edge": ([(4, 20), (5, 200), (7, 10)], 2),
+}
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
+@pytest.mark.parametrize("load", list(LOADS))
+def test_dropless_equals_dense_experts_at_every_load(load, impl):
+    from skycomputing_tpu.ops.moe_dropless import block_rows
+
+    T, k, d, f, E, total, start = 256, 2, 32, 48, 4, 32, 4
+    assert block_rows(T, k, E, total) == 128
+    ks = jax.random.split(jax.random.key(11), 5)
+    tokens = jax.random.normal(ks[0], (T, d))
+    w_up = 0.1 * jax.random.normal(ks[1], (E, d, f))
+    w_down = 0.1 * jax.random.normal(ks[2], (E, f, d))
+    firsts, blocks = LOADS[load]
+    t = jnp.arange(T)
+    if firsts is None:
+        idx = jnp.stack([start + t % E, start + (t + 1) % E], 1)
+    else:
+        first = jnp.full(T, 0)
+        at = 0
+        for expert, count in firsts:
+            first = first.at[at:at + count].set(expert)
+            at += count
+        idx = jnp.stack([first, jnp.full(T, 9)], 1)
+    idx = idx.astype(jnp.int32)
+    w = jax.random.uniform(ks[3], (T, k), minval=0.2, maxval=1.5)
+    g = jax.random.normal(ks[4], (T, d))
+    held = int(((idx >= start) & (idx < start + E)).sum())
+    assert -(-held // 128) == blocks
+
+    def program(tokens, w, w_up, w_down):
+        return dropless_experts(tokens, idx, w, w_up, w_down,
+                                held_start=start, num_experts=total,
+                                impl=impl)
+
+    y, counts, walked = jax.jit(program)(tokens, w, w_up, w_down)
+    assert int(walked) == blocks
+    assert int(counts[-2]) == held and int(counts[-1]) == 0
+    assert counts[:E].tolist() == [
+        int((idx == start + e).sum()) for e in range(E)]
+    assert close(y, dense_experts(tokens, idx, w, w_up, w_down, start), 2e-5)
+    got = jax.jit(jax.grad(
+        lambda *inputs: (program(*inputs)[0] * g).sum(),
+        argnums=(0, 1, 2, 3)))(tokens, w, w_up, w_down)
+    want = jax.grad(
+        lambda *inputs: (dense_experts(inputs[0], idx, *inputs[1:], start)
+                         * g).sum(),
+        argnums=(0, 1, 2, 3))(tokens, w, w_up, w_down)
+    for a, b in zip(got, want):
+        if held:
+            assert close(a, b, 2e-5)
+        else:
+            assert not a.any() and not b.any()
+
+
+@pytest.mark.parametrize("T,total", [(64, 8), (256, 32)])
 def test_dropped_counts_the_rows_the_grouped_product_did_not_write(
-        monkeypatch):
+        monkeypatch, T, total):
     """``dropped_tokens`` is read off the product's result: a product that
     skips an expert's rows (here: the last held expert's) shows as that
-    many pairs dropped, whatever ``group_sizes`` said it was given."""
+    many pairs dropped, whatever ``group_sizes`` said it was given; in the
+    one block that is the whole order (64 tokens) and in a loop's."""
     from skycomputing_tpu.ops import moe_dropless
 
-    T, k, d, f, E, total = 64, 2, 32, 48, 4, 8
+    k, d, f, E = 2, 32, 48, 4
     ks = jax.random.split(jax.random.key(9), 4)
     tokens = jax.random.normal(ks[0], (T, d))
     idx, w = route_top_k(jax.random.normal(ks[1], (T, total)),
@@ -124,15 +201,15 @@ def test_dropped_counts_the_rows_the_grouped_product_did_not_write(
     def skips_the_last_expert(lhs, rhs, sizes, *, impl=None):
         return real(lhs, rhs, sizes.at[E - 1].set(0), impl=impl)
 
-    _, sound = dropless_experts(tokens, idx, w, w_up, w_down, held_start=2,
-                                impl="xla")
+    _, sound, _ = dropless_experts(tokens, idx, w, w_up, w_down, held_start=2,
+                                   num_experts=total, impl="xla")
     monkeypatch.setattr(moe_dropless, "grouped_matmul", skips_the_last_expert)
-    _, faulty = dropless_experts(tokens, idx, w, w_up, w_down, held_start=2,
-                                 impl="xla")
+    _, faulty, _ = dropless_experts(tokens, idx, w, w_up, w_down,
+                                    held_start=2, num_experts=total,
+                                    impl="xla")
     assert int(sound[-1]) == 0 and int(sound[E - 1]) > 0
     assert int(faulty[-1]) >= int(sound[E - 1])
     assert int(faulty[-2]) == int(sound[-2])
-
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
@@ -146,18 +223,48 @@ def test_dropless_under_skew_drops_nothing(impl):
                          scaling_factor=2.5)
     w_up = 0.1 * jax.random.normal(ks[2], (E, d, f))
     w_down = 0.1 * jax.random.normal(ks[3], (E, f, d))
-    y, counts = dropless_experts(tokens, idx, w, w_up, w_down, held_start=4,
-                                 impl=impl)
+    y, counts, blocks = dropless_experts(
+        tokens, idx, w, w_up, w_down, held_start=4, num_experts=total,
+        impl=impl)
     assert int(counts[1]) == T                   # expert 5 is local row 1
     held = (idx >= 4) & (idx < 8)
     assert int(counts[-2]) == int(held.sum()) and int(counts[-1]) == 0
     assert int(counts[:E].sum()) == int(held.sum())
-    want = jnp.zeros((T, d))
-    for e in range(E):
-        weight = jnp.where(idx == 4 + e, w, 0.0).sum(1)
-        want = want + weight[:, None] * (
-            jnp.square(jax.nn.relu(tokens @ w_up[e])) @ w_down[e])
-    assert close(y, want, 2e-5)
+    assert int(blocks) == 2                      # 128 rows a block, of 256
+    assert close(y, dense_experts(tokens, idx, w, w_up, w_down, 4), 2e-5)
+
+
+def test_expert_programs_hold_one_body_and_no_worst_case_buffer():
+    """The expert part lowered for a TPU at the benchmark cell's shapes
+    (nothing runs): the gradient program holds the six grouped products of
+    ONE block (two recomputed, two for the rows, two transposed for the
+    matrices) and the forward program two, neither a ``case`` (no second
+    arm), neither an array of ``T x k`` rows of width ``d`` or ``f``."""
+    T, k, d, f, E, total = 4096, 6, 2688, 1856, 8, 128
+    shape = jax.ShapeDtypeStruct
+    inputs = (shape((T, d), jnp.bfloat16), shape((T, k), jnp.float32),
+              shape((E, d, f), jnp.float32), shape((E, f, d), jnp.float32))
+    idx = shape((T, k), jnp.int32)
+
+    def forward(idx, *inputs):
+        tokens, *rest = inputs
+        return dropless_experts(tokens, idx, *rest, held_start=0,
+                                num_experts=total, impl="pallas")
+
+    def gradient(idx, g, *inputs):
+        _, pull = jax.vjp(lambda *x: forward(idx, *x)[0], *inputs)
+        return pull(g)
+
+    lowered = lambda fn, *args: jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+    for text, products in (
+            (lowered(forward, idx, *inputs), 2),
+            (lowered(gradient, idx, shape((T, d), jnp.float32), *inputs), 6)):
+        assert text.count("tpu_custom_call") == products
+        assert "stablehlo.case" not in text
+        assert "stablehlo.while" in text
+        for width in (d, f):
+            assert f"tensor<{T * k}x{width}x" not in text
 
 
 def test_reference_step_by_layers_equals_its_value_and_grad():

@@ -187,9 +187,14 @@ def test_pipeline_step_equals_whole_stack_gradient(devices, monkeypatch,
     assert counters["tokens_routed_here"] == int(held.sum()) > 0
     assert counters["dropped_tokens"] == 0
     assert model.stats.tokens_routed_here == counters["tokens_routed_here"]
+    # a call a microbatch, and the one block its 128 pairs fit
+    assert counters["moe_calls"] == counters["moe_blocks"] == 2
+    assert model.stats.moe_calls == model.stats.moe_blocks == 2
     # a second pass adds to them: they are totals, never reset by a step
     model.compute_gradients((ids,), ids, jax.random.key(1))
-    assert model.read_counters()["tokens_routed_here"] == 2 * int(held.sum())
+    again = model.read_counters()
+    assert again["tokens_routed_here"] == 2 * int(held.sum())
+    assert again["moe_calls"] == again["moe_blocks"] == 4
 
     # a whole step, and the way from a trace's instruction to its scope
     before = jax.device_get(model.stages[0].params)
@@ -205,6 +210,35 @@ def test_pipeline_step_equals_whole_stack_gradient(devices, monkeypatch,
     # one program a layer: layers of one kind share their programs
     if layer_programs:
         assert [len(s.layers) for s in model.stages] == [3, 2]
+
+
+def test_a_pipeline_whose_layers_count_nothing_reads_no_counters(devices):
+    from skycomputing_tpu.models import bert_config, bert_layer_configs
+    from skycomputing_tpu.ops import cross_entropy_loss
+
+    model_cfg = bert_layer_configs(
+        bert_config("tiny", dtype="float32"), num_encoder_units=2,
+        num_classes=3, deterministic=True)
+    manager = WorkerManager()
+    manager.load_worker_pool_from_config([
+        dict(name=f"w{i}", device_config=dict(device_index=i),
+             extra_config=dict(slowdown=1.0)) for i in range(2)
+    ])
+    half = len(model_cfg) // 2
+    for worker, units in zip(manager.worker_pool,
+                             [model_cfg[:half], model_cfg[half:]]):
+        worker.model_config = units
+        worker.order = worker.rank
+    ids = np.full((4, 16), 7, np.int32)
+    inputs = (ids, np.zeros_like(ids), np.ones_like(ids))
+    server = ParameterServer(model_cfg, example_inputs=inputs,
+                             rng=jax.random.key(0))
+    model = PipelineModel(manager, server, optax.sgd(1e-2),
+                          cross_entropy_loss, devices=devices,
+                          num_microbatches=2)
+    model.train_step(inputs, np.zeros(4, np.int32), jax.random.key(1))
+    assert model.read_counters() == {}
+    assert model.stats.moe_calls == model.stats.moe_blocks == 0
 
 
 # -- the engine's two stage forms, and what they keep ------------------------
