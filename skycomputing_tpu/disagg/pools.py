@@ -261,6 +261,9 @@ class DisaggFleet(ServingFleet):
                     or replica.crashed or replica.engine is None):
                 continue
             engine = replica.engine
+            # a first token is read back a step after its prefill: hand
+            # off as soon as it exists, not a decode tick later
+            engine.flush()
             ready = [rid for rid, r in engine._running.items()
                      if r.tokens and not r.done]
             for rid in ready:

@@ -104,8 +104,34 @@ from .speculative import (
 )
 
 
-# one compiled gather/argmax pair per (batch, vocab) shape — module-level
-# jits so every engine instance shares the executables
+# module-level jits, so every engine instance shares the executables (one
+# compile per shape).  The greedy path never takes logits off the chip:
+# each prefill wave and decode tick takes its argmax straight into the
+# engine's next-token vector (``[rows, 1]`` int32, the decode program's
+# input as it stands) at the rows it served: a tick's are those with
+# ``valid > 0``, a wave's are ``at[1]`` (out of range for a pad row, which
+# drops), read at each wave row's last position ``at[0]``.  _write_next
+# writes tokens the host chose (a sampled token, a resumed row's last);
+# _gather_last and _argmax_tokens serve the paths that read logits on the
+# host (temperature sampling, the speculative verify)
+_next_of_decode = jax.jit(
+    lambda logits, valid, nxt: jnp.where(
+        valid[:, None] > 0,
+        jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)[:, None],
+        nxt,
+    )
+)
+_next_of_prefill = jax.jit(
+    lambda logits, at, nxt: nxt.at[at[1], 0].set(
+        jnp.argmax(
+            logits[jnp.arange(logits.shape[0]), at[0]], axis=-1
+        ).astype(jnp.int32),
+        mode="drop",
+    )
+)
+_write_next = jax.jit(
+    lambda nxt, at: nxt.at[at[0], 0].set(at[1], mode="drop")
+)
 _gather_last = jax.jit(
     lambda logits, pos: logits[jnp.arange(logits.shape[0]), pos, :]
 )
@@ -220,12 +246,20 @@ class ServingStats:
     handoffs_in: int = 0
     handoff_failures: int = 0
     handoff_bytes: int = 0
+    # one program in flight across steps (docs/serving.md): ticks_in_flight
+    # counts decode ticks dispatched while a program of an earlier step was
+    # still unread; retires counts reads forced before their time by a
+    # path that needs token values on the host (sampling, preemption,
+    # speculation, chunked prefill, handoff, reconfigure, flush)
+    ticks_in_flight: int = 0
+    retires: int = 0
     # gauges
     queue_depth: int = 0
     batch_occupancy: float = 0.0
     pages_in_use: int = 0
     free_pages: int = 0
-    # blocked wall time per phase (timed across block_until_ready)
+    # host wall time inside each phase's programs: the dispatch, plus the
+    # wait for what is read back of them (a step later)
     prefill_s: float = 0.0
     decode_s: float = 0.0
     # per-request SLO samples
@@ -259,6 +293,7 @@ class ServingStats:
         "spec_rollbacks": "counter",
         "handoffs_out": "counter", "handoffs_in": "counter",
         "handoff_failures": "counter", "handoff_bytes": "counter",
+        "ticks_in_flight": "counter", "retires": "counter",
         "queue_depth": "gauge", "batch_occupancy": "gauge",
         "pages_in_use": "gauge", "free_pages": "gauge",
         "tokens_per_s": "gauge",
@@ -314,6 +349,8 @@ class ServingStats:
             handoffs_in=self.handoffs_in,
             handoff_failures=self.handoff_failures,
             handoff_bytes=self.handoff_bytes,
+            ticks_in_flight=self.ticks_in_flight,
+            retires=self.retires,
             queue_depth=self.queue_depth,
             batch_occupancy=self.batch_occupancy,
             pages_in_use=self.pages_in_use,
@@ -326,6 +363,24 @@ class ServingStats:
             tpot_p50_s=pct(self.tpot_s, 50),
             tpot_p95_s=pct(self.tpot_s, 95),
         )
+
+
+@dataclass
+class _Dispatched:
+    """A program whose tokens the host has not read back yet.
+
+    ``out`` is the next-token vector the program returned; ``served``
+    holds ``(decode row, logits row, request)`` for each token it made;
+    ``logits`` (``[program rows, vocab]``) is kept only where a served
+    request samples.  ``kind`` says which of ``prefill_s`` / ``decode_s``
+    the wait for it adds to, ``step`` the engine iteration that
+    dispatched it."""
+
+    kind: str
+    step: int
+    out: Any
+    served: List[Any]
+    logits: Any = None
 
 
 # small page-slab utilities, module-level jits so every engine shares
@@ -782,6 +837,12 @@ class ServingEngine(LiveMetricsMixin):
         # end of their effective prompt (insertion order = enrollment
         # FIFO, which chunk waves honor head-first)
         self._prefilling: Dict[int, Request] = {}
+        # request_id -> tokens dispatched and not yet read back (a request
+        # with every token dispatched leaves _running and holds no row
+        # or pages; the program in flight finishes it when it is read)
+        self._owed: Dict[int, int] = {}
+        # programs dispatched and not yet read back, in issue order
+        self._inflight: List[_Dispatched] = []
         self._finished: List[Request] = []
         # closed-loop tuning: when set (tuning.ServingAutotuner attaches
         # itself here), every step ends with an observe/decide callback —
@@ -873,6 +934,10 @@ class ServingEngine(LiveMetricsMixin):
             self.stages.append(stage)
             cursor += n
         self._last_device = self.stages[-1].device
+        # each row's next token, on the device: the decode tick's input
+        self._next = jax.device_put(
+            np.zeros((self.max_concurrency, 1), np.int32), self._last_device
+        )
         if self.spec_k > 0:
             self._draft = self._build_draft()
             # one source of truth for the resident charge (the
@@ -1202,7 +1267,12 @@ class ServingEngine(LiveMetricsMixin):
         request's pages over the host link twice; a resume prefix that
         has outgrown every bucket forces swap — the case recomputation
         structurally cannot serve.
+
+        The program in flight is read back first: the resume prefix is
+        the request's tokens, and a request whose last token it held is
+        finished, not running (``KeyError``).
         """
+        self._retire()
         request = self._running.get(request_id)
         prefilling = False
         if request is None:
@@ -1327,6 +1397,7 @@ class ServingEngine(LiveMetricsMixin):
         this one's host pool), so migration resumes by re-prefilling
         the effective prompt — and any swap records held for queued
         requests are dropped with the same consequence."""
+        self._retire()
         for request_id in list(self._running) + list(self._prefilling):
             try:
                 # cross-engine resume is recompute by construction
@@ -1416,6 +1487,7 @@ class ServingEngine(LiveMetricsMixin):
         front door) owns delivering the pair and conserving it in a
         ledger — after this returns, this engine holds NO state for the
         request."""
+        self._retire()
         request = self._running.get(request_id)
         if request is None:
             raise KeyError(
@@ -1538,12 +1610,18 @@ class ServingEngine(LiveMetricsMixin):
         """Requests waiting for admission, FIFO order (read-only view)."""
         return list(self._queue.requests)
 
-    def _finish(self, request: Request, now: float) -> None:
+    def _release(self, request: Request) -> None:
         self._rows.release(request.slot)
         # pages the radix index still references survive the release —
         # the prefix cache's retention, not a leak
         self._pool.release(request.request_id)
         request.slot = None
+
+    def _finish(self, request: Request, now: float) -> None:
+        """Terminal bookkeeping once every token is on the host (the row
+        and pages went when the last one was dispatched, or go here)."""
+        if request.slot is not None:
+            self._release(request)
         request.status = FINISHED
         request.finished_s = now
         self._running.pop(request.request_id, None)
@@ -1573,17 +1651,29 @@ class ServingEngine(LiveMetricsMixin):
 
     # --- the continuous-batching loop ---------------------------------------
     def has_work(self) -> bool:
+        """True while anything is queued, seated, or in flight on the
+        chip (a program not yet read back)."""
         return (bool(self._running) or bool(self._prefilling)
-                or self._queue.depth > 0)
+                or self._queue.depth > 0 or bool(self._inflight))
 
     def step(self) -> None:
         """One engine iteration: admit prefill waves (or, with
         ``prefill_chunk`` set, enroll admissions and advance at most a
-        budgeted number of prefill chunks), then one decode tick over
-        every row.  Requests join and leave the running batch only
-        here, between decode steps — iteration-level scheduling; the
-        chunk budget bounds how much prefill any single decode tick
-        can wait behind."""
+        budgeted number of prefill chunks), then dispatch one decode
+        tick over every row, then read back the programs the PREVIOUS
+        step dispatched and commit their tokens.  Requests join and
+        leave the running batch only here, between decode steps —
+        iteration-level scheduling; the chunk budget bounds how much
+        prefill any single decode tick can wait behind.
+
+        Nothing the host decides needs a greedy token's value (a
+        request is done at ``max_new_tokens``), so the tick is built
+        from counts and takes its tokens from the chip, and the host's
+        work of this step runs while the last step's programs do.  A
+        request's tokens reach ``Request.tokens`` one step after the
+        program that made them; ``status`` turns ``FINISHED`` only when
+        all of them have.  Paths that need token values on the host
+        read back first (``_retire``)."""
         sp = self._sp = span_sinks()
         eng = self._eng_lane = sp.lane("serving", "engine")
         with sp.span("sky.serve.step", eng,
@@ -1603,6 +1693,10 @@ class ServingEngine(LiveMetricsMixin):
                 self._spec_tick()
             else:
                 self._decode_tick()
+            self._read_back(before=self.stats.iterations)
+            if not (self._running or self._prefilling or self._queue.depth):
+                # nothing to dispatch next step: nothing to overlap
+                self._read_back()
             with sp.span("sky.serve.sync", eng):
                 self.stats.iterations += 1
                 self.stats.queue_depth = self._queue.depth
@@ -1612,6 +1706,79 @@ class ServingEngine(LiveMetricsMixin):
                     self.timeseries.sample()
                 if self.autotuner is not None:
                     self.autotuner.on_step(self)
+
+    def flush(self) -> None:
+        """Read back every program in flight and commit its tokens, so
+        that every request's ``tokens`` and ``status`` are up to date
+        now rather than after the next ``step()`` (``_retire`` made
+        public; counted in ``stats.retires`` when it reads anything)."""
+        self._retire()
+
+    def _retire(self) -> None:
+        """What a path that needs token values on the host calls first:
+        sampling, preemption, swap-out, handoff export, speculation,
+        chunked prefill, reconfigure."""
+        if self._inflight:
+            self.stats.retires += 1
+            self._read_back()
+
+    def _read_back(self, before: Optional[int] = None) -> None:
+        """Read the tokens of the programs dispatched before engine
+        iteration ``before`` (all of them when None) and commit them, in
+        issue order: one ``sky.serve.run`` > ``sky.serve.wait`` around
+        the reads (each wait adds to its program's ``prefill_s`` or
+        ``decode_s``), then ``sky.serve.commit``."""
+        n = len(self._inflight) if before is None else sum(
+            1 for p in self._inflight if p.step < before
+        )
+        if not n:
+            return
+        batch, self._inflight = self._inflight[:n], self._inflight[n:]
+        sp = span_sinks()
+        eng = sp.lane("serving", "engine")
+        with sp.span("sky.serve.retire", eng, {"programs": n}):
+            read = []
+            with sp.span("sky.serve.run", eng):
+                with sp.span("sky.serve.wait", eng):
+                    for p in batch:
+                        t0 = time.perf_counter()
+                        read.append(np.asarray(p.out))
+                        waited = time.perf_counter() - t0
+                        if p.kind == "decode":
+                            self.stats.decode_s += waited
+                        else:
+                            self.stats.prefill_s += waited
+            now = time.perf_counter()
+            with sp.span("sky.serve.commit", eng):
+                for p, tokens in zip(batch, read):
+                    self._commit(p, tokens, now)
+
+    def _commit(self, program: _Dispatched, tokens: np.ndarray,
+                now: float) -> None:
+        """One read-back program's tokens onto its requests' streams."""
+        sampled = {}
+        if program.logits is not None:
+            sampled = self._sampled_rows(
+                program.logits, [(i, r) for _, i, r in program.served]
+            )
+        chosen = []
+        for row, i, r in program.served:
+            greedy = int(tokens[row, 0])
+            tok = self._pick_token(r, greedy, sampled.get(i))
+            r.tokens.append(tok)
+            owed = self._owed.pop(r.request_id) - 1
+            if owed:
+                self._owed[r.request_id] = owed
+            if r.first_token_s is None:
+                r.first_token_s = now
+            self.stats.generated_tokens += 1
+            if program.kind == "decode":
+                self.stats.decode_tokens += 1
+            if r.done:
+                self._finish(r, now)
+            elif tok != greedy:
+                chosen.append((row, tok))  # the next tick's input
+        self._set_next(chosen)
 
     def _sync_paged_stats(self) -> None:
         """Mirror the page pool's counters/gauges into ``ServingStats``
@@ -1691,6 +1858,8 @@ class ServingEngine(LiveMetricsMixin):
         """
         from ..analysis.plan_check import verify_tuning_knobs
 
+        # resume prefixes are tokens: read the program in flight first
+        self._retire()
         if buckets is not None:
             # same normalization the constructor's ShapeBucketer applies,
             # so reconfigure accepts exactly the inputs construction
@@ -1939,6 +2108,10 @@ class ServingEngine(LiveMetricsMixin):
                 st.pool = self._rows
             self.max_concurrency = new_rows
             self.num_slots = new_rows
+            # every row was evicted: re-admission writes each one
+            self._next = jax.device_put(
+                np.zeros((new_rows, 1), np.int32), self._last_device
+            )
         self.bucketer = new_bucketer
         self.prefill_batch = new_batch
         self.prefill_chunk = new_chunk
@@ -2210,64 +2383,44 @@ class ServingEngine(LiveMetricsMixin):
                 width = self._table_width(valid)
                 tables = tables[:, :width]
                 self._count_quant(index, valid, width, len(wave))
-            t0 = time.perf_counter()
+            # final chunk: the last true position's logits seed the
+            # first generated token, exactly like a one-shot wave
+            finals = [
+                (r.slot, i, r) for i, r in enumerate(wave)
+                if r.prefilled_len + int(chunks[i].size)
+                >= int(r.effective_prompt.size)
+            ]
+            t_dispatch = time.perf_counter()
             compiles0 = xla_compile_count()
             run_args = wave_args if tracer is None else dict(
                 wave_args, requests=[r.request_id for r in wave])
             with sp.span("sky.serve.run", eng, run_args,
                          ring="prefill") as run:
-                data = self._run_stages(
+                data, _ = self._run_stages(
                     ids, tables, index, valid, "prefill",
                     {"bucket": bucket, "chunk": True},
                 )
-                pos = device_put_elided(lengths - 1, self._last_device)
-                logits = _gather_last(data, pos)  # [rows, V]
-                tokens = _argmax_tokens(logits)
-                with sp.span("sky.serve.wait", eng):
-                    jax.block_until_ready(tokens)
-            now = time.perf_counter()
-            self.stats.prefill_s += now - t0
-            with sp.span("sky.serve.commit", eng):
-                self.stats.prefill_waves += 1
-                self.stats.prefill_tokens += wave_tokens
-                self.stats.prefill_chunks += len(wave)
-                self.stats.compiles += xla_compile_count() - compiles0
-
-                finals = [
-                    (i, r) for i, r in enumerate(wave)
-                    if r.prefilled_len + int(chunks[i].size)
-                    >= int(r.effective_prompt.size)
-                ]
-                tokens_np = np.asarray(tokens)
-                sampled = self._sampled_rows(logits, finals)
-                for i, r in enumerate(wave):
-                    clen = int(chunks[i].size)
-                    r.prefilled_len += clen
-                    if r.prefilled_len < int(r.effective_prompt.size):
-                        continue  # watermark advanced; more chunks to come
-                    # final chunk: the last true position's logits seed
-                    # the first generated token, exactly like a one-shot
-                    # wave
-                    self._prefilling.pop(r.request_id)
-                    self._pool.register_prefix(
-                        r.request_id, [int(t) for t in r.prompt]
-                    )
-                    tok = self._pick_token(r, tokens_np[i], sampled.get(i))
-                    r.tokens.append(tok)
-                    r.index = r.prefilled_len
-                    r.prefilled_len = 0
-                    r.status = RUNNING
-                    self._running[r.request_id] = r
-                    if r.first_token_s is None:
-                        r.first_token_s = now
-                    self.stats.generated_tokens += 1
-                    if tracer is not None:
-                        self._trace_close_prefill(
-                            r, tracer, end_us=run.end_us, bucket=bucket,
-                            slot=r.slot)
-                        r.trace_marks["decode"] = run.end_us
-                    if r.done:
-                        self._finish(r, now)
+                if finals:
+                    self._dispatch_next_of_prefill(data, lengths, finals)
+            self.stats.prefill_s += time.perf_counter() - t_dispatch
+            self.stats.prefill_waves += 1
+            self.stats.prefill_tokens += wave_tokens
+            self.stats.prefill_chunks += len(wave)
+            self.stats.compiles += xla_compile_count() - compiles0
+            for i, r in enumerate(wave):
+                r.prefilled_len += int(chunks[i].size)
+                if r.prefilled_len < int(r.effective_prompt.size):
+                    continue  # watermark advanced; more chunks to come
+                self._prefilling.pop(r.request_id)
+                index, r.prefilled_len = r.prefilled_len, 0
+                if tracer is not None:
+                    self._trace_close_prefill(
+                        r, tracer, end_us=run.end_us, bucket=bucket,
+                        slot=r.slot)
+                    r.trace_marks["decode"] = run.end_us
+                self._start_decode(r, index)
+        # chunked prefill runs as it always has: read back at once
+        self._retire()
 
     @staticmethod
     def _effective_tokens(request: Request) -> tuple:
@@ -2400,72 +2553,112 @@ class ServingEngine(LiveMetricsMixin):
                         for st in self.stages:
                             st.apply_cow_plan(plan)
 
-            t0 = time.perf_counter()
+            t_dispatch = time.perf_counter()
             compiles0 = xla_compile_count()
             run_args = wave_args if tracer is None else dict(
                 wave_args, requests=[r.request_id for r, _ in wave])
             with sp.span("sky.serve.run", eng, run_args,
                          ring="prefill") as run:
-                data = self._run_stages(
+                data, _ = self._run_stages(
                     ids, tables, index, valid, "prefill",
                     {"bucket": bucket},
                 )
-                pos = device_put_elided(lengths - 1, self._last_device)
-                logits = _gather_last(data, pos)  # [rows, V]
-                tokens = _argmax_tokens(logits)
-                with sp.span("sky.serve.wait", eng):
-                    jax.block_until_ready(tokens)
-            now = time.perf_counter()
-            self.stats.prefill_s += now - t0
-            with sp.span("sky.serve.commit", eng):
-                if tracer is not None:
-                    for r, g in wave:
-                        tracer.instant(
-                            "admit", eng,
-                            {"request": r.request_id, "slot": r.slot,
-                             "pages": len(g.page_table),
-                             "shared": g.shared_tokens},
-                        )
-                        self._trace_close_queue(r, tracer,
-                                                end_us=run.start_us)
-                        lane = tracer.request_lane(r.request_id,
-                                                   lease=False)
-                        if lane is not None:
-                            tracer.complete(
-                                "prefill", lane, run.start_us,
-                                {"request": r.request_id,
-                                 "replica": self.trace_name,
-                                 "bucket": bucket, "slot": r.slot,
-                                 "shared": g.shared_tokens},
-                                dur_us=run.end_us - run.start_us,
-                            )
-                        r.trace_marks["decode"] = run.end_us
-                self.stats.prefill_waves += 1
-                self.stats.prefill_tokens += wave_tokens
-                self.stats.compiles += xla_compile_count() - compiles0
-
-                tokens_np = np.asarray(tokens)
-                sampled = self._sampled_rows(
-                    logits, [(i, r) for i, (r, _) in enumerate(wave)]
+                self._dispatch_next_of_prefill(
+                    data, lengths,
+                    [(r.slot, i, r) for i, (r, _) in enumerate(wave)],
                 )
-                for i, (r, g) in enumerate(wave):
-                    # index the radix cache BEFORE the done-check can
-                    # release the pages: a request that finishes in its
-                    # prefill tick still leaves its prompt warm for the
-                    # next sharer
-                    self._pool.register_prefix(
-                        r.request_id, [int(t) for t in r.prompt]
+            self.stats.prefill_s += time.perf_counter() - t_dispatch
+            self.stats.prefill_waves += 1
+            self.stats.prefill_tokens += wave_tokens
+            self.stats.compiles += xla_compile_count() - compiles0
+            if tracer is not None:
+                for r, g in wave:
+                    tracer.instant(
+                        "admit", eng,
+                        {"request": r.request_id, "slot": r.slot,
+                         "pages": len(g.page_table),
+                         "shared": g.shared_tokens},
                     )
-                    tok = self._pick_token(r, tokens_np[i], sampled.get(i))
-                    r.tokens.append(tok)
-                    r.index = int(valid[i])
-                    r.status = RUNNING
-                    self._running[r.request_id] = r
-                    if r.first_token_s is None:
-                        r.first_token_s = now
-                    self.stats.generated_tokens += 1
-                    if r.done:
-                        self._finish(r, now)
+                    self._trace_close_queue(r, tracer, end_us=run.start_us)
+                    lane = tracer.request_lane(r.request_id, lease=False)
+                    if lane is not None:
+                        tracer.complete(
+                            "prefill", lane, run.start_us,
+                            {"request": r.request_id,
+                             "replica": self.trace_name,
+                             "bucket": bucket, "slot": r.slot,
+                             "shared": g.shared_tokens},
+                            dur_us=run.end_us - run.start_us,
+                        )
+                    r.trace_marks["decode"] = run.end_us
+            for i, (r, _) in enumerate(wave):
+                self._start_decode(r, int(valid[i]))
+            if self._reads_now(r for r, _ in wave):
+                self._retire()
+
+    def _start_decode(self, request: Request, index: int) -> None:
+        """Seat a request on the decode batch once the program that
+        makes its first token is dispatched.  The radix cache indexes
+        its prompt BEFORE ``_sent`` can release the pages: a request
+        that finishes in its prefill still leaves its prompt warm for
+        the next sharer."""
+        self._pool.register_prefix(
+            request.request_id, [int(t) for t in request.prompt]
+        )
+        request.index = index
+        request.status = RUNNING
+        self._running[request.request_id] = request
+        self._sent(request)
+
+    def _sent(self, request: Request) -> None:
+        """One more of ``request``'s tokens is being made on the chip.
+        Once its last one is, its row and pages are free for the next
+        admission: programs on a device run in the order they were
+        issued, and each takes the slabs the one before it returned, so
+        what a new occupant writes lands after what this request's last
+        program read."""
+        rid = request.request_id
+        owed = self._owed[rid] = self._owed.get(rid, 0) + 1
+        if len(request.tokens) + owed >= request.max_new_tokens:
+            self._running.pop(rid, None)
+            self._release(request)
+
+    def _reads_now(self, requests) -> bool:
+        """Whether a program's tokens are needed on the host before the
+        next one is dispatched: a sampled token is drawn there, and
+        chunked prefill runs every program to its end."""
+        return self._chunk_policy is not None or any(
+            r.temperature > 0.0 for r in requests
+        )
+
+    def _dispatch_next_of_prefill(self, data, lengths, served) -> None:
+        """The wave's first tokens into the next-token vector, from each
+        ``served`` row's last true position (``lengths - 1``)."""
+        at = np.full((2, self.prefill_batch), self.max_concurrency,
+                     np.int32)
+        at[0] = lengths - 1
+        for row, i, _ in served:
+            at[1, i] = row
+        at = device_put_elided(at, self._last_device)
+        self._next = _next_of_prefill(data, at, self._next)
+        samples = any(r.temperature > 0.0 for _, _, r in served)
+        self._inflight.append(_Dispatched(
+            "prefill", self.stats.iterations, self._next, served,
+            _gather_last(data, at[0]) if samples else None,
+        ))
+
+    def _set_next(self, chosen) -> None:
+        """Write ``(row, token)`` pairs the host chose into the
+        next-token vector."""
+        if not chosen:
+            return
+        at = np.full((2, self.max_concurrency), self.max_concurrency,
+                     np.int32)
+        for k, (row, tok) in enumerate(chosen):
+            at[:, k] = (row, tok)
+        self._next = _write_next(
+            self._next, device_put_elided(at, self._last_device)
+        )
 
     def _table_width(self, valid) -> int:
         """Page-table columns this step actually needs: the wave's max
@@ -2524,24 +2717,24 @@ class ServingEngine(LiveMetricsMixin):
         """Thread one step through every stage — the ONE
         dispatch idiom shared by tail-prefill waves, chunk waves,
         decode ticks, and the speculative verify forward: per-stage
-        device puts, the donated step program with its same-statement
-        slab rebind, and per stage a ``sky.serve.put`` span around the
-        puts and a dispatch span (``sky.serve.stage``; ``ring`` on the
-        stage's lane in the ring).  Returns the last stage's output."""
+        device puts (one batched call for what moves), the donated step
+        program with its same-statement slab rebind, and per stage a
+        ``sky.serve.put`` span around the puts and a dispatch span
+        (``sky.serve.stage``; ``ring`` on the stage's lane in the ring).
+        Returns the last stage's output and its ``valid`` on the device."""
         sp, eng = self._sp, self._eng_lane
         for k, st in enumerate(self.stages):
             with sp.span("sky.serve.put", eng):
-                data = device_put_elided(data, st.device)
-                tb = device_put_elided(tables, st.device)
-                ix = device_put_elided(index, st.device)
-                vl = device_put_elided(valid, st.device)
+                data, tb, ix, vl = device_put_elided(
+                    (data, tables, index, valid), st.device
+                )
             with sp.span("sky.serve.stage",
                          sp.lane(st.lane_name, "dispatch"),
                          dict(span_args or (), stage=k), ring=ring):
                 data, st.slabs = st._step_donated(
                     st.params, data, st.slabs, tb, ix, vl
                 )
-        return data
+        return data, vl
 
     def _swap_in(self, request: Request) -> bool:
         """Re-seat a swapped-out request: fresh pages, host copies
@@ -2605,6 +2798,7 @@ class ServingEngine(LiveMetricsMixin):
         request.index = record["index"]
         request.status = RUNNING
         self._running[request.request_id] = request
+        self._set_next([(row, request.tokens[-1])])
         self.stats.swap_ins += 1
         self.stats.queue_depth = self._queue.depth
         tracer = get_tracer()
@@ -2619,19 +2813,23 @@ class ServingEngine(LiveMetricsMixin):
         return True
 
     def _decode_tick(self) -> None:
+        """One token for every seated row, its input the next-token
+        vector on the chip and everything else from the host's counts;
+        read back a step later unless a row samples (``_reads_now``)."""
         active = list(self._running.values())
         if not active:
             return
         sp, eng = self._sp, self._eng_lane
         ends = [r.index + 1 for r in active]
         width = self._table_width(ends)
-        tick_args = {"active": len(active),
+        in_flight = int(bool(self._inflight)
+                        and self._inflight[0].step < self.stats.iterations)
+        tick_args = {"active": len(active), "in_flight": in_flight,
                      **self._count_attn_pages(ends, width)}
         with sp.span("sky.serve.decode", eng, tick_args):
             with sp.span("sky.serve.build", eng):
                 rows = self.max_concurrency
                 sentinel = self.num_pages
-                tokens = np.zeros((rows,), np.int32)
                 index = np.zeros((rows,), np.int32)
                 # inactive rows never write
                 valid = np.zeros((rows,), np.int32)
@@ -2639,7 +2837,6 @@ class ServingEngine(LiveMetricsMixin):
                     (rows, self.max_pages_per_request), sentinel, np.int32
                 )
                 for r in active:
-                    tokens[r.slot] = r.tokens[-1]
                     index[r.slot] = r.index
                     valid[r.slot] = r.index + 1
                     held = self._pool.table(r.request_id)
@@ -2647,34 +2844,27 @@ class ServingEngine(LiveMetricsMixin):
 
                 tables = tables[:, :width]
                 self._count_quant(index, valid, width, len(active))
-            t0 = time.perf_counter()
+            t_dispatch = time.perf_counter()
             compiles0 = xla_compile_count()
             with sp.span("sky.serve.run", eng, tick_args, ring="decode"):
-                data = self._run_stages(
-                    tokens[:, None], tables, index, valid, "decode"
+                data, valid = self._run_stages(
+                    self._next, tables, index, valid, "decode"
                 )
-                logits = data[:, 0]  # [rows, V]
-                nxt = _argmax_tokens(logits)
-                with sp.span("sky.serve.wait", eng):
-                    jax.block_until_ready(nxt)
-            now = time.perf_counter()
-            self.stats.decode_s += now - t0
-            with sp.span("sky.serve.commit", eng):
-                self.stats.decode_tokens += len(active)
-                self.stats.generated_tokens += len(active)
-                self.stats.compiles += xla_compile_count() - compiles0
-
-                nxt_np = np.asarray(nxt)
-                sampled = self._sampled_rows(
-                    logits, [(r.slot, r) for r in active]
-                )
-                for r in active:
-                    tok = self._pick_token(r, nxt_np[r.slot],
-                                           sampled.get(r.slot))
-                    r.tokens.append(tok)
-                    r.index += 1
-                    if r.done:
-                        self._finish(r, now)
+                self._next = _next_of_decode(data, valid, self._next)
+                samples = any(r.temperature > 0.0 for r in active)
+                self._inflight.append(_Dispatched(
+                    "decode", self.stats.iterations, self._next,
+                    [(r.slot, r.slot, r) for r in active],
+                    data[:, 0] if samples else None,
+                ))
+            self.stats.decode_s += time.perf_counter() - t_dispatch
+            self.stats.compiles += xla_compile_count() - compiles0
+            self.stats.ticks_in_flight += in_flight
+            for r in active:
+                r.index += 1
+                self._sent(r)
+        if self._reads_now(active):
+            self._retire()
 
     def _spec_tick(self) -> None:
         """One speculative decode tick (replaces the plain decode tick
@@ -2698,7 +2888,12 @@ class ServingEngine(LiveMetricsMixin):
         sample streams are untouched (and contribute nothing to the
         draft/accept/rollback counters: they never consume drafts).
         A tick with NO greedy row falls back to the plain decode tick
-        — drafting for rows that cannot accept would be pure waste."""
+        — drafting for rows that cannot accept would be pure waste.
+
+        Drafting starts from each row's last token on the host, so the
+        tick reads back what is in flight first, commits at once, and
+        leaves the rows' last tokens in the next-token vector."""
+        self._retire()
         active = list(self._running.values())
         if not active:
             return
@@ -2776,7 +2971,7 @@ class ServingEngine(LiveMetricsMixin):
             # --- verify: one Lq=k+1 forward over the whole pipeline
             with sp.span("sky.serve.run", eng, tick_args, ring="decode"):
                 verify_in = np.concatenate([tokens[:, None], drafted], axis=1)
-                logits3 = self._run_stages(
+                logits3, _ = self._run_stages(
                     verify_in, tables, index0, valid, "decode"
                 )  # [rows, k+1, V]
                 target = _argmax_tokens(logits3)  # [rows, k+1]
@@ -2836,6 +3031,8 @@ class ServingEngine(LiveMetricsMixin):
                         self._finish(r, now)
                 self.stats.decode_tokens += committed_total
                 self.stats.generated_tokens += committed_total
+                self._set_next([(r.slot, r.tokens[-1]) for r in active
+                                if r.slot is not None])
 
     @staticmethod
     def _sampled_rows(logits, rows) -> Dict[int, np.ndarray]:

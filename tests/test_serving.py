@@ -185,6 +185,130 @@ def test_preemption_requeues_with_stream_intact(gpt):
     np.testing.assert_array_equal(other.output(), reference(fwd, other))
 
 
+# --------------------------------------------------------------------------
+# one program in flight: a step reads back the step before it
+# --------------------------------------------------------------------------
+
+
+def _backlog(rng):
+    """Prompts on both sides of the 8- and 16-token page edges, and
+    1 to 9 new tokens, so that requests finish at every position of a
+    tick, in their prefill too, while three rows admit the rest."""
+    return mixed_requests(rng, [(7, 1), (8, 5), (9, 2), (15, 9), (16, 3),
+                                (3, 7), (12, 4), (5, 6), (2, 8)])
+
+
+def _step_and_check(engine, requests):
+    """One step, then the contract every caller may rely on after it:
+    a finished request holds all its tokens, tokens arrive in order and
+    only ever grow, and work is reported while a program is in flight.
+    Returns the step's tick count (0 or 1) and whether a program was
+    left in flight."""
+    before = {r.request_id: list(r.tokens) for r in requests}
+    table0 = engine.stats.attn_pages_table
+    engine.step()
+    for r in requests:
+        assert r.tokens[:len(before[r.request_id])] == \
+            before[r.request_id]
+        if r.status == "finished":
+            assert len(r.tokens) == r.max_new_tokens
+    in_flight = bool(engine._inflight)
+    assert engine.has_work() or not in_flight
+    return int(engine.stats.attn_pages_table > table0), in_flight
+
+
+def test_greedy_backlog_in_flight_is_token_identical(gpt):
+    """A greedy backlog over admissions, waves of two, finishes at every
+    position of a tick and page edges: every stream equals ``generate``'s,
+    each step but the first dispatches its tick while the step before it
+    is still in flight (nothing forces a read: ``retires`` stays 0), and
+    ``run`` leaves nothing in flight."""
+    layer_cfgs, params, fwd = gpt
+    engine = paged_engine(layer_cfgs, params, prefill_batch=2)
+    requests = _backlog(np.random.default_rng(40))
+    for r in requests:
+        engine.submit(r)
+    ticks = 0
+    left_in_flight = 0
+    while engine.has_work():
+        tick, in_flight = _step_and_check(engine, requests)
+        ticks += tick
+        left_in_flight += in_flight
+    assert left_in_flight > 0 and not engine._inflight
+    assert engine.stats.retires == 0
+    assert engine.stats.ticks_in_flight == ticks - 1
+    assert engine.stats.finished == len(requests)
+    for r in requests:
+        np.testing.assert_array_equal(r.output(), reference(fwd, r))
+    engine._pool.check_consistency()
+    # a flush mid-run is a read before its time: the tick after it has
+    # nothing of an earlier step in flight, as the first one had not
+    more = _backlog(np.random.default_rng(41))
+    in_flight0 = engine.stats.ticks_in_flight
+    for r in more:
+        engine.submit(r)
+    ticks = sum(_step_and_check(engine, more)[0] for _ in range(3))
+    assert engine._inflight
+    engine.flush()
+    assert not engine._inflight and engine.stats.retires == 1
+    while engine.has_work():
+        ticks += _step_and_check(engine, more)[0]
+    assert engine.stats.ticks_in_flight - in_flight0 == ticks - 2
+    for r in more:
+        np.testing.assert_array_equal(r.output(), reference(fwd, r))
+
+
+def test_temperature_row_retires_its_ticks_and_keeps_its_stream(gpt):
+    """A sampled token is drawn on the host, so every program that makes
+    one is read back at once (counted in ``retires``); its stream equals
+    the one the request draws served alone, and the greedy rows beside
+    it keep ``generate``'s."""
+    layer_cfgs, params, fwd = gpt
+    rng = np.random.default_rng(42)
+    prompt = rng.integers(1, 512, (9,)).astype(np.int32)
+    alone = paged_engine(layer_cfgs, params)
+    solo = Request(prompt=prompt.copy(), max_new_tokens=6,
+                   temperature=0.8, seed=5)
+    alone.run([solo])
+    engine = paged_engine(layer_cfgs, params, prefill_batch=2)
+    greedy = mixed_requests(rng, [(5, 9), (12, 7)])
+    sampled = Request(prompt=prompt.copy(), max_new_tokens=6,
+                      temperature=0.8, seed=5)
+    engine.run(greedy + [sampled])
+    np.testing.assert_array_equal(sampled.output(), solo.output())
+    for r in greedy:
+        np.testing.assert_array_equal(r.output(), reference(fwd, r))
+    # its prefill wave and each of the five ticks it was seated for
+    assert engine.stats.retires >= sampled.max_new_tokens
+    assert not engine._inflight
+
+
+@pytest.mark.parametrize("mode", ["recompute", "swap"])
+def test_preempt_with_a_tick_in_flight_keeps_the_stream(gpt, mode):
+    """A request preempted while its next token is still on the chip is
+    read back first (one ``retires``), re-queues with every token it
+    was owed, and resumes token-identical."""
+    layer_cfgs, params, fwd = gpt
+    engine = paged_engine(layer_cfgs, params)
+    victim, other = mixed_requests(np.random.default_rng(43),
+                                   [(6, 10), (5, 9)])
+    engine.submit(victim)
+    engine.submit(other)
+    for _ in range(3):
+        engine.step()
+    assert engine._inflight and engine._owed[victim.request_id] == 1
+    seen = len(victim.tokens)
+    engine.preempt(victim.request_id, mode=mode)
+    assert engine.stats.retires == 1 and not engine._inflight
+    assert len(victim.tokens) == seen + 1
+    assert victim.slot is None and victim.status == "queued"
+    engine.run()
+    for r in (victim, other):
+        np.testing.assert_array_equal(r.output(), reference(fwd, r))
+    assert engine.stats.swap_ins == (mode == "swap")
+    engine._pool.check_consistency()
+
+
 def test_default_engine_is_paged_and_token_identical(gpt):
     """``ServingEngine(cfgs, params)`` with no layout argument serves
     from a page pool (the derived defaults: 4 rows' worth of whole
@@ -676,7 +800,9 @@ def test_paged_admission_decouples_buckets_from_capacity(gpt):
     engine.step()
     # all four admitted at once: 4 x bucket(16) = 64 padded positions
     # against a 32-position pool — bucket choice did not charge memory
-    assert len(engine.running_requests) + engine.stats.finished == 4
+    # (the two of 2 new tokens have every token dispatched: they hold no
+    # row any more, and are finished once their tokens are read back)
+    assert all(r.status == "running" for r in requests)
     assert engine.stats.queue_stalls == 0
     engine.run()
     for r in requests:

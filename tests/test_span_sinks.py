@@ -295,11 +295,13 @@ SERVE_PARENT = {
     "sky.serve.decode": {"sky.serve.step"},
     "sky.serve.build": {"sky.serve.prefill", "sky.serve.decode"},
     "sky.serve.cow": {"sky.serve.prefill"},
-    "sky.serve.run": {"sky.serve.prefill", "sky.serve.decode"},
+    "sky.serve.run": {"sky.serve.prefill", "sky.serve.decode",
+                      "sky.serve.retire"},
     "sky.serve.put": {"sky.serve.run"},
     "sky.serve.stage": {"sky.serve.run"},
+    "sky.serve.retire": {"sky.serve.step"},
     "sky.serve.wait": {"sky.serve.run"},
-    "sky.serve.commit": {"sky.serve.prefill", "sky.serve.decode"},
+    "sky.serve.commit": {"sky.serve.retire"},
     "sky.serve.sync": {"sky.serve.step"},
 }
 
@@ -370,10 +372,21 @@ def test_serving_leaves_the_catalogue_in_the_profilers_trace(tmp_path):
             for s in by_name["sky.serve.decode"]] \
         == [(2 + 1 + 10, rows * 2), (2 + 1 + 10, rows * 2),
             (3 + 1 + 10, rows * 4)]
+    # the first tick has nothing of an earlier step in flight, the next
+    # two have the tick before them
+    assert [s["in_flight"] for s in by_name["sky.serve.decode"]] \
+        == [0, 1, 1]
     assert all(s["stage"] == 0 for s in by_name["sky.serve.stage"])
-    for name in ("sky.serve.put", "sky.serve.stage", "sky.serve.wait"):
-        # one stage: one put, one dispatch and one barrier to a run
-        assert len(by_name[name]) == len(by_name["sky.serve.run"]) == 5
+    for name in ("sky.serve.put", "sky.serve.stage"):
+        # one stage: one put and one dispatch to a program
+        assert len(by_name[name]) == 5
+    # steps 2 and 3 each read back the step before them (step 1's two
+    # waves and its tick, then step 2's tick): one barrier and one
+    # commit to a read-back
+    assert [s["programs"] for s in by_name["sky.serve.retire"]] == [3, 1]
+    for name in ("sky.serve.wait", "sky.serve.commit"):
+        assert len(by_name[name]) == 2
+    assert len(by_name["sky.serve.run"]) == 5 + 2
     assert len(by_name["sky.serve.sync"]) == 3
 
 
