@@ -549,7 +549,7 @@ def server_phase(size, gpt, prompts, specs, refs, argmax_gaps, kv_dtype,
         stage, rows = engine.stages[0], engine.max_concurrency
         width = engine.max_pages_per_request
         i32 = lambda *shape: jax.ShapeDtypeStruct(shape, np.int32)
-        text = stage._step_donated.lower(
+        text = stage.programs["decode_tick"].lower(
             stage.params, i32(rows, 1), stage.slabs, i32(rows, width),
             i32(rows), i32(rows),
         ).compile().as_text()

@@ -45,6 +45,7 @@ batch_occupancy`` makes it visible instead of hidden.
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import json
 import time
@@ -104,40 +105,75 @@ from .speculative import (
 )
 
 
-# module-level jits, so every engine instance shares the executables (one
+# Every program the engine dispatches is named for the work it serves: a
+# jitted function's name is its module's name in a profiler trace
+# (``jit_<name>``), so the chip's time reads by kind.  The prefixes:
+# ``prefill_`` (a wave and its first tokens), ``decode_`` (a tick and
+# its next-token vector), ``spec_`` (the speculative draft and verify),
+# ``cow_`` (the copy-on-write page copy), ``swap_`` (the swap-out gather
+# and swap-in scatter); a new program takes one of them
+# (docs/observability.md, "Program names").
+#
+# Module-level jits, so every engine instance shares the executables (one
 # compile per shape).  The greedy path never takes logits off the chip:
 # each prefill wave and decode tick takes its argmax straight into the
 # engine's next-token vector (``[rows, 1]`` int32, the decode program's
 # input as it stands) at the rows it served: a tick's are those with
 # ``valid > 0``, a wave's are ``at[1]`` (out of range for a pad row, which
-# drops), read at each wave row's last position ``at[0]``.  _write_next
-# writes tokens the host chose (a sampled token, a resumed row's last);
-# _gather_last and _argmax_tokens serve the paths that read logits on the
-# host (temperature sampling, the speculative verify)
-_next_of_decode = jax.jit(
-    lambda logits, valid, nxt: jnp.where(
+# drops), read at each wave row's last position ``at[0]``.
+# decode_write_next writes tokens the host chose (a sampled token, a
+# resumed row's last); prefill_gather_last and spec_verify_argmax serve
+# the paths that read logits on the host (temperature sampling, the
+# speculative verify)
+@jax.jit
+def decode_next(logits, valid, nxt):
+    return jnp.where(
         valid[:, None] > 0,
         jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)[:, None],
         nxt,
     )
-)
-_next_of_prefill = jax.jit(
-    lambda logits, at, nxt: nxt.at[at[1], 0].set(
+
+
+@jax.jit
+def prefill_first_token(logits, at, nxt):
+    return nxt.at[at[1], 0].set(
         jnp.argmax(
             logits[jnp.arange(logits.shape[0]), at[0]], axis=-1
         ).astype(jnp.int32),
         mode="drop",
     )
-)
-_write_next = jax.jit(
-    lambda nxt, at: nxt.at[at[0], 0].set(at[1], mode="drop")
-)
-_gather_last = jax.jit(
-    lambda logits, pos: logits[jnp.arange(logits.shape[0]), pos, :]
-)
-_argmax_tokens = jax.jit(
-    lambda logits: jnp.argmax(logits, axis=-1).astype(jnp.int32)
-)
+
+
+@jax.jit
+def decode_write_next(nxt, at):
+    return nxt.at[at[0], 0].set(at[1], mode="drop")
+
+
+@jax.jit
+def prefill_gather_last(logits, pos):
+    return logits[jnp.arange(logits.shape[0]), pos, :]
+
+
+@jax.jit
+def spec_verify_argmax(logits):
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+# the kinds of forward ``_run_stages`` dispatches, each its own handle on
+# one ``step`` body: tail and chunk waves share one compiled shape set,
+# the verify forward (``Lq = spec_k + 1``) has its own
+STAGE_PROGRAM_KINDS = ("prefill_wave", "decode_tick", "spec_verify")
+
+
+def _named(fn, name: str):
+    """``fn`` under another name (and ``fn``'s signature): the name a
+    jitted handle of it gives its program."""
+    @functools.wraps(fn)
+    def program(*args):
+        return fn(*args)
+
+    program.__name__ = program.__qualname__ = name
+    return program
 
 # Process-level stage-program cache: the jit'd step closures, keyed by
 # the stage's layer-config signature (+ max_len + donation).
@@ -385,25 +421,32 @@ class _Dispatched:
 
 # small page-slab utilities, module-level jits so every engine shares
 # the executables (shape-keyed: one compile per slab geometry).
-# _copy_page is undonated, so on accelerators each COW event pays a
+# cow_copy_page is undonated, so on accelerators each COW event pays a
 # slab-sized copy; COW fires at most once per prefix-hit admission, so
 # this is off the per-token path — donate + rebind if it ever shows up
-_copy_page = jax.jit(lambda slab, src, dst: slab.at[dst].set(slab[src]))
-_gather_rows = jax.jit(
-    lambda slab, table: slab[jnp.clip(table, 0, slab.shape[0] - 1)]
-)
-_scatter_rows = jax.jit(
-    lambda slab, table, vals: slab.at[table].set(
-        vals.astype(slab.dtype), mode="drop"
-    )
-)
+@jax.jit
+def cow_copy_page(slab, src, dst):
+    return slab.at[dst].set(slab[src])
+
+
+@jax.jit
+def swap_gather_rows(slab, table):
+    return slab[jnp.clip(table, 0, slab.shape[0] - 1)]
+
+
+@jax.jit
+def swap_scatter_rows(slab, table, vals):
+    return slab.at[table].set(vals.astype(slab.dtype), mode="drop")
 
 
 class _ServingStage:
     """One pipeline stage: module slice + device + per-attention-layer
-    page slabs ``[num_pages, page_size, heads * head_dim]`` + the one
-    fused step program (prefill and decode are the same function at
-    different input shapes — see ``models/gpt.apply_kv_paged``)."""
+    page slabs ``[num_pages, page_size, heads * head_dim]`` + the fused
+    step program, one jitted handle of it per kind of forward
+    (``programs``, keyed by ``STAGE_PROGRAM_KINDS``: prefill and decode
+    are the same function at different input shapes — see
+    ``models/gpt.apply_kv_paged`` — and each handle compiles only its
+    kind's shapes, under its kind's name)."""
 
     def __init__(
         self,
@@ -440,9 +483,9 @@ class _ServingStage:
             if program_key is not None else None
         )
         if cached is not None:
-            # same config signature -> the closure (and jax's traced/
-            # compiled cache behind its identity) is reusable as-is
-            self._step_donated = cached
+            # same config signature -> the closures (and jax's traced/
+            # compiled cache behind their identity) are reusable as-is
+            self.programs = cached
             return
         mods = self.modules
         impl = attn_impl
@@ -453,16 +496,17 @@ class _ServingStage:
                 valid_len, attn_impl=impl,
             )
 
-        # convention: the *_donated handle consumes its slabs on call —
-        # the engine rebinds st.slabs to the outputs on the same line.
+        # convention: every handle consumes its slabs on call — the
+        # engine rebinds st.slabs to the outputs on the same line.
         # Donation follows the backend like the training engine:
         # in-place slab reuse pays on TPU/GPU, is inert on CPU.
-        if _donation_enabled():
-            self._step_donated = jax.jit(step, donate_argnums=(2,))
-        else:
-            self._step_donated = jax.jit(step)
+        donate = (2,) if _donation_enabled() else ()
+        self.programs = {
+            kind: jax.jit(_named(step, kind), donate_argnums=donate)
+            for kind in STAGE_PROGRAM_KINDS
+        }
         if program_key is not None:
-            _STAGE_PROGRAMS[program_key] = self._step_donated
+            _STAGE_PROGRAMS[program_key] = self.programs
 
     def build_slabs(self, num_pages: int, page_size: int):
         """Fresh zeroed page slabs (construction + the reconfigure
@@ -503,14 +547,14 @@ class _ServingStage:
                 )
             if not quantized:
                 s, d = copies["values"]
-                return _copy_page(slab, s, d)
+                return cow_copy_page(slab, s, d)
             values, scale = slab.values, slab.scale
             if "values" in copies:
                 s, d = copies["values"]
-                values = _copy_page(values, s, d)
+                values = cow_copy_page(values, s, d)
             if "scales" in copies:
                 s, d = copies["scales"]
-                scale = _copy_page(scale, s, d)
+                scale = cow_copy_page(scale, s, d)
             return QuantizedPages(values, scale)
 
         # one pass over the slab list regardless of how many entry
@@ -528,10 +572,10 @@ class _ServingStage:
         def g(slab):
             if isinstance(slab, QuantizedPages):
                 return QuantizedPages(
-                    np.asarray(_gather_rows(slab.values, t)),
-                    np.asarray(_gather_rows(slab.scale, t)),
+                    np.asarray(swap_gather_rows(slab.values, t)),
+                    np.asarray(swap_gather_rows(slab.scale, t)),
                 )
-            return np.asarray(_gather_rows(slab, t))
+            return np.asarray(swap_gather_rows(slab, t))
 
         return [(g(k), g(v)) for k, v in self.slabs]
 
@@ -543,12 +587,12 @@ class _ServingStage:
         def s(slab, host):
             if isinstance(slab, QuantizedPages):
                 return QuantizedPages(
-                    _scatter_rows(slab.values, t,
+                    swap_scatter_rows(slab.values, t,
                                   jnp.asarray(host.values)),
-                    _scatter_rows(slab.scale, t,
+                    swap_scatter_rows(slab.scale, t,
                                   jnp.asarray(host.scale)),
                 )
-            return _scatter_rows(slab, t, jnp.asarray(host))
+            return swap_scatter_rows(slab, t, jnp.asarray(host))
 
         self.slabs = [
             (s(k, hk), s(v, hv))
@@ -2361,8 +2405,11 @@ class ServingEngine(LiveMetricsMixin):
         # serving_padding_fraction() must see what this wave actually
         # prefilled, never the members' full prompt lengths
         wave_tokens = int(sum(int(c.size) for c in chunks))
+        # positions: what the program computes, the [rows, bucket] that
+        # pad_batch builds, pad rows and pad columns included
         wave_args = {"bucket": bucket, "wave": len(wave),
-                     "tokens": wave_tokens, "chunk": True}
+                     "tokens": wave_tokens, "positions": rows * bucket,
+                     "chunk": True}
         with sp.span("sky.serve.prefill", eng, wave_args):
             with sp.span("sky.serve.build", eng):
                 ids, lengths = self.bucketer.pad_batch(
@@ -2397,7 +2444,7 @@ class ServingEngine(LiveMetricsMixin):
             with sp.span("sky.serve.run", eng, run_args,
                          ring="prefill") as run:
                 data, _ = self._run_stages(
-                    ids, tables, index, valid, "prefill",
+                    "prefill_wave", ids, tables, index, valid,
                     {"bucket": bucket, "chunk": True},
                 )
                 if finals:
@@ -2518,7 +2565,8 @@ class ServingEngine(LiveMetricsMixin):
         wave_tokens = int(sum(int(t.size) for t in tails))
         shared_tokens = int(sum(g.shared_tokens for _, g in wave))
         wave_args = {"bucket": bucket, "wave": len(wave),
-                     "tokens": wave_tokens, "shared": shared_tokens}
+                     "tokens": wave_tokens, "positions": rows * bucket,
+                     "shared": shared_tokens}
         with sp.span("sky.serve.prefill", eng, wave_args):
             with sp.span("sky.serve.build", eng):
                 ids, lengths = self.bucketer.pad_batch(
@@ -2560,7 +2608,7 @@ class ServingEngine(LiveMetricsMixin):
             with sp.span("sky.serve.run", eng, run_args,
                          ring="prefill") as run:
                 data, _ = self._run_stages(
-                    ids, tables, index, valid, "prefill",
+                    "prefill_wave", ids, tables, index, valid,
                     {"bucket": bucket},
                 )
                 self._dispatch_next_of_prefill(
@@ -2640,11 +2688,11 @@ class ServingEngine(LiveMetricsMixin):
         for row, i, _ in served:
             at[1, i] = row
         at = device_put_elided(at, self._last_device)
-        self._next = _next_of_prefill(data, at, self._next)
+        self._next = prefill_first_token(data, at, self._next)
         samples = any(r.temperature > 0.0 for _, _, r in served)
         self._inflight.append(_Dispatched(
             "prefill", self.stats.iterations, self._next, served,
-            _gather_last(data, at[0]) if samples else None,
+            prefill_gather_last(data, at[0]) if samples else None,
         ))
 
     def _set_next(self, chosen) -> None:
@@ -2656,7 +2704,7 @@ class ServingEngine(LiveMetricsMixin):
                      np.int32)
         for k, (row, tok) in enumerate(chosen):
             at[:, k] = (row, tok)
-        self._next = _write_next(
+        self._next = decode_write_next(
             self._next, device_put_elided(at, self._last_device)
         )
 
@@ -2712,17 +2760,19 @@ class ServingEngine(LiveMetricsMixin):
         self.stats.attn_pages_table += counts["attn_pages_table"]
         return counts
 
-    def _run_stages(self, data, tables, index, valid, ring,
+    def _run_stages(self, kind, data, tables, index, valid,
                     span_args=None):
         """Thread one step through every stage — the ONE
         dispatch idiom shared by tail-prefill waves, chunk waves,
         decode ticks, and the speculative verify forward: per-stage
-        device puts (one batched call for what moves), the donated step
-        program with its same-statement slab rebind, and per stage a
-        ``sky.serve.put`` span around the puts and a dispatch span
-        (``sky.serve.stage``; ``ring`` on the stage's lane in the ring).
+        device puts (one batched call for what moves), the stage's
+        handle for ``kind`` (one of ``STAGE_PROGRAM_KINDS``) with its
+        same-statement slab rebind, and per stage a ``sky.serve.put``
+        span around the puts and a dispatch span (``sky.serve.stage``;
+        ``prefill`` or ``decode`` on the stage's lane in the ring).
         Returns the last stage's output and its ``valid`` on the device."""
         sp, eng = self._sp, self._eng_lane
+        ring = "prefill" if kind == "prefill_wave" else "decode"
         for k, st in enumerate(self.stages):
             with sp.span("sky.serve.put", eng):
                 data, tb, ix, vl = device_put_elided(
@@ -2731,7 +2781,7 @@ class ServingEngine(LiveMetricsMixin):
             with sp.span("sky.serve.stage",
                          sp.lane(st.lane_name, "dispatch"),
                          dict(span_args or (), stage=k), ring=ring):
-                data, st.slabs = st._step_donated(
+                data, st.slabs = st.programs[kind](
                     st.params, data, st.slabs, tb, ix, vl
                 )
         return data, vl
@@ -2848,9 +2898,9 @@ class ServingEngine(LiveMetricsMixin):
             compiles0 = xla_compile_count()
             with sp.span("sky.serve.run", eng, tick_args, ring="decode"):
                 data, valid = self._run_stages(
-                    self._next, tables, index, valid, "decode"
+                    "decode_tick", self._next, tables, index, valid
                 )
-                self._next = _next_of_decode(data, valid, self._next)
+                self._next = decode_next(data, valid, self._next)
                 samples = any(r.temperature > 0.0 for r in active)
                 self._inflight.append(_Dispatched(
                     "decode", self.stats.iterations, self._next,
@@ -2972,9 +3022,9 @@ class ServingEngine(LiveMetricsMixin):
             with sp.span("sky.serve.run", eng, tick_args, ring="decode"):
                 verify_in = np.concatenate([tokens[:, None], drafted], axis=1)
                 logits3, _ = self._run_stages(
-                    verify_in, tables, index0, valid, "decode"
+                    "spec_verify", verify_in, tables, index0, valid
                 )  # [rows, k+1, V]
-                target = _argmax_tokens(logits3)  # [rows, k+1]
+                target = spec_verify_argmax(logits3)  # [rows, k+1]
                 with sp.span("sky.serve.wait", eng):
                     jax.block_until_ready(target)
             now = time.perf_counter()
@@ -2983,9 +3033,13 @@ class ServingEngine(LiveMetricsMixin):
                 self.stats.compiles += xla_compile_count() - compiles0
 
                 target_np = np.asarray(target)
-                sampled = self._sampled_rows(
-                    logits3[:, 0], [(r.slot, r) for r in active]
-                )
+                # position 0 of each pulled row, sliced on the host: a
+                # tick with no sampling row dispatches nothing here
+                sampled = {
+                    row: pulled[0] for row, pulled in self._sampled_rows(
+                        logits3, [(r.slot, r) for r in active]
+                    ).items()
+                }
                 committed_total = 0
                 for r in active:
                     row = r.slot

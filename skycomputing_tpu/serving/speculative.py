@@ -127,7 +127,8 @@ class DraftModel:
         mods = self.modules
         impl = attn_impl
 
-        def step(params_list, tokens, slabs, tables, index, valid_len):
+        def spec_draft_step(params_list, tokens, slabs, tables, index,
+                            valid_len):
             # argmax FUSED into the program: drafting is greedy by
             # definition (only the target's verify logits ever commit
             # a token), so the draft never needs its logits on the
@@ -140,8 +141,8 @@ class DraftModel:
             nxt = jnp.argmax(out[:, 0], axis=-1).astype(jnp.int32)
             return nxt, new_slabs
 
-        def loop(params_list, tokens, slabs, tables, index, reserve,
-                 k):
+        def spec_draft_loop(params_list, tokens, slabs, tables, index,
+                            reserve, k):
             # the WHOLE k-step autoregressive draft as ONE compiled
             # program (k static, unrolled): per-step dispatch cost was
             # measured at ~half a full decode tick on the CPU fallback
@@ -152,20 +153,22 @@ class DraftModel:
             for j in range(k):
                 idx = index + j
                 valid = jnp.minimum(idx + 1, reserve)
-                cur, slabs = step(
+                cur, slabs = spec_draft_step(
                     params_list, cur, slabs, tables, idx, valid
                 )
                 proposals.append(cur)
             return jnp.stack(proposals, axis=1), slabs
 
         if _donation_enabled():
-            self._step_donated = jax.jit(step, donate_argnums=(2,))
+            self._step_donated = jax.jit(spec_draft_step,
+                                         donate_argnums=(2,))
             self._loop_donated = jax.jit(
-                loop, static_argnums=(6,), donate_argnums=(2,)
+                spec_draft_loop, static_argnums=(6,), donate_argnums=(2,)
             )
         else:
-            self._step_donated = jax.jit(step)
-            self._loop_donated = jax.jit(loop, static_argnums=(6,))
+            self._step_donated = jax.jit(spec_draft_step)
+            self._loop_donated = jax.jit(spec_draft_loop,
+                                         static_argnums=(6,))
         if program_key is not None:
             _DRAFT_PROGRAMS[program_key] = (
                 self._step_donated, self._loop_donated

@@ -442,6 +442,153 @@ def test_zero_steady_state_recompiles_after_bucket_warmup(
 
 
 # --------------------------------------------------------------------------
+# program names: the chip's time reads by the work it served
+# --------------------------------------------------------------------------
+
+KIND_PREFIXES = ("prefill_", "decode_", "spec_", "cow_", "swap_")
+
+
+def _dispatched_programs(out_dir):
+    """How often each jitted program was dispatched while the profiler
+    ran: the host plane's ``PjitFunction(<name>)`` events, one a call of
+    a jitted handle, under the name its program carries on the chip."""
+    import collections
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(
+        str(out_dir), "plugins", "profile", "*", "*.xplane.pb"))
+    calls = collections.Counter()
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("PjitFunction("):
+                    calls[ev.name[len("PjitFunction("):-1]] += 1
+    return calls
+
+
+def _prefill_decode_cow_swap(engine, rng):
+    """Prefill waves, decode ticks, a prefix hit whose shared prompt
+    ends inside a page (a copy-on-write copy) and a swap-out and swap-in,
+    at the same shapes on every call: only the tokens are new."""
+    system = rng.integers(1, 512, (18,)).astype(np.int32)
+
+    def shared(n):
+        return Request(prompt=np.concatenate(
+            [system, rng.integers(1, 512, (n,)).astype(np.int32)]),
+            max_new_tokens=6)
+
+    first = shared(3)
+    engine.run([first])
+    victim, other = mixed_requests(rng, [(6, 10), (5, 9)])
+    sharer = shared(4)
+    for r in (victim, other, sharer):
+        engine.submit(r)
+    for _ in range(3):
+        engine.step()
+    engine.preempt(victim.request_id, mode="swap")
+    engine.run()
+    return [first, victim, other, sharer]
+
+
+@pytest.mark.parametrize("engine_kw, expect", [
+    pytest.param(
+        dict(),
+        {"prefill_wave", "prefill_first_token", "decode_tick",
+         "decode_next", "decode_write_next", "cow_copy_page",
+         "swap_gather_rows", "swap_scatter_rows"},
+        id="plain"),
+    pytest.param(
+        dict(spec_k=2, draft_blocks=1),
+        {"prefill_wave", "prefill_first_token", "spec_draft_loop",
+         "spec_verify", "spec_verify_argmax", "decode_write_next",
+         "cow_copy_page", "swap_gather_rows", "swap_scatter_rows"},
+        id="speculative"),
+])
+def test_every_dispatched_program_is_named_for_its_work(
+        gpt, tmp_path, engine_kw, expect):
+    """A paged engine run that prefills, decodes, copies a page on write
+    and swaps a request out and in dispatches only programs whose names
+    carry a kind's prefix (``serving/engine.py``, docs/observability.md),
+    the stage programs under the kind of forward they run; the traced
+    run compiles nothing, and every stream equals ``generate``'s."""
+    layer_cfgs, params, fwd = gpt
+    engine = paged_engine(layer_cfgs, params, buckets=(8, 16, 32),
+                          prefill_batch=2, **engine_kw)
+    for st in engine.stages:
+        assert sorted(st.programs) == sorted(
+            ("prefill_wave", "decode_tick", "spec_verify"))
+        assert {kind: handle.__name__
+                for kind, handle in st.programs.items()} == {
+            kind: kind for kind in st.programs}
+    _prefill_decode_cow_swap(engine, np.random.default_rng(12))  # compile
+    cow0, swaps0 = engine.stats.cow_copies, engine.stats.swap_ins
+    compiles0 = xla_compile_count()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        requests = _prefill_decode_cow_swap(
+            engine, np.random.default_rng(13))
+    finally:
+        jax.profiler.stop_trace()
+    assert xla_compile_count() == compiles0
+    assert engine.stats.cow_copies > cow0
+    assert engine.stats.swap_ins == swaps0 + 1
+    calls = _dispatched_programs(tmp_path)
+    assert set(calls) == expect, calls
+    assert all(name.startswith(KIND_PREFIXES) for name in calls)
+    for r in requests:
+        np.testing.assert_array_equal(r.output(), reference(fwd, r))
+    engine._pool.check_consistency()
+
+
+def test_each_kind_of_forward_compiles_under_its_own_handle(gpt):
+    """The zero-steady-state-recompile pin with one handle per kind: a
+    warm-up compiles tail waves and chunk waves into ``prefill_wave``
+    alone and ticks into ``decode_tick`` alone (the verify handle of an
+    engine without speculation stays empty), lowered as modules of those
+    names; a steady wave then adds no compile to any handle."""
+    layer_cfgs, params, fwd = gpt
+    # max_len 56 keys these handles apart from the other tests' engines
+    engine = paged_engine(layer_cfgs, params, max_len=56, prefill_batch=2)
+    programs = engine.stages[0].programs
+    assert len({id(h) for h in programs.values()}) == len(programs)
+
+    def entries():
+        return {kind: h._cache_size() for kind, h in programs.items()}
+
+    before = entries()
+    rng = np.random.default_rng(27)
+    engine.run(mixed_requests(rng, [(4, 3), (12, 6)]))
+    # chunk waves run the tail waves' shapes, under the same handle
+    engine.reconfigure(prefill_chunk=8)
+    engine.run(mixed_requests(rng, [(14, 3)]))
+    engine.reconfigure(prefill_chunk=0)
+    warm = entries()
+    assert warm["prefill_wave"] > before["prefill_wave"]
+    assert warm["decode_tick"] > before["decode_tick"]
+    assert warm["spec_verify"] == before["spec_verify"] == 0
+    compiles0 = xla_compile_count()
+    wave = mixed_requests(rng, [(6, 8), (2, 3), (15, 5), (9, 4), (11, 2)])
+    outputs = engine.run(wave)
+    assert xla_compile_count() == compiles0
+    assert entries() == warm
+    for r in wave:
+        np.testing.assert_array_equal(outputs[r.request_id],
+                                      reference(fwd, r))
+    st = engine.stages[0]
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, np.int32)  # noqa: E731
+    width = engine.max_pages_per_request
+    for kind, rows, cols in (("prefill_wave", 2, 8),
+                             ("decode_tick", engine.max_concurrency, 1)):
+        text = programs[kind].lower(
+            st.params, i32(rows, cols), st.slabs, i32(rows, width),
+            i32(rows), i32(rows)).as_text()
+        assert text.startswith(f"module @jit_{kind} ")
+
+
+# --------------------------------------------------------------------------
 # admission / pool contracts
 # --------------------------------------------------------------------------
 

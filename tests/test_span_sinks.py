@@ -390,6 +390,43 @@ def test_serving_leaves_the_catalogue_in_the_profilers_trace(tmp_path):
     assert len(by_name["sky.serve.sync"]) == 3
 
 
+@pytest.mark.parametrize("chunk", [None, 8], ids=["tail_wave", "chunk_wave"])
+def test_prefill_span_counts_the_positions_its_program_computes(
+        tmp_path, chunk):
+    """``sky.serve.prefill`` carries ``positions``: the ``[rows, bucket]``
+    its program computes, pad rows and pad columns included, beside
+    ``tokens``, the true ones; for a tail wave and for a chunk wave."""
+    engine, requests = _tiny_engine()
+    if chunk:
+        engine.reconfigure(prefill_chunk=chunk)
+    engine.run(requests())  # compile every shape first
+    for request in requests():
+        engine.submit(request)
+    _start_trace(tmp_path)
+    try:
+        engine.run()
+    finally:
+        jax.profiler.stop_trace()
+    waves = [stats for name, stats, _ in _sky_spans(tmp_path)
+             if name == "sky.serve.prefill"]
+    rows = engine.prefill_batch
+    assert rows == 2
+    for wave in waves:
+        assert wave["positions"] == rows * wave["bucket"]
+        assert wave["tokens"] <= wave["positions"]
+        assert bool(wave.get("chunk")) == bool(chunk)
+    # prompts of 14 and 5: one wave each in buckets 16 and 8, or chunks
+    # of at most 8 (14 = 8 + 6) that pack two to a wave of bucket 8
+    if chunk:
+        assert sum(w["tokens"] for w in waves) == 14 + 5
+        assert {w["bucket"] for w in waves} == {8}
+        assert sorted((w["wave"], w["positions"]) for w in waves) \
+            == [(1, 16), (2, 16)]
+    else:
+        assert sorted((w["bucket"], w["tokens"], w["positions"])
+                      for w in waves) == [(8, 5, 16), (16, 14, 32)]
+
+
 # --------------------------------------------------------------------------
 # (c) a session changes nothing the program counts
 # --------------------------------------------------------------------------
