@@ -137,11 +137,22 @@ class GptEmbeddings(nn.Module):
         ``index`` may be a scalar (all rows at the same offset) or a [B]
         vector (continuous batching: every slot at its own position).
         Dropout is never applied (decoding is inference).
+
+        Rows are taken from the float32 tables and only those rows are
+        cast: ``nn.Embed`` casts the whole table before its take, which
+        XLA does not fuse, so every decode tick and prefill wave would
+        convert the full ``[vocab, hidden]`` table.  A cast rounds each
+        element on its own, so the result is bit-identical.  ``__call__``
+        keeps ``nn.Embed``: there, take-then-cast would move the
+        embedding gradient's scatter-add from ``cfg.dtype`` to float32.
         """
         from ..serving.kv_cache import decode_positions
 
+        dtype = jnp.dtype(_gcfg(self.config).dtype)
         positions = decode_positions(index, input_ids.shape[1])
-        return self.wte(input_ids) + self.wpe(positions)
+        tokens = jnp.take(self.wte.embedding, input_ids, axis=0)
+        places = jnp.take(self.wpe.embedding, positions, axis=0)
+        return tokens.astype(dtype) + places.astype(dtype)
 
 
 @LAYER.register_module

@@ -1,6 +1,7 @@
 """GPT family: causal masking, pipeline training, ring-attention variant."""
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
@@ -9,9 +10,11 @@ from jax.sharding import Mesh
 from skycomputing_tpu.builder import build_layer_stack
 from skycomputing_tpu.models.gpt import (
     GptConfig,
+    GptEmbeddings,
     causal_lm_loss,
     gpt_layer_configs,
 )
+from skycomputing_tpu.serving.kv_cache import decode_positions
 
 
 def tiny_gpt(mesh=None, seq=32):
@@ -173,3 +176,66 @@ def test_gpt_profiles_through_model_benchmarker():
     assert all(f > 0 for f in flops)
     # repeated blocks profile identically (config-hash dedup)
     assert flops[1] == flops[3] and flops[2] == flops[4]
+
+
+def _bf16_embeddings(vocab, hidden, max_positions):
+    cfg = GptConfig(vocab_size=vocab, hidden_size=hidden, num_hidden_layers=1,
+                    num_attention_heads=1, max_position_embeddings=max_positions,
+                    dropout_prob=0.0, initializer_range=1.0, dtype="bfloat16")
+    module = GptEmbeddings(cfg.to_dict(), deterministic=True)
+    params = module.init(jax.random.key(5), np.zeros((1, 4), np.int32))
+    return module, params
+
+
+def _embed_whole_table(module, ids, index):
+    # the reference: nn.Embed casts the whole table to bfloat16, then
+    # takes the rows
+    return module.wte(ids) + module.wpe(decode_positions(index, ids.shape[1]))
+
+
+@pytest.mark.parametrize("query_len", [1, 7])
+@pytest.mark.parametrize("per_row_index", [False, True])
+def test_gpt_embeddings_decode_bits_match_whole_table_cast(query_len,
+                                                           per_row_index):
+    vocab, max_positions = 997, 61
+    module, params = _bf16_embeddings(vocab, 48, max_positions)
+    rng = np.random.default_rng(query_len)
+    ids = rng.integers(0, vocab, (4, query_len)).astype(np.int32)
+    ids[0, 0], ids[-1, -1] = 0, vocab - 1
+    if per_row_index:
+        index = np.array([0, 3, 17, max_positions - query_len], np.int32)
+    else:
+        index = np.int32(max_positions - query_len)
+
+    new = module.apply(params, ids, index, method=GptEmbeddings.decode)
+    old = module.apply(params, ids, index, method=_embed_whole_table)
+    assert new.dtype == jnp.bfloat16 and new.shape == (4, query_len, 48)
+    np.testing.assert_array_equal(np.asarray(new.view(jnp.uint16)),
+                                  np.asarray(old.view(jnp.uint16)))
+
+    # the comparison can tell roundings apart: one cast after a float32
+    # add gives other bits
+    tables = params["params"]
+    f32_sum = (jnp.take(tables["wte"]["embedding"], ids, axis=0)
+               + jnp.take(tables["wpe"]["embedding"],
+                          decode_positions(index, query_len), axis=0))
+    assert np.any(np.asarray(f32_sum.astype(jnp.bfloat16).view(jnp.uint16))
+                  != np.asarray(new.view(jnp.uint16)))
+
+
+@pytest.mark.parametrize("query_len", [1, 7])
+def test_gpt_embeddings_decode_casts_no_whole_table(query_len):
+    vocab, hidden, max_positions = 997, 48, 61
+    module, params = _bf16_embeddings(vocab, hidden, max_positions)
+    ids = np.zeros((4, query_len), np.int32)
+    index = np.zeros((4,), np.int32)
+
+    def compiled(method):
+        fn = jax.jit(lambda p, i, x: module.apply(p, i, x, method=method))
+        return fn.lower(params, ids, index).compile().as_text()
+
+    tables = (f"bf16[{vocab},{hidden}]", f"bf16[{max_positions},{hidden}]")
+    old = compiled(_embed_whole_table)
+    assert all(t in old for t in tables), "the old form no longer shows it"
+    new = compiled(GptEmbeddings.decode)
+    assert not [t for t in tables if t in new]
