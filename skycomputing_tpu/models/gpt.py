@@ -449,6 +449,38 @@ class GptLmHead(nn.Module):
         return logits.astype(jnp.float32)
 
 
+# The parameters each layer kind rounds to ``cfg.dtype`` in every forward
+# before it uses them: the kernel and bias of its dense projections, the
+# experts' weights.  Held in that dtype they give the same bits from
+# fewer bytes.  Everything else is used at its width: the LayerNorms, the
+# embedding tables (their rows are gathered, then cast) and the router.
+ROUNDED_PARAMS = {
+    GptBlock_Attn: ("q_proj", "k_proj", "v_proj", "c_proj"),
+    GptBlock_Mlp: ("c_fc", "c_proj"),
+    GptBlock_MoeMlp: ("w1", "b1", "w2", "b2"),
+    GptLmHead: ("lm_head",),
+}
+
+
+def served_param_dtypes(module, params):
+    """The dtype each leaf of one layer's ``params`` is held in where it
+    is served: ``cfg.dtype`` under a name ``ROUNDED_PARAMS`` gives for
+    the module's kind, where that dtype is the narrower; every other
+    leaf's own.  A tree already so held maps to itself."""
+    names = ROUNDED_PARAMS.get(type(module), ())
+
+    def held(path, x):
+        own = np.dtype(x.dtype)
+        if names and getattr(path[0], "key", None) in names:
+            dtype = np.dtype(_gcfg(module.config).dtype)
+            if (jnp.issubdtype(own, jnp.floating)
+                    and dtype.itemsize < own.itemsize):
+                return dtype
+        return own
+
+    return jax.tree_util.tree_map_with_path(held, params)
+
+
 def gpt_layer_configs(
     config: Any,
     num_blocks: Optional[int] = None,
@@ -871,4 +903,6 @@ __all__ = [
     "attn_indices",
     "decode_modules",
     "draft_slice_indices",
+    "ROUNDED_PARAMS",
+    "served_param_dtypes",
 ]

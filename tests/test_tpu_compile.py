@@ -10,7 +10,8 @@ tests lower and compile each kernel at the widths ``chip_smoke.py`` runs
 require a ``tpu_custom_call`` in the compiled program: a kernel lowered
 in interpret mode has none.  The paged stage's whole step program is
 compiled too, at GPT-2-large's pool, and its optimized HLO read for
-what no CPU run shows: an operation that relays a whole slab out.
+what no CPU run shows: an operation that relays a whole slab out, and
+code emitted anew for every layer.
 Nothing runs, so nothing here is a device result.  Skipped where the
 topology cannot be described.
 """
@@ -127,6 +128,66 @@ _HLO_RESULT = re.compile(
 )
 
 
+def _paged_step(on_v5e, monkeypatch, blocks, pages, held=False):
+    """The stage's step program as ``_ServingStage`` builds it
+    (``apply_kv_paged``, the Pallas kernel) for a GPT of ``blocks``
+    blocks at GPT-2-large's widths: ``(step, params, slab)``, the
+    params abstract and on the described chip, each leaf in the dtype
+    the engine holds it in where ``held``."""
+    from skycomputing_tpu.builder import build_layer_stack
+    from skycomputing_tpu.models.gpt import (
+        GptConfig,
+        apply_kv_paged,
+        decode_modules,
+        gpt_layer_configs,
+        served_param_dtypes,
+    )
+
+    # on this CPU backend ``paged_attention`` would pick interpret mode
+    kernel_module = sys.modules["skycomputing_tpu.ops.paged_attention"]
+    monkeypatch.setattr(
+        kernel_module, "paged_attention",
+        functools.partial(paged_attention, interpret=False),
+    )
+    cfg = GptConfig(
+        vocab_size=50257, hidden_size=LARGE["hidden"],
+        num_hidden_layers=blocks,
+        num_attention_heads=LARGE["heads"], intermediate_size=5120,
+        max_position_embeddings=1024, dtype="bfloat16",
+    )
+    stack = build_layer_stack(gpt_layer_configs(cfg, deterministic=True))
+    modules = decode_modules(stack)
+    avals = jax.eval_shape(
+        lambda key: stack.init(key, np.ones((1, 8), np.int32)),
+        jax.random.key(0),
+    )
+    params = [
+        jax.tree.map(
+            lambda leaf, dtype: on_v5e(
+                leaf.shape, dtype if held else leaf.dtype),
+            p, served_param_dtypes(m, p),
+        )
+        for m, p in zip(modules, avals)
+    ]
+    slab = on_v5e((pages, PAGE_SIZE, LARGE["hidden"]), jnp.bfloat16)
+
+    def step(params_list, data, slabs, tables, index, valid_len):
+        return apply_kv_paged(
+            modules, params_list, data, slabs, tables, index, valid_len,
+            attn_impl="pallas",
+        )
+
+    return step, params, slab
+
+
+def _step_args(on_v5e, slab, blocks, rows, query_len):
+    return (
+        on_v5e((rows, query_len), jnp.int32), [(slab, slab)] * blocks,
+        on_v5e((rows, LARGE["width"]), jnp.int32),
+        on_v5e((rows,), jnp.int32), on_v5e((rows,), jnp.int32),
+    )
+
+
 @pytest.mark.parametrize("shape", list(STEP_SHAPES))
 def test_paged_step_never_relays_a_slab_out(on_v5e, monkeypatch, shape):
     """The stage's step program (``apply_kv_paged`` as
@@ -138,49 +199,11 @@ def test_paged_step_never_relays_a_slab_out(on_v5e, monkeypatch, shape):
     program copied every slab twice and reshaped it once, 113 ms of a
     124 ms tick (PERF.md, PR 30).  Two blocks stand for the 36: every
     block's slabs are treated alike."""
-    from skycomputing_tpu.builder import build_layer_stack
-    from skycomputing_tpu.models.gpt import (
-        GptConfig,
-        apply_kv_paged,
-        decode_modules,
-        gpt_layer_configs,
-    )
-
-    # on this CPU backend ``paged_attention`` would pick interpret mode
-    kernel_module = sys.modules["skycomputing_tpu.ops.paged_attention"]
-    monkeypatch.setattr(
-        kernel_module, "paged_attention",
-        functools.partial(paged_attention, interpret=False),
-    )
     rows, query_len = STEP_SHAPES[shape]
-    cfg = GptConfig(
-        vocab_size=50257, hidden_size=LARGE["hidden"],
-        num_hidden_layers=LARGE["blocks"],
-        num_attention_heads=LARGE["heads"], intermediate_size=5120,
-        max_position_embeddings=1024, dtype="bfloat16",
-    )
-    stack = build_layer_stack(gpt_layer_configs(cfg, deterministic=True))
-    modules = decode_modules(stack)
-    params = jax.tree.map(
-        lambda leaf: on_v5e(leaf.shape, leaf.dtype),
-        list(jax.eval_shape(
-            lambda key: stack.init(key, np.ones((1, 8), np.int32)),
-            jax.random.key(0),
-        )),
-    )
-    slab = on_v5e((LARGE["pages"], PAGE_SIZE, LARGE["hidden"]), jnp.bfloat16)
-
-    def step(params_list, data, slabs, tables, index, valid_len):
-        return apply_kv_paged(
-            modules, params_list, data, slabs, tables, index, valid_len,
-            attn_impl="pallas",
-        )
-
+    step, params, slab = _paged_step(on_v5e, monkeypatch, LARGE["blocks"],
+                                     LARGE["pages"])
     text = jax.jit(step, donate_argnums=(2,)).lower(
-        params, on_v5e((rows, query_len), jnp.int32),
-        [(slab, slab)] * LARGE["blocks"],
-        on_v5e((rows, LARGE["width"]), jnp.int32),
-        on_v5e((rows,), jnp.int32), on_v5e((rows,), jnp.int32),
+        params, *_step_args(on_v5e, slab, LARGE["blocks"], rows, query_len),
     ).compile().as_text()
     assert text.count("%decode_paged_attention") >= LARGE["blocks"]
     slab_elements = int(np.prod(slab.shape))
@@ -192,3 +215,29 @@ def test_paged_step_never_relays_a_slab_out(on_v5e, monkeypatch, shape):
             if int(np.prod(dims)) >= slab_elements:
                 relayouts.append(line.strip()[:160])
     assert not relayouts, relayouts
+
+
+def test_stage_program_code_is_emitted_once_for_its_layers(
+        on_v5e, monkeypatch):
+    """A stage program of the held weights, compiled with the options
+    ``_ServingStage`` gives a TPU, holds its layers' code once: six
+    blocks' code is within a tenth of two blocks'.  Without them each
+    block adds its own ~2 MB (at 36 blocks a program held 36-108 MB of
+    code, in device memory and in the persistent compile cache)."""
+    from skycomputing_tpu.serving.engine import _stage_compiler_options
+
+    def code_bytes(blocks, options):
+        step, params, slab = _paged_step(on_v5e, monkeypatch, blocks,
+                                         256, held=True)
+        device = next(iter(slab.sharding.device_set))
+        compiled = jax.jit(
+            step, donate_argnums=(2,),
+            compiler_options=(_stage_compiler_options(device)
+                              if options else None),
+        ).lower(params, *_step_args(on_v5e, slab, blocks, 1, 128)).compile()
+        return compiled.memory_analysis().generated_code_size_in_bytes
+
+    code = {(blocks, options): code_bytes(blocks, options)
+            for blocks, options in ((2, True), (6, True), (6, False))}
+    assert code[6, True] - code[2, True] < code[2, True] / 10, code
+    assert code[6, False] > 2 * code[6, True], code
